@@ -135,7 +135,6 @@ def run_engine(model, params, cfg, ecfg: EngineConfig, reqs):
     done = [r for r in results if r.ok]
     lats = sorted(r.latency for r in done) or [0.0]
     compiled = dict(engine.compile_counts())
-    counts_known = all(v is not None for v in compiled.values())
     qs = engine.queue_stats()
     return {
         "requests": len(results),
@@ -154,9 +153,7 @@ def run_engine(model, params, cfg, ecfg: EngineConfig, reqs):
         "queue_depth_mean": qs["mean"],
         "rejected": qs["rejected"],
         "compiled_programs": compiled,
-        # None = jit cache sizes unavailable (UNKNOWN, not "no recompile")
-        "recompiled_after_warmup": (compiled != compiled_warm
-                                    if counts_known else None),
+        "recompiled_after_warmup": compiled != compiled_warm,
         **({"page_stats": ps} if (ps := engine.page_stats()) else {}),
     }, results
 

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import projections as proj
 from repro.sharding import ShardingRules
 
@@ -105,7 +104,7 @@ def awp_prune_colsharded_fn(k: int, eta, iters: int, rules: ShardingRules):
         return theta_loc
 
     def fn(w, c):
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(dp, tp), P(tp, None)),
             out_specs=P(dp, tp),
@@ -129,7 +128,7 @@ def calib_c_distributed(acts: jax.Array, rules: ShardingRules) -> jax.Array:
         n = jax.lax.psum(jnp.float32(a.shape[0]), dp)
         return c_sum / n
 
-    return compat.shard_map(local, mesh=mesh,
+    return jax.shard_map(local, mesh=mesh,
                          in_specs=P(dp, None, None) if acts.ndim == 3 else P(dp, None),
                          out_specs=P(None, None), check_vma=False)(acts)
 

@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_NRM_ROWS = 8      # sublane height of the per-block residual-norm tiles
+
 
 def _kernel(w_ref, theta_k_ref, c_ref, theta_out_ref, eta_ref, z_ref, nrm_ref,
             acc_ref, *, n_k: int):
@@ -39,18 +41,24 @@ def _kernel(w_ref, theta_k_ref, c_ref, theta_out_ref, eta_ref, z_ref, nrm_ref,
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
         eta = eta_ref[0, 0]
+        acc = acc_ref[...]
         z_ref[...] = (theta_out_ref[...].astype(jnp.float32)
-                      + eta * acc_ref[...]).astype(z_ref.dtype)
-        # per-block ‖(W−Θ)C‖² partial from the f32 accumulator — recovering
-        # the residual norm from Z−Θ instead would cancel catastrophically
-        # near convergence (‖ηR‖ ≪ ‖Θ‖) and floor the PGD stopping rule
-        nrm_ref[0, 0] = jnp.sum(acc_ref[...] * acc_ref[...])
+                      + eta * acc).astype(z_ref.dtype)
+        # per-block ‖(W−Θ)C‖² column partials from the f32 accumulator —
+        # recovering the residual norm from Z−Θ instead would cancel
+        # catastrophically near convergence (‖ηR‖ ≪ ‖Θ‖) and floor the PGD
+        # stopping rule. Stored lane-dense: an (8, bn) tile repeating the
+        # (1, bn) partial, so the block stays (8, 128)-aligned.
+        part = jnp.sum(acc * acc, axis=0, keepdims=True)
+        nrm_ref[...] = jnp.broadcast_to(part, nrm_ref.shape)
 
 
 def _kernel_batched(w_ref, theta_k_ref, c_ref, theta_out_ref, eta_ref, z_ref,
                     nrm_ref, acc_ref, *, n_k: int):
     """Batched variant: blocks carry a leading singleton batch dim; K is
-    grid axis 3. η is per-item (read from the (B, 1) eta array)."""
+    grid axis 3. η is per-item, read from the (B, 1) SMEM array."""
+    item = pl.program_id(0)
+
     @pl.when(pl.program_id(3) == 0)
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -62,10 +70,12 @@ def _kernel_batched(w_ref, theta_k_ref, c_ref, theta_out_ref, eta_ref, z_ref,
 
     @pl.when(pl.program_id(3) == n_k - 1)
     def _epilogue():
-        eta = eta_ref[0, 0]
+        eta = eta_ref[item, 0]
+        acc = acc_ref[...]
         z_ref[0] = (theta_out_ref[0].astype(jnp.float32)
-                    + eta * acc_ref[...]).astype(z_ref.dtype)
-        nrm_ref[0, 0, 0] = jnp.sum(acc_ref[...] * acc_ref[...])
+                    + eta * acc).astype(z_ref.dtype)
+        part = jnp.sum(acc * acc, axis=0, keepdims=True)
+        nrm_ref[0] = jnp.broadcast_to(part, nrm_ref.shape[1:])
 
 
 def _step_batched(w, theta, c, eta, *, bm, bn, bk, interpret,
@@ -95,21 +105,23 @@ def _step_batched(w, theta, c, eta, *, bm, bn, bk, interpret,
             pl.BlockSpec((1, bm, bk), lambda bb, i, j, kk: (bb, i, kk)),  # Θ[i,k]
             pl.BlockSpec((1, bk, bn), lambda bb, i, j, kk: (bb, kk, j)),  # C
             pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),   # Θ[i,j]
-            pl.BlockSpec((1, 1), lambda bb, i, j, kk: (bb, 0)),           # η_b
+            pl.BlockSpec(memory_space=pltpu.SMEM),                        # η
         ],
         out_specs=[
             pl.BlockSpec((1, bm, bn), lambda bb, i, j, kk: (bb, i, j)),
-            pl.BlockSpec((1, 1, 1), lambda bb, i, j, kk: (bb, i, j)),
+            pl.BlockSpec((1, _NRM_ROWS, bn), lambda bb, i, j, kk: (bb, i, j)),
         ],
         out_shape=[jax.ShapeDtypeStruct((b, mp, np_), w.dtype),
-                   jax.ShapeDtypeStruct((b, gm, gn), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, gm * _NRM_ROWS, np_),
+                                        jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="awp_pgd",
         interpret=interpret,
     )(w, theta, c, theta, eta_arr)
     z = out[:, :m, :k]
     if not with_resid_norm:
         return z
-    return z, jnp.sqrt(nrm.sum(axis=(-2, -1)))
+    return z, jnp.sqrt(nrm[:, ::_NRM_ROWS].sum(axis=(-2, -1)))
 
 
 def awp_pgd_step(w: jax.Array, theta: jax.Array, c: jax.Array, eta,
@@ -150,21 +162,22 @@ def awp_pgd_step(w: jax.Array, theta: jax.Array, c: jax.Array, eta,
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),   # Θ[i, k]
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),   # C[k, j]
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),    # Θ[i, j]
-            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),      # η
+            pl.BlockSpec(memory_space=pltpu.SMEM),              # η
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, kk: (i, j)),
+            pl.BlockSpec((_NRM_ROWS, bn), lambda i, j, kk: (i, j)),
         ],
         out_shape=[jax.ShapeDtypeStruct((mp, np_), w.dtype),
-                   jax.ShapeDtypeStruct((gm, gn), jnp.float32)],
+                   jax.ShapeDtypeStruct((gm * _NRM_ROWS, np_), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="awp_pgd",
         interpret=interpret,
     )(w, theta, c, theta, eta_arr)
     z = out[:m, :n]
     if not with_resid_norm:
         return z
-    return z, jnp.sqrt(nrm.sum())
+    return z, jnp.sqrt(nrm[::_NRM_ROWS].sum())
 
 
 __all__ = ["awp_pgd_step"]

@@ -4,7 +4,8 @@ One query token per row attends its whole cache history in a single fused
 program — no materialized dense K/V. Four variants share the kernel body:
 
 - **slot layout**: per-layer cache ``(B, T, Hk, D)`` (B = slots), dense
-  floats or INT8 codes + per-head-group f16 scale/zero dequantized IN-TILE;
+  floats or INT8 codes + per-head-group f32 scale / uint8 zero dequantized
+  IN-TILE;
 - **paged layout**: per-layer page pools ``(P, page, Hk, D)`` routed through
   a ``(B, n_pages)`` block table — each K tile is one page, gathered via the
   scalar-prefetched table in the BlockSpec index map (sentinel entries
@@ -34,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.dequant_matmul import expand_groups
+
 _NEG_INF = -1e30   # finite init so exp(m_prev - m_new) is 0.0, never NaN
 
 
@@ -43,12 +46,18 @@ def _cdiv(a, b):
 
 def _dequant_tile(codes, scale, zero, group: int):
     """In-tile INT8 → f32 expansion; same op order as the reference
-    ``kv_cache._reference_dequant`` so fused-vs-reference parity is tight."""
-    bt, hk, d = codes.shape
-    g = codes.astype(jnp.float32).reshape(bt, hk, d // group, group)
-    deq = (g - zero.astype(jnp.float32)[..., None]) \
-        * scale.astype(jnp.float32)[..., None]
-    return deq.reshape(bt, hk, d)
+    ``kv_cache._reference_dequant`` so fused-vs-reference parity is tight.
+    Codes widen through int32 and the per-group planes expand across lanes
+    with masks — no (…, D/g, g) reshape, which the TPU compiler refuses for
+    groups narrower than 128 lanes."""
+    d = codes.shape[-1]
+    dg = scale.shape[-1]
+    scale = scale.astype(jnp.float32)
+    zero = zero.astype(jnp.int32).astype(jnp.float32)
+    if dg > 1:
+        scale = expand_groups(scale, 0, dg, group, d)
+        zero = expand_groups(zero, 0, dg, group, d)
+    return (codes.astype(jnp.int32).astype(jnp.float32) - zero) * scale
 
 
 def _online_update(q, k, v, start, length, bt, sm_scale,
@@ -234,6 +243,7 @@ def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
                             pltpu.VMEM((h, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        name="flash_decode",
         interpret=interpret,
     )(*prefetch, q, *operands)
 

@@ -49,6 +49,7 @@ def quant_project(z: jax.Array, bits: int, group_size: int = 128, *,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows + pm, d + pn), z.dtype),
+        name="quant_proj",
         interpret=interpret,
     )(z)
     return out[:rows, :d]

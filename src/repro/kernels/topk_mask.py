@@ -3,9 +3,10 @@ vectorized threshold bisection — the projection of Eq. (5).
 
 GPU implementations use radix-select in shared memory; the TPU-native
 replacement is a fixed number of lane-parallel "count |z| ≥ τ" sweeps
-(DESIGN.md §2): 32 bisection steps shrink [lo, hi) to ~1 ulp, then exact-k
+(DESIGN.md §2): 40 bisection steps shrink [lo, hi) to ~1 ulp, then exact-k
 is restored by keeping all entries > boundary plus the first (k − count)
-boundary ties in index order (matching jax.lax.top_k's tie-breaking).
+boundary ties in index order (matching jax.lax.top_k's tie-breaking),
+found by a second bisection over the index position.
 
 Grid: one program per row block; the whole row strip lives in VMEM
 (bm × d_in — ≤ 8×73728 f32 ≈ 2.3 MB for the largest assigned arch).
@@ -44,8 +45,24 @@ def _kernel(z_ref, out_ref, *, k: int):
     definite = mag >= hi                               # strictly above ties
     n_def = jnp.sum(definite.astype(jnp.int32), axis=-1, keepdims=True)
     boundary = jnp.logical_and(mag >= lo, jnp.logical_not(definite))
-    order = jnp.cumsum(boundary.astype(jnp.int32), axis=-1)
-    take_tie = jnp.logical_and(boundary, order <= (k - n_def))
+    need = k - n_def                                   # ties to keep, ≥ 0
+    # the first `need` ties in index order: bisect the smallest position p
+    # with count(boundary ∧ idx ≤ p) ≥ need — lane-parallel counts, the same
+    # sweep as above (Mosaic has no cumsum)
+    idx = jax.lax.broadcasted_iota(jnp.int32, mag.shape, mag.ndim - 1)
+
+    def pos_body(_, carry):
+        p_lo, p_hi = carry                             # count(p_lo) < need
+        mid = (p_lo + p_hi) >> 1                       # count(p_hi) ≥ need
+        cnt = jnp.sum(jnp.logical_and(boundary, idx <= mid)
+                      .astype(jnp.int32), axis=-1, keepdims=True)
+        enough = cnt >= need
+        return jnp.where(enough, p_lo, mid), jnp.where(enough, mid, p_hi)
+
+    _, p = jax.lax.fori_loop(0, d.bit_length(), pos_body,
+                             (jnp.full_like(need, -1),
+                              jnp.full_like(need, d - 1)))
+    take_tie = boundary & (idx <= p) & (need > 0)
     keep = jnp.logical_or(definite, take_tie)
     out_ref[...] = jnp.where(keep, z, jnp.zeros_like(z))
 
@@ -64,6 +81,7 @@ def topk_row(z: jax.Array, k: int, *, bm: int = 8,
         in_specs=[pl.BlockSpec((bm, d), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows + pm, d), z.dtype),
+        name="topk_mask",
         interpret=interpret,
     )(z)
     return out[:rows]

@@ -30,6 +30,7 @@ from repro.core import metrics, registry
 from repro.core.compress import CompressionConfig, compress_model
 from repro.core.specs import Policy
 from repro.data import DataConfig, ZipfMarkov, calibration_batches
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.obs import MetricsRegistry
 
@@ -80,6 +81,7 @@ def main():
                     help="block index to wrap in a jax.profiler trace "
                          "window (needs --profile-dir; -1 -> off)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_tiny_config(args.arch) if args.tiny else get_config(args.arch)
     model = build_model(cfg, remat=False)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: AOT lower + compile every (arch × shape × mesh) cell.
 
 Two lowerings per cell (see EXPERIMENTS.md §Dry-run "methodology"):
@@ -14,7 +11,10 @@ Two lowerings per cell (see EXPERIMENTS.md §Dry-run "methodology"):
    at 2-3 depths and reconstruct full-depth costs by exact linear fit
    f(L) = fixed + L·per_layer (+ ceil(L/p)·per_shared for the hybrid).
 
-Everything is ShapeDtypeStruct — no allocation.
+Everything is ShapeDtypeStruct — no allocation. The 512 devices are
+virtual CPU devices: ``main()`` pins the CPU platform and sets
+``XLA_FLAGS`` before JAX starts (never at import), so neither this process
+nor the per-cell children it starts ever take an attached accelerator.
 
 Usage:
   python -m repro.launch.dryrun --arch granite-8b --shape train_4k --mesh single
@@ -23,12 +23,12 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
 import traceback
 
-from repro import compat
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "results", "dryrun")
@@ -94,7 +94,7 @@ def _build_lowered(cfg, mesh, shape, kind, *, unroll: bool, n_micro: int):
         b_sh = {k: NamedSharding(mesh, rules.spec(("batch",) + (None,) * (len(v.shape) - 1),
                                                   v.shape))
                 for k, v in b_sds.items()}
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step_fn, in_shardings=(state_sh, b_sh),
                               donate_argnums=0).lower(state_sds, b_sds)
         tokens = shape.global_batch * shape.seq_len
@@ -124,7 +124,7 @@ def _build_lowered(cfg, mesh, shape, kind, *, unroll: bool, n_micro: int):
         args_sds = (param_sds, tok_sds, cache_sds)
         args_sh = (p_shardings, tok_sh, c_shardings)
         tokens = shape.global_batch
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(serve_step, in_shardings=args_sh,
                           donate_argnums=2).lower(*args_sds)
     return lowered, 2.0 * n_active * tokens
@@ -311,13 +311,13 @@ def _lower_compress(cfg, mesh, chips) -> dict:
             (in_w, in_c), out_sh = dist.rowsharded_shardings(v2_rules, d_out)
         else:
             (in_w, in_c), out_sh = dist.rowsharded_shardings(rules, d_out)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(unrolled_run, in_shardings=(in_w, in_c),
                               out_shardings=out_sh).lower(w_sds, c_sds)
         schedule = f"row-sharded (zero-collective, {sched})"
     else:
         run = dist.awp_prune_colsharded_fn(k, eta, iters, rules)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(run).lower(w_sds, c_sds)
         schedule = "column-sharded C (psum per iteration)"
     compiled = lowered.compile()
@@ -397,7 +397,17 @@ def orchestrate(meshes, include_compress: bool, timeout: int):
     return 0 if not failed else 1
 
 
+def _pin_virtual_cpu_devices() -> None:
+    """512 virtual CPU devices for the lowering; inherited by the per-cell
+    children ``orchestrate`` starts. Must run before JAX initializes."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main():
+    _pin_virtual_cpu_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

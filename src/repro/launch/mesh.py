@@ -6,13 +6,9 @@ import jax
 
 
 def _make(shape, axes):
-    """jax.make_mesh across versions: AxisType (and the axis_types kwarg)
-    only exist on newer jax; older releases default to auto axes anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with auto axis types on every axis."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

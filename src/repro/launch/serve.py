@@ -41,8 +41,10 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_tiny_config
 from repro.data import DataConfig, ZipfMarkov
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.quant import QTensor
+from repro.serving.engine import step_jit
 
 
 def qtensor_leaves(params) -> list:
@@ -93,8 +95,7 @@ def make_step_fns(model):
         logits, cache = model.decode_step(params, tok, cache)
         return jnp.argmax(logits[:, -1], -1)[:, None], cache
 
-    return (jax.jit(prefill_fn),
-            jax.jit(decode_fn, donate_argnums=2))
+    return step_jit(prefill_fn), step_jit(decode_fn, donate_argnums=2)
 
 
 def _load_params(args, model):
@@ -235,7 +236,7 @@ def _interval_printer(every: int):
 
 def _serve_engine(args, cfg, model, params):
     from repro.obs import StepTraceWindow
-    from repro.serving import Engine, EngineConfig
+    from repro.serving import Engine, EngineConfig, RequestStatus
 
     max_len = min(args.max_len, args.prompt_len + args.gen) \
         if args.max_len else args.prompt_len + args.gen
@@ -324,10 +325,7 @@ def _serve_engine(args, cfg, model, params):
               f"({rate:.0%}), {ps['prefix_hit_tokens']} prompt tokens reused")
         print(f"[serve] preemptions {ps['preemptions']}, resumes "
               f"{ps['resumes']}, pages spilled {ps['pages_spilled']}")
-    if None in after.values() or None in compiled.values():
-        print("[serve] note: jit cache sizes unavailable on this jax — "
-              "recompilation check is UNKNOWN")
-    elif after != compiled:
+    if after != compiled:
         print("[serve] WARNING: recompilation after warmup")
     if args.verify:
         if args.temperature > 0:
@@ -359,6 +357,14 @@ def _serve_engine(args, cfg, model, params):
         with open(prom, "w") as f:
             f.write(engine.metrics.to_prometheus())
         print(f"[serve] metrics snapshot -> {args.metrics_json} (+ {prom})")
+    failed = [r for r in results if r.status == RequestStatus.ERROR.value]
+    if failed:
+        # an error is a step failure the engine isolated to its request
+        # (compile or runtime fault on the device path) — never a pass;
+        # shed, cancelled and deadline results stay legitimate outcomes
+        for r in failed:
+            print(f"[serve] ERROR rid={r.rid}: {r.error}")
+        raise SystemExit(f"[serve] {len(failed)} request(s) ended in error")
 
 
 def main():
@@ -436,6 +442,7 @@ def main():
     args = ap.parse_args()
     if args.packed and not args.ckpt:
         ap.error("--packed requires --ckpt")
+    enable_compile_cache()
 
     cfg = get_tiny_config(args.arch) if args.tiny else get_config(args.arch)
     model = build_model(cfg, remat=False)

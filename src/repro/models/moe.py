@@ -24,7 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.quant import QTensor
@@ -244,7 +243,7 @@ def moe_apply_a2a(p, x, cfg: ModelConfig, rules: ShardingRules) -> jax.Array:
                 lambda a: P(tp, *([None] * (a.ndim - 1))), w)
         return P(tp, None, None)
 
-    y = compat.shard_map(local, mesh=mesh,
+    y = jax.shard_map(local, mesh=mesh,
                       in_specs=(x_spec, P(None, None), ew_spec(wu_w),
                                 ew_spec(wd_w),
                                 ew_spec(wg_w) if has_gate else P()),

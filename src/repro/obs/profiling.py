@@ -8,37 +8,16 @@ Two shapes:
   the first N engine steps (used by ``serve.py --profile-steps``); its
   ``on_step`` method plugs into ``Engine.run(step_hook=...)``.
 
-Both degrade to no-ops when the directory is empty or the profiler is
-unavailable, so telemetry never takes the serving path down.
+Both are no-ops when the directory is empty. A window that was asked for
+and cannot start raises: a measurement run must not go on unprofiled in
+silence.
 """
 
 from __future__ import annotations
 
 import contextlib
-import sys
 
 __all__ = ["trace_window", "StepTraceWindow"]
-
-
-def _start(log_dir: str) -> bool:
-    try:
-        import jax
-
-        jax.profiler.start_trace(log_dir)
-        return True
-    except Exception as e:  # pragma: no cover - environment dependent
-        print(f"[obs] profiler start failed ({e!r}); continuing unprofiled",
-              file=sys.stderr)
-        return False
-
-
-def _stop() -> None:
-    try:
-        import jax
-
-        jax.profiler.stop_trace()
-    except Exception as e:  # pragma: no cover - environment dependent
-        print(f"[obs] profiler stop failed ({e!r})", file=sys.stderr)
 
 
 @contextlib.contextmanager
@@ -47,12 +26,12 @@ def trace_window(log_dir: str):
     if not log_dir:
         yield False
         return
-    started = _start(log_dir)
+    import jax
+    jax.profiler.start_trace(log_dir)
     try:
-        yield started
+        yield True
     finally:
-        if started:
-            _stop()
+        jax.profiler.stop_trace()
 
 
 class StepTraceWindow:
@@ -71,7 +50,9 @@ class StepTraceWindow:
     def start(self) -> None:
         if not self.enabled or self._active:
             return
-        self._active = _start(self.log_dir)
+        import jax
+        jax.profiler.start_trace(self.log_dir)
+        self._active = True
         self._remaining = self.steps
 
     def on_step(self, engine=None) -> None:
@@ -83,5 +64,6 @@ class StepTraceWindow:
 
     def stop(self) -> None:
         if self._active:
-            _stop()
+            import jax
+            jax.profiler.stop_trace()
             self._active = False

@@ -49,6 +49,7 @@ shared-prefix, and overload/preemption traces) into
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -69,6 +70,25 @@ from repro.serving.scheduler import (AdmittedBatch, DuplicateRequestError,
                                      GenerationRequest, GenerationResult,
                                      InvalidRequestError, QueueFullError,
                                      RequestStatus, ResumeTicket, Scheduler)
+
+
+def step_jit(fn, **jit_kwargs):
+    """``jax.jit(fn)`` with every dot traced inside at float32 precision
+    (``"highest"``): XLA's dots and those inside the Pallas kernels
+    (``dequant_matmul``, ``flash_decode``), which read the same default when
+    they are traced. On TPU that is several MXU passes per f32 dot.
+
+    At TPU default precision XLA rounds f32 dot operands to bf16 on the MXU
+    but computes a one-row dot in f32, so a request's logits would depend
+    on how many rows share its batch (the prefill batch bucket, the static
+    path's batch of one) and greedy tokens could flip on near-ties. Every
+    serving step program, the engine's and the static path's, is built
+    here."""
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return jax.jit(traced, **jit_kwargs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -383,9 +403,9 @@ class Engine:
                              ok.astype(jnp.int32)], axis=-1)
             return out, {"k": cache["k"], "v": cache["v"]}
 
-        return (jax.jit(prefill_fn, donate_argnums=1),
-                jax.jit(chunk_fn, donate_argnums=1),
-                jax.jit(decode_fn, donate_argnums=1))
+        return (step_jit(prefill_fn, donate_argnums=1),
+                step_jit(chunk_fn, donate_argnums=1),
+                step_jit(decode_fn, donate_argnums=1))
 
     def _make_paged_step_fns(self, mini_dtype, model):
         """Paged mirrors of the three step programs. Prefill keeps the
@@ -437,9 +457,9 @@ class Engine:
                              ok.astype(jnp.int32)], axis=-1)
             return out, {"k": cache["k"], "v": cache["v"]}
 
-        return (jax.jit(prefill_fn, donate_argnums=1),
-                jax.jit(chunk_fn, donate_argnums=1),
-                jax.jit(decode_fn, donate_argnums=1))
+        return (step_jit(prefill_fn, donate_argnums=1),
+                step_jit(chunk_fn, donate_argnums=1),
+                step_jit(decode_fn, donate_argnums=1))
 
     # -- request API -------------------------------------------------------
     def submit(self, req: GenerationRequest) -> None:
@@ -604,17 +624,8 @@ class Engine:
         # it whenever a trace could hit it
         if chunked or (self._paged and self.prefix is not None
                        and (seen or chunked)):
-            # one dummy chunk compiles the (single) chunk program; on the
-            # slot path the garbage it writes into slot 0 sits beyond every
-            # causal mask until the slot's next prefill overwrites it (the
-            # engine is idle); on the paged path an all-sentinel table row
-            # drops the writes outright
-            route = (jnp.full((1, self.pages_per_slot), self.alloc.num_pages,
-                              jnp.int32) if self._paged else np.int32(0))
-            tok_dev, self.kv = self._chunk(
-                self.params, self.kv, jnp.zeros((1, wmax), jnp.int32),
-                np.int32(0), np.int32(1), route, np.float32(0.0),
-                np.int32(0), np.uint32(0))
+            # one dummy chunk compiles the (single) chunk program
+            tok_dev, self.kv = self._chunk(*self._dummy_chunk_args())
 
         # end-to-end clones (decode program + host bookkeeping paths)
         wid = -1
@@ -643,6 +654,25 @@ class Engine:
         self._reset_counters()
         self.set_faults(plan)
         return self.compile_counts()
+
+    def _dummy_chunk_args(self) -> tuple:
+        """Chunk-program arguments that write nothing live: on the slot
+        path the garbage lands in slot 0 beyond every causal mask until the
+        slot's next prefill overwrites it (the engine is idle); on the
+        paged path an all-sentinel table row drops the writes outright."""
+        route = (jnp.full((1, self.pages_per_slot), self.alloc.num_pages,
+                          jnp.int32) if self._paged else np.int32(0))
+        return (self.params, self.kv,
+                jnp.zeros((1, self.scheduler.buckets[-1]), jnp.int32),
+                np.int32(0), np.int32(1), route, np.float32(0.0),
+                np.int32(0), np.uint32(0))
+
+    def _decode_args(self) -> tuple:
+        args = (self.params, self.kv, jnp.asarray(self._pos),
+                jnp.asarray(self._tok[:, None]), jnp.asarray(self._temps),
+                jnp.asarray(self._topks), jnp.asarray(self._seeds),
+                jnp.asarray(self._steps))
+        return args + ((jnp.asarray(self._table),) if self._paged else ())
 
     def step(self) -> None:
         """Admit every admissible request (one batched prefill dispatch per
@@ -683,17 +713,7 @@ class Engine:
             self._extend_for_decode()
             if sched.num_active == 0:      # extension self-preempted all
                 return
-            out_dev, self.kv = self._decode(
-                self.params, self.kv, jnp.asarray(self._pos),
-                jnp.asarray(self._tok[:, None]), jnp.asarray(self._temps),
-                jnp.asarray(self._topks), jnp.asarray(self._seeds),
-                jnp.asarray(self._steps), jnp.asarray(self._table))
-        else:
-            out_dev, self.kv = self._decode(
-                self.params, self.kv, jnp.asarray(self._pos),
-                jnp.asarray(self._tok[:, None]), jnp.asarray(self._temps),
-                jnp.asarray(self._topks), jnp.asarray(self._seeds),
-                jnp.asarray(self._steps))
+        out_dev, self.kv = self._decode(*self._decode_args())
         out = np.asarray(out_dev)  # lint: allow[host-sync] THE one transfer per decode step (S, 2): token + finite flag
         toks, finite = out[:, 0], out[:, 1]
         now = self._now()
@@ -1287,22 +1307,25 @@ class Engine:
         return True
 
     # -- introspection -----------------------------------------------------
-    def compile_counts(self) -> Dict[str, Optional[int]]:
+    def compile_counts(self) -> Dict[str, int]:
         """Compiled-program counts (prefill: one per (prompt bucket, batch
         bucket) pair seen; chunk: 1 when the trace has beyond-largest-bucket
         prompts; decode: 1). Flat across a post-warmup trace ⇔ no
-        recompilation. ``None`` when the jit cache size is unavailable
-        (private jax API moved) — callers must treat that as UNKNOWN, never
-        as "no recompilation". The paged spill/restore gathers compile
-        lazily at the first preemption (O(log max_pages) programs, bounded
-        by the pow2 padding) and are not tracked here."""
-        def size(f) -> Optional[int]:
-            try:
-                return int(f._cache_size())
-            except Exception:
-                return None
-        return {"prefill": size(self._prefill), "chunk": size(self._chunk),
-                "decode": size(self._decode)}
+        recompilation. The paged spill/restore gathers compile lazily at
+        the first preemption (O(log max_pages) programs, bounded by the
+        pow2 padding) and are not tracked here."""
+        return {"prefill": self._prefill._cache_size(),
+                "chunk": self._chunk._cache_size(),
+                "decode": self._decode._cache_size()}
+
+    def lowered_text(self, program: str) -> str:
+        """StableHLO text of the ``"decode"`` or ``"chunk"`` program at
+        this engine's shapes, lowered from its current state without
+        running it. Pallas kernels show up as ``tpu_custom_call`` ops with
+        their ``kernel_name`` when lowered for TPU."""
+        fn, args = {"decode": (self._decode, self._decode_args),
+                    "chunk": (self._chunk, self._dummy_chunk_args)}[program]
+        return fn.lower(*args()).as_text()
 
     def kv_cache_bytes(self) -> int:
         return cache_bytes(self.kv)

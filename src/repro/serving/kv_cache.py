@@ -7,9 +7,11 @@ donation like the rest of the model state):
 
 where ``<storage>`` is either a dense ``(L, S, T, Hk, D)`` array (``L``
 layers, ``S`` slots, ``T`` max_len) or a :class:`QuantizedKV` — INT8 codes
-plus per-(token, head, group) float16 scale/zero, groups tiling the
-head_dim axis ("per-head-group"). INT8 storage costs ``1 + 4/group`` bytes
-per element vs 2 for bf16, i.e. ~½ the resident bytes at ``group ≥ 32``.
+plus a per-(token, head, group) float32 scale and uint8 zero-point, groups
+tiling the head_dim axis ("per-head-group"). INT8 storage costs
+``1 + 5/group`` bytes per element vs 2 for bf16, i.e. ~½ the resident bytes
+at ``group ≥ 32``. The planes are float32/uint8 because the TPU kernels
+cannot load float16.
 
 Reads dequantize at the attention boundary (``models/layers.attn_apply``):
 the reference path is pure jnp; on TPU the Pallas ``kv_dequant`` kernel
@@ -36,8 +38,9 @@ from repro.quant.qtensor import resolved_impl
 class QuantizedKV(NamedTuple):
     """INT8 cache storage: codes + per-(…, head, group) affine params.
 
-    ``codes``: (..., T, Hk, D) uint8; ``scale``/``zero``: (..., T, Hk, D/g)
-    float16. ``group_size`` is static (pytree aux), so QuantizedKV leaves
+    ``codes``: (..., T, Hk, D) uint8; ``scale``: (..., T, Hk, D/g) float32;
+    ``zero``: (..., T, Hk, D/g) uint8 (the zero-point is an integer code).
+    ``group_size`` is static (pytree aux), so QuantizedKV leaves
     scan/stack/donate like dense arrays with the group layout baked in.
     """
     codes: jax.Array
@@ -62,13 +65,14 @@ jax.tree_util.register_pytree_node(
 # ---------------------------------------------------------------------------
 
 def kv_quantize(x: jax.Array, group_size: int) -> QuantizedKV:
-    """x: (..., D) float → codes (..., D) uint8 + scale/zero (..., D/g) f16.
+    """x: (..., D) float → codes (..., D) uint8 + scale (..., D/g) f32 and
+    zero (..., D/g) uint8.
 
     Asymmetric min/max over each head_dim group, with the grid stretched to
     include 0 (the ONNX convention) so the zero-point is always exactly
     representable — one-sided groups (e.g. a constant bias channel) round-
     trip instead of collapsing, and zero-initialized cache rows stay
-    exactly zero. Scales are clamped to a float16-safe minimum."""
+    exactly zero. Scales are clamped to a small positive minimum."""
     d = x.shape[-1]
     assert d % group_size == 0, (d, group_size)
     g = x.reshape(*x.shape[:-1], d // group_size, group_size).astype(jnp.float32)
@@ -79,8 +83,8 @@ def kv_quantize(x: jax.Array, group_size: int) -> QuantizedKV:
     codes = jnp.clip(jnp.round(g / scale[..., None]) + zero[..., None],
                      0.0, 255.0).astype(jnp.uint8)
     return QuantizedKV(codes=codes.reshape(*x.shape[:-1], d),
-                       scale=scale.astype(jnp.float16),
-                       zero=zero.astype(jnp.float16),
+                       scale=scale,
+                       zero=zero.astype(jnp.uint8),
                        group_size=group_size)
 
 
@@ -198,8 +202,8 @@ def init_slot_cache(model_cfg, cfg: KVCacheConfig) -> dict:
         assert model_cfg.resolved_head_dim % g == 0, (shape, g)
         store = QuantizedKV(
             codes=jnp.zeros(shape, jnp.uint8),
-            scale=jnp.full(shape[:-1] + (shape[-1] // g,), 1e-4, jnp.float16),
-            zero=jnp.zeros(shape[:-1] + (shape[-1] // g,), jnp.float16),
+            scale=jnp.full(shape[:-1] + (shape[-1] // g,), 1e-4, jnp.float32),
+            zero=jnp.zeros(shape[:-1] + (shape[-1] // g,), jnp.uint8),
             group_size=g)
         k = store
         v = QuantizedKV(jnp.zeros_like(store.codes),
@@ -291,8 +295,8 @@ def init_paged_storage(model_cfg, num_pages: int, page_size: int,
         assert model_cfg.resolved_head_dim % g == 0, (shape, g)
         k = QuantizedKV(
             codes=jnp.zeros(shape, jnp.uint8),
-            scale=jnp.full(shape[:-1] + (shape[-1] // g,), 1e-4, jnp.float16),
-            zero=jnp.zeros(shape[:-1] + (shape[-1] // g,), jnp.float16),
+            scale=jnp.full(shape[:-1] + (shape[-1] // g,), 1e-4, jnp.float32),
+            zero=jnp.zeros(shape[:-1] + (shape[-1] // g,), jnp.uint8),
             group_size=g)
         v = QuantizedKV(jnp.zeros_like(k.codes), jnp.full_like(k.scale, 1e-4),
                         jnp.zeros_like(k.zero), g)

@@ -10,7 +10,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.optim.optimizers import OptimizerConfig, make_optimizer
 from repro.optim import grad_compress
 from repro.sharding import ShardingRules, NO_RULES
@@ -138,7 +137,7 @@ def make_train_step_ddp(model, tcfg: TrainConfig, rules: ShardingRules, *,
         batch_spec = jax.tree.map(lambda _: P(dp_axes), batch)
         rep = jax.tree.map(lambda _: P(), state["params"])
         opt_spec = jax.tree.map(lambda _: P(), state["opt"])
-        params2, opt2, step2, loss, gn = compat.shard_map(
+        params2, opt2, step2, loss, gn = jax.shard_map(
             shard_body, mesh=rules.mesh,
             in_specs=(rep, opt_spec, P(), batch_spec),
             out_specs=(rep, opt_spec, P(), P(), P()),

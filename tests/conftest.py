@@ -101,9 +101,7 @@ def assert_flat_compiles():
             engine.run()
 
     Compares ``engine.compile_counts()`` after the block against the
-    baseline (default: counts on entry) per program kind; ``None`` counts
-    (cache introspection unavailable on some backends) are skipped, same
-    as the historical ad-hoc assertions in test_obs.py."""
+    baseline (default: counts on entry) per program kind."""
     import contextlib
 
     @contextlib.contextmanager
@@ -117,8 +115,6 @@ def assert_flat_compiles():
             f"{sorted(after)}")
         for kind, n in after.items():
             want = before[kind]
-            if n is None or want is None:
-                continue
             assert n == want, (
                 f"post-warmup recompile: {kind} compiled {n} time(s), "
                 f"expected {want}")

@@ -1,0 +1,123 @@
+"""AOT compile guards: the main-path Pallas kernels (and ``topk_mask``) at
+llama32-1b widths, compiled by the TPU compiler for a described (not
+attached) v5e chip.
+
+Interpret mode accepts block shapes, casts and reshapes that Mosaic refuses;
+these compiles catch that without a chip. The topology is described inside
+a fixture (never at import, in ``skipif`` or in ``parametrize``) so every
+test worker collects the same tests and only the worker that runs this file
+loads the TPU compiler. The persistent compilation cache is off around the
+compiles: entries for a described device cannot be read back here.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels import (awp_pgd, decode_attn, dequant_matmul, kv_dequant,
+                           topk_mask)
+
+CFG = get_config("llama32-1b")
+D, F = CFG.d_model, CFG.d_ff                     # 2048, 8192
+H, HK, HD = CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
+SLOTS, T, PAGE = 8, 1024, 16
+PAGES = SLOTS * T // PAGE
+GROUP = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _pgd(batched: bool):
+    if batched:       # gate/up bucket: B=2 items of (d_ff, d_model)
+        shapes = [((2, F, D),), ((2, F, D),), ((2, D, D),), ((2,),)]
+        return (lambda w, t, c, e: awp_pgd.awp_pgd_step(
+            w, t, c, e, with_resid_norm=True)), shapes
+    # down projection: the bucket of one that runs the 2-D kernel
+    return (lambda w, t, c: awp_pgd.awp_pgd_step(
+        w, t, c, 0.1, with_resid_norm=True)), [((D, F),), ((D, F),),
+                                                ((F, F),)]
+
+
+def _dequant(m: int, n: int, k: int):
+    return (lambda x, p, s, z: dequant_matmul.dequant_matmul(
+        x, p, s, z, group_size=GROUP)), [
+        ((m, k),), ((n, k // 2), jnp.uint8), ((n, k // GROUP),),
+        ((n, k // GROUP),)]
+
+
+def _flash(paged: bool, int8: bool):
+    lead = (PAGES, PAGE) if paged else (SLOTS, T)
+    kv = [(lead + (HK, HD), jnp.uint8 if int8 else jnp.float32)]
+    if int8:          # one group per head: f32 scale, uint8 zero-point
+        kv += [(lead + (HK, 1),), (lead + (HK, 1), jnp.uint8)]
+    shapes = [((SLOTS, H, HD),)] + kv + kv + [((SLOTS,), jnp.int32)]
+    if paged:
+        shapes.append(((SLOTS, T // PAGE), jnp.int32))
+
+    def fn(q, *rest):
+        table = rest[-1] if paged else None
+        lengths = rest[-2] if paged else rest[-1]
+        planes = rest[:len(kv) * 2]
+        if int8:
+            kc, ks, kz, vc, vs, vz = planes
+            return decode_attn.flash_decode(
+                q, kc, vc, lengths, k_scale=ks, k_zero=kz, v_scale=vs,
+                v_zero=vz, group_size=HD, table=table)
+        return decode_attn.flash_decode(q, planes[0], planes[1], lengths,
+                                        table=table)
+    return fn, shapes
+
+
+def _kv_dequant():
+    rows = SLOTS * 256
+    return (lambda c, s, z: kv_dequant.kv_dequant(c, s, z, group_size=HD)), [
+        ((rows, HK * HD), jnp.uint8), ((rows, HK),), ((rows, HK), jnp.uint8)]
+
+
+CASES = {
+    "awp_pgd_2d": lambda: _pgd(False),
+    "awp_pgd_batched": lambda: _pgd(True),
+    **{f"dequant_matmul_m{m}_{n}x{k}": (lambda m=m, n=n, k=k:
+                                         _dequant(m, n, k))
+       for m in (8, 512) for n, k in ((D, D), (F, D), (D, F))},
+    "flash_decode_slot_dense": lambda: _flash(False, False),
+    "flash_decode_slot_int8": lambda: _flash(False, True),
+    "flash_decode_paged_dense": lambda: _flash(True, False),
+    "flash_decode_paged_int8": lambda: _flash(True, True),
+    "kv_dequant": _kv_dequant,
+    "topk_mask": lambda: ((lambda z: topk_mask.topk_row(z, F // 2)),
+                          [((D, F),)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s[0], s[1] if len(s) > 1 else jnp.float32,
+                                 sharding=one_chip) for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

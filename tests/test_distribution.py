@@ -24,7 +24,6 @@ def test_spec_divisibility_fallback():
     run_multidevice("""
 import jax
 from repro.launch.mesh import make_mesh
-from repro import compat
 from repro.sharding import ShardingRules
 from jax.sharding import PartitionSpec as P
 mesh = make_mesh((2, 4), ("data", "model"))
@@ -46,14 +45,13 @@ from repro.configs import get_tiny_config
 from repro.models.moe import moe_params, moe_apply_dense, moe_apply_a2a
 from repro.sharding import ShardingRules
 from repro.launch.mesh import make_mesh
-from repro import compat
 cfg = dataclasses.replace(get_tiny_config("qwen3-moe-235b-a22b"), capacity_factor=8.0)
 mesh = make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules.for_mesh(mesh)
 p = moe_params(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 64, cfg.d_model))
 y_dense = moe_apply_dense(p, x, cfg)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_a2a = jax.jit(lambda p, x: moe_apply_a2a(p, x, cfg, rules))(p, x)
 np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_a2a), rtol=2e-4, atol=2e-4)
 print("ok")
@@ -66,7 +64,6 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import distributed as dist, projections as proj
 from repro.sharding import ShardingRules
 from repro.launch.mesh import make_mesh
-from repro import compat
 mesh = make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules.for_mesh(mesh)
 rng = np.random.default_rng(0)
@@ -77,7 +74,7 @@ k, eta, iters = 32, float(2.0 / jnp.linalg.norm(c)), 10
 ref = dist.awp_prune_rowsharded_fn(k, eta, iters)(w, c)
 row = dist.awp_prune_rowsharded(w, c, k, eta, iters, rules)
 np.testing.assert_allclose(np.asarray(row), np.asarray(ref), rtol=2e-4, atol=2e-4)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     col = jax.jit(dist.awp_prune_colsharded_fn(k, eta, iters, rules))(w, c)
 np.testing.assert_allclose(np.asarray(col), np.asarray(ref), rtol=2e-4, atol=2e-4)
 print("ok")
@@ -90,13 +87,12 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import distributed as dist
 from repro.sharding import ShardingRules
 from repro.launch.mesh import make_mesh
-from repro import compat
 mesh = make_mesh((8,), ("data",))
 rules = ShardingRules.for_mesh(mesh)
 rng = np.random.default_rng(0)
 a = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
 ref = np.asarray(a.T @ a / 64)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     c = jax.jit(lambda a: dist.calib_c_distributed(a, rules))(a)
 np.testing.assert_allclose(np.asarray(c), ref, rtol=1e-4, atol=1e-5)
 print("ok")
@@ -112,7 +108,6 @@ from repro.training.train_loop import TrainConfig, make_train_step_ddp
 from repro.optim import OptimizerConfig
 from repro.sharding import ShardingRules
 from repro.launch.mesh import make_mesh
-from repro import compat
 mesh = make_mesh((8,), ("data",))
 rules = ShardingRules.for_mesh(mesh)
 cfg = get_tiny_config("granite-8b")
@@ -123,7 +118,7 @@ params = model.init(jax.random.PRNGKey(0))
 state = {"params": params, "opt": opt_init(params), "step": jnp.zeros((), jnp.int32)}
 from repro.data import DataConfig, ZipfMarkov
 gen = ZipfMarkov(DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=16))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     jstep = jax.jit(step_fn)
     losses = []
     for i in range(25):
@@ -141,7 +136,6 @@ import jax, jax.numpy as jnp, numpy as np, tempfile, os
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save_checkpoint, restore_checkpoint
 from repro.launch.mesh import make_mesh
-from repro import compat
 rng = np.random.default_rng(0)
 tree = {"w": jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)}
 mesh1 = make_mesh((2, 4), ("data", "model"))
@@ -170,7 +164,6 @@ from repro.training.train_loop import TrainConfig, make_train_step
 from repro.optim import OptimizerConfig
 from repro.sharding import ShardingRules, tree_shardings
 from repro.launch.mesh import make_mesh
-from repro import compat
 cfg = get_tiny_config("granite-8b")
 mesh = make_mesh((2, 2), ("data", "model"))
 rules = ShardingRules.for_mesh(mesh)
@@ -184,7 +177,7 @@ step_s, opt_init = make_train_step(m_sharded, tcfg)
 step_p, _ = make_train_step(m_plain, tcfg)
 state = {"params": params, "opt": opt_init(params), "step": jnp.zeros((), jnp.int32)}
 s_plain, mp = jax.jit(step_p)(state, batch)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     p_sh = tree_shardings(rules, m_sharded.param_logical_axes(),
                           jax.eval_shape(m_sharded.init, key))
     sp = jax.device_put(params, p_sh)
@@ -212,7 +205,6 @@ from repro.models.moe import moe_params, moe_apply_dense, moe_apply_a2a, moe_app
 from repro.sharding import ShardingRules
 from repro.launch.mesh import make_mesh
 from repro.quant import QTensor
-from repro import compat
 cfg = dataclasses.replace(get_tiny_config("qwen3-moe-235b-a22b"), capacity_factor=8.0)
 mesh = make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules.for_mesh(mesh)
@@ -223,7 +215,7 @@ def pack(w):   # (E, d_in, d_out) dense stack -> stacked per-expert QTensor
 pq = dict(p, wu=pack(p["wu"]), wd=pack(p["wd"]), wg=pack(p["wg"]))
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 64, cfg.d_model))
 y_dense = moe_apply_dense(pq, x, cfg)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_a2a = jax.jit(lambda p, x: moe_apply_a2a(p, x, cfg, rules))(pq, x)
     y_auto = jax.jit(lambda p, x: moe_apply(p, x, cfg, rules))(pq, x)
 np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_a2a), rtol=2e-4, atol=2e-4)

@@ -34,7 +34,6 @@ def test_production_mesh_and_tiny_cell_lowering():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.mesh import make_production_mesh, make_mesh
-from repro import compat
 from repro.configs import get_tiny_config
 from repro.models import build_model, batch_specs
 from repro.sharding import rules_for_cell, tree_shardings
@@ -62,7 +61,7 @@ state_sds = {"params": p_sds, "opt": o_sds, "step": jax.ShapeDtypeStruct((), jnp
 state_sh = {"params": p_sh, "opt": o_sh, "step": NamedSharding(mesh, P())}
 b_sds = batch_specs(cfg, 8, 16)
 b_sh = {k: NamedSharding(mesh, P(("data",))) for k in b_sds}
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(step_fn, in_shardings=(state_sh, b_sh),
                        donate_argnums=0).lower(state_sds, b_sds).compile()
 mem = compiled.memory_analysis()

@@ -389,6 +389,32 @@ def test_engine_chunked_int8_cache_completes(tiny_lm):
         r.max_new_tokens for r in reqs)
 
 
+@pytest.mark.parametrize("layout", ["slots", "paged"])
+def test_engine_lowered_programs_run_dots_at_f32(tiny_lm, layout):
+    """``lowered_text`` lowers the decode and chunk programs at the engine's
+    own shapes without running or recompiling them, and every dot in them
+    is traced at float32 precision (``step_jit``), so no row's logits
+    depend on how many rows share its batch."""
+    cfg, model, params = tiny_lm
+    reqs = _requests(cfg, lens=[20, 5], gens=[3, 2])
+    engine = Engine(model, params,
+                    EngineConfig(num_slots=2, max_len=32,
+                                 prompt_buckets=(8, 16), kv_layout=layout,
+                                 page_size=8))
+    compiled = engine.warmup(reqs)
+    for program in ("decode", "chunk"):
+        dots = [line for line in engine.lowered_text(program).splitlines()
+                if "stablehlo.dot_general" in line]
+        assert dots, program
+        assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots), \
+            program
+    assert engine.compile_counts() == compiled
+    for r in reqs:
+        engine.submit(r)
+    assert all(r.ok for r in engine.run())
+    assert engine.compile_counts() == compiled
+
+
 def test_engine_warmup_guards_non_idle(tiny_lm):
     """warmup() drains the scheduler, so calling it with live submissions
     would silently execute and discard them — it must raise instead, and

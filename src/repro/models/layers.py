@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs.spans import scope
 from repro.quant import QTensor
 from repro.serving.kv_cache import (QuantizedKV, fused_decode_attn,
                                     kv_dequantize, kv_update, kv_quantize,
@@ -393,125 +394,138 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     h, hk = cfg.num_heads, cfg.num_kv_heads
-    xn = rmsnorm(x, p["norm"], cfg.norm_eps)
-    if capture is not None:
-        capture["attn_in"] = xn
-    q = linear_apply(p["wq"], xn).reshape(b, s, h, hd)
-    k = linear_apply(p["wk"], xn).reshape(b, s, hk, hd)
-    v = linear_apply(p["wv"], xn).reshape(b, s, hk, hd)
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    q = hint(q, rules, ("batch", None, "tp", None))
-    k = hint(k, rules, ("batch", None, None, None))
+    with scope("attn_in"):
+        xn = rmsnorm(x, p["norm"], cfg.norm_eps)
+        if capture is not None:
+            capture["attn_in"] = xn
+        q = linear_apply(p["wq"], xn).reshape(b, s, h, hd)
+        k = linear_apply(p["wk"], xn).reshape(b, s, hk, hd)
+        v = linear_apply(p["wv"], xn).reshape(b, s, hk, hd)
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        q = hint(q, rules, ("batch", None, "tp", None))
+        k = hint(k, rules, ("batch", None, None, None))
 
     if kv_cache is None:
-        out = flash_attention(q, k, v, causal=True, q_chunk=attn_chunk,
-                              kv_chunk=attn_chunk, p_dtype=attn_p_dtype)
+        with scope("attn"):
+            out = flash_attention(q, k, v, causal=True, q_chunk=attn_chunk,
+                                  kv_chunk=attn_chunk, p_dtype=attn_p_dtype)
         new_kv = (k, v)
     elif block_table is not None:
         k_cache, v_cache = kv_cache                  # pools (P, page, Hk, D)
         page = (k_cache.codes if isinstance(k_cache, QuantizedKV)
                 else k_cache).shape[1]
-        k_cache = paged_write(k_cache, block_table, cache_pos, k, page)
-        v_cache = paged_write(v_cache, block_table, cache_pos, v, page)
-        if s == 1:
-            if fused_decode:
-                out = fused_decode_attn(q, k_cache, v_cache, positions,
-                                        table=block_table)
+        with scope("kv_write"):
+            k_cache = paged_write(k_cache, block_table, cache_pos, k, page)
+            v_cache = paged_write(v_cache, block_table, cache_pos, v, page)
+        with scope("attn"):
+            if s == 1:
+                if fused_decode:
+                    out = fused_decode_attn(q, k_cache, v_cache, positions,
+                                            table=block_table)
+                else:
+                    k_r = paged_view(k_cache, block_table)
+                    v_r = paged_view(v_cache, block_table)
+                    if isinstance(k_r, QuantizedKV):
+                        k_r = kv_dequantize(k_r, q.dtype)
+                        v_r = kv_dequantize(v_r, q.dtype)
+                    out = decode_attention(q, k_r, v_r, positions, rules,
+                                           p_dtype=attn_p_dtype)
             else:
-                k_r = paged_view(k_cache, block_table)
-                v_r = paged_view(v_cache, block_table)
-                if isinstance(k_r, QuantizedKV):
-                    k_r = kv_dequantize(k_r, q.dtype)
-                    v_r = kv_dequantize(v_r, q.dtype)
-                out = decode_attention(q, k_r, v_r, positions, rules,
-                                       p_dtype=attn_p_dtype)
-        else:
-            assert attend_cache, \
-                "paged s > 1 is the chunked-prefill contract (batched " \
-                "prefill fills a dense mini-cache, then write_pages)"
-            out = flash_attention(q, k_cache, v_cache, causal=True,
-                                  q_offset=cache_pos, q_chunk=attn_chunk,
-                                  kv_chunk=attn_chunk, p_dtype=attn_p_dtype,
-                                  kv_pages=(block_table, page))
+                assert attend_cache, \
+                    "paged s > 1 is the chunked-prefill contract (batched " \
+                    "prefill fills a dense mini-cache, then write_pages)"
+                out = flash_attention(q, k_cache, v_cache, causal=True,
+                                      q_offset=cache_pos, q_chunk=attn_chunk,
+                                      kv_chunk=attn_chunk,
+                                      p_dtype=attn_p_dtype,
+                                      kv_pages=(block_table, page))
         new_kv = (k_cache, v_cache)
     else:
         k_cache, v_cache = kv_cache                  # (B, Smax, Hk, D)
-        if isinstance(k_cache, QuantizedKV):
-            k_cache = kv_update(k_cache, k, cache_pos)
-            v_cache = kv_update(v_cache, v, cache_pos)
-        elif getattr(cache_pos, "ndim", 0) == 1:     # per-slot positions
-            assert s == 1, "per-slot cache writes are one token per step"
-            rows = jnp.arange(b)
-            k_cache = k_cache.at[rows, cache_pos].set(
-                k[:, 0].astype(k_cache.dtype))
-            v_cache = v_cache.at[rows, cache_pos].set(
-                v[:, 0].astype(v_cache.dtype))
-        elif attend_cache:
-            # chunked prefill: per-column scatter so a final chunk's padded
-            # tail past the cache edge is dropped, never shifted back onto
-            # live rows like dynamic_update_slice would
-            cols = cache_pos + jnp.arange(s)
-            k_cache = k_cache.at[:, cols].set(k.astype(k_cache.dtype))
-            v_cache = v_cache.at[:, cols].set(v.astype(v_cache.dtype))
-        else:
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                k_cache, k.astype(k_cache.dtype), cache_pos, axis=1)
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                v_cache, v.astype(v_cache.dtype), cache_pos, axis=1)
-        if s > 1 and attend_cache:
-            # chunked prefill: attend the cache rows (which now include
-            # this chunk's K/V) under the offset causal mask — flash with
-            # q_offset keeps per-query numerics bit-compatible with the
-            # fresh-prefill path, so chunked greedy output matches the
-            # static path exactly on dense f32 caches
+        with scope("kv_write"):
             if isinstance(k_cache, QuantizedKV):
-                k_r = kv_dequantize(k_cache, q.dtype)
-                v_r = kv_dequantize(v_cache, q.dtype)
+                k_cache = kv_update(k_cache, k, cache_pos)
+                v_cache = kv_update(v_cache, v, cache_pos)
+            elif getattr(cache_pos, "ndim", 0) == 1:  # per-slot positions
+                assert s == 1, "per-slot cache writes are one token per step"
+                rows = jnp.arange(b)
+                k_cache = k_cache.at[rows, cache_pos].set(
+                    k[:, 0].astype(k_cache.dtype))
+                v_cache = v_cache.at[rows, cache_pos].set(
+                    v[:, 0].astype(v_cache.dtype))
+            elif attend_cache:
+                # chunked prefill: per-column scatter so a final chunk's
+                # padded tail past the cache edge is dropped, never shifted
+                # back onto live rows like dynamic_update_slice would
+                cols = cache_pos + jnp.arange(s)
+                k_cache = k_cache.at[:, cols].set(k.astype(k_cache.dtype))
+                v_cache = v_cache.at[:, cols].set(v.astype(v_cache.dtype))
             else:
-                k_r, v_r = k_cache, v_cache
-            out = flash_attention(q, k_r, v_r, causal=True,
-                                  q_offset=cache_pos, q_chunk=attn_chunk,
-                                  kv_chunk=attn_chunk, p_dtype=attn_p_dtype)
-        elif s > 1:
-            # prefill: flash attention over the new tokens (assumes
-            # cache_pos == 0 — the serving manager's convention);
-            # decode_attention here would materialize (B,H,S,Smax) scores.
-            out = flash_attention(q, k, v, causal=True, q_chunk=attn_chunk,
-                                  kv_chunk=attn_chunk, p_dtype=attn_p_dtype)
-        elif fused_decode:
-            out = fused_decode_attn(q, k_cache, v_cache, positions)
-        else:
-            if isinstance(k_cache, QuantizedKV):
-                k_r = kv_dequantize(k_cache, q.dtype)
-                v_r = kv_dequantize(v_cache, q.dtype)
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    k_cache, k.astype(k_cache.dtype), cache_pos, axis=1)
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    v_cache, v.astype(v_cache.dtype), cache_pos, axis=1)
+        with scope("attn"):
+            if s > 1 and attend_cache:
+                # chunked prefill: attend the cache rows (which now include
+                # this chunk's K/V) under the offset causal mask — flash
+                # with q_offset keeps per-query numerics bit-compatible with
+                # the fresh-prefill path, so chunked greedy output matches
+                # the static path exactly on dense f32 caches
+                if isinstance(k_cache, QuantizedKV):
+                    k_r = kv_dequantize(k_cache, q.dtype)
+                    v_r = kv_dequantize(v_cache, q.dtype)
+                else:
+                    k_r, v_r = k_cache, v_cache
+                out = flash_attention(q, k_r, v_r, causal=True,
+                                      q_offset=cache_pos, q_chunk=attn_chunk,
+                                      kv_chunk=attn_chunk,
+                                      p_dtype=attn_p_dtype)
+            elif s > 1:
+                # prefill: flash attention over the new tokens (assumes
+                # cache_pos == 0 — the serving manager's convention);
+                # decode_attention here would materialize (B,H,S,Smax)
+                # scores.
+                out = flash_attention(q, k, v, causal=True,
+                                      q_chunk=attn_chunk, kv_chunk=attn_chunk,
+                                      p_dtype=attn_p_dtype)
+            elif fused_decode:
+                out = fused_decode_attn(q, k_cache, v_cache, positions)
             else:
-                k_r, v_r = k_cache, v_cache
-            out = decode_attention(q, k_r, v_r, positions, rules,
-                                   p_dtype=attn_p_dtype)
+                if isinstance(k_cache, QuantizedKV):
+                    k_r = kv_dequantize(k_cache, q.dtype)
+                    v_r = kv_dequantize(v_cache, q.dtype)
+                else:
+                    k_r, v_r = k_cache, v_cache
+                out = decode_attention(q, k_r, v_r, positions, rules,
+                                       p_dtype=attn_p_dtype)
         new_kv = (k_cache, v_cache)
 
-    out = hint(out, rules, ("batch", None, "tp", None))
-    if capture is not None:
-        capture["attn_out_in"] = out.reshape(b, s, h * hd)
-    y = linear_apply(p["wo"], out.reshape(b, s, h * hd))
-    return y.astype(x.dtype), new_kv
+    with scope("attn_out"):
+        out = hint(out, rules, ("batch", None, "tp", None))
+        if capture is not None:
+            capture["attn_out_in"] = out.reshape(b, s, h * hd)
+        y = linear_apply(p["wo"], out.reshape(b, s, h * hd))
+        return y.astype(x.dtype), new_kv
 
 
 def mlp_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *, capture=None):
-    xn = rmsnorm(x, p["norm"], cfg.norm_eps)
-    if capture is not None:
-        capture["mlp_in"] = xn
-    if cfg.mlp_act == "silu":
-        hdn = mlp_act(linear_apply(p["wg"], xn), "silu") * linear_apply(p["wu"], xn)
-    else:
-        hdn = mlp_act(linear_apply(p["wu"], xn), cfg.mlp_act)
-    hdn = hint(hdn, rules, ("batch", None, "tp"))
-    if capture is not None:
-        capture["mlp_down_in"] = hdn
-    return linear_apply(p["wd"], hdn).astype(x.dtype)
+    with scope("mlp"):
+        xn = rmsnorm(x, p["norm"], cfg.norm_eps)
+        if capture is not None:
+            capture["mlp_in"] = xn
+        if cfg.mlp_act == "silu":
+            hdn = (mlp_act(linear_apply(p["wg"], xn), "silu")
+                   * linear_apply(p["wu"], xn))
+        else:
+            hdn = mlp_act(linear_apply(p["wu"], xn), cfg.mlp_act)
+        hdn = hint(hdn, rules, ("batch", None, "tp"))
+        if capture is not None:
+            capture["mlp_down_in"] = hdn
+        return linear_apply(p["wd"], hdn).astype(x.dtype)
 
 
 __all__ = ["dense_init", "embed_init", "rmsnorm", "rope", "mlp_act",

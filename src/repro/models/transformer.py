@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
+from repro.obs.spans import scope
 from repro.quant import QTensor
 from repro.sharding import ShardingRules, NO_RULES, hint  # noqa: F401 (re-export)
 
@@ -40,8 +41,11 @@ def block_apply(p, x, cfg, rules=NO_RULES, *, positions=None, capture=None,
                              fused_decode=fused_decode,
                              attn_chunk=attn_chunk,
                              attn_p_dtype=attn_p_dtype)
-    x = x + a
-    x = x + L.mlp_apply(p["mlp"], x, cfg, rules, capture=capture)
+    with scope("attn_out"):
+        x = x + a
+    m = L.mlp_apply(p["mlp"], x, cfg, rules, capture=capture)
+    with scope("mlp"):
+        x = x + m
     return x, new_kv
 
 
@@ -111,14 +115,15 @@ class DenseModel:
     # -- embedding / frontend ------------------------------------------------
     def embed(self, params: Params, batch: Dict[str, jax.Array]) -> jax.Array:
         cfg = self.cfg
-        if cfg.frontend == "audio_frames":
-            h = batch["frames"].astype(self.param_dtype)   # stub frontend
-        else:
-            h = jnp.take(params["embed"], batch["tokens"], axis=0)
-            if cfg.frontend == "vision_patches":
-                patches = batch["patches"].astype(h.dtype)  # stub frontend
-                h = jnp.concatenate([patches, h], axis=1)
-        return hint(h, self.rules, ("batch", None, None))
+        with scope("embed"):
+            if cfg.frontend == "audio_frames":
+                h = batch["frames"].astype(self.param_dtype)  # stub frontend
+            else:
+                h = jnp.take(params["embed"], batch["tokens"], axis=0)
+                if cfg.frontend == "vision_patches":
+                    patches = batch["patches"].astype(h.dtype)  # stub
+                    h = jnp.concatenate([patches, h], axis=1)
+            return hint(h, self.rules, ("batch", None, None))
 
     def _block_scan(self, params, h, positions):
         cfg, rules = self.cfg, self.rules
@@ -131,13 +136,14 @@ class DenseModel:
             # are sharded over ('batch', tp-on-seq) — Megatron-SP layout;
             # cuts per-device saved activations by the TP degree (DESIGN §4)
             return hint(y, rules, ("batch", "tp", None)), None
-        if self.unroll:
-            for i in range(cfg.num_layers):
-                h, _ = body(h, self.block_slice(params, i))
+        with scope("blocks"):
+            if self.unroll:
+                for i in range(cfg.num_layers):
+                    h, _ = body(h, self.block_slice(params, i))
+                return h
+            body_fn = jax.checkpoint(body) if self.remat else body
+            h, _ = jax.lax.scan(body_fn, h, params["blocks"])
             return h
-        body_fn = jax.checkpoint(body) if self.remat else body
-        h, _ = jax.lax.scan(body_fn, h, params["blocks"])
-        return h
 
     def hidden_states(self, params, batch) -> jax.Array:
         h = self.embed(params, batch)
@@ -160,6 +166,16 @@ class DenseModel:
             return logits
         iota = jnp.arange(logits.shape[-1])
         return jnp.where(iota < v, logits, jnp.finfo(logits.dtype).min)
+
+    def _head(self, params, h, lengths=None) -> jax.Array:
+        """Logits of ``h`` (B, s, d), or of each row's last real token
+        (``lengths`` (B,)): final norm, head, pad mask."""
+        with scope("head"):
+            if lengths is not None:
+                idx = jnp.clip(lengths - 1, 0, h.shape[1] - 1)
+                h = jnp.take_along_axis(h, idx[:, None, None], axis=1)
+            h = L.rmsnorm(h, params["final_norm"], self.cfg.norm_eps)
+            return self._mask_pad(L.linear_apply(self._head_w(params), h))
 
     def logits(self, params, batch) -> jax.Array:
         return self._mask_pad(L.linear_apply(self._head_w(params),
@@ -210,19 +226,21 @@ class DenseModel:
                                         attn_chunk=self.attn_chunk,
                                         attn_p_dtype=self.attn_p_dtype)
             return y, (kc2, vc2)
-        if self.unroll:
-            kvs = []
-            for i in range(cfg.num_layers):
-                layer_kv = jax.tree.map(lambda x: x[i],
-                                        (cache["k"], cache["v"]))
-                h, kv2 = body(h, (self.block_slice(params, i),) + layer_kv)
-                kvs.append(kv2)
-            k_new, v_new = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
-        else:
-            h, (k_new, v_new) = jax.lax.scan(
-                body, h, (params["blocks"], cache["k"], cache["v"]))
-        new_cache = {"k": k_new, "v": v_new,
-                     "pos": cache["pos"] + positions.shape[1]}
+        with scope("blocks"):
+            if self.unroll:
+                kvs = []
+                for i in range(cfg.num_layers):
+                    layer_kv = jax.tree.map(lambda x: x[i],
+                                            (cache["k"], cache["v"]))
+                    h, kv2 = body(h, (self.block_slice(params, i),)
+                                  + layer_kv)
+                    kvs.append(kv2)
+                k_new, v_new = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
+            else:
+                h, (k_new, v_new) = jax.lax.scan(
+                    body, h, (params["blocks"], cache["k"], cache["v"]))
+            new_cache = {"k": k_new, "v": v_new,
+                         "pos": cache["pos"] + positions.shape[1]}
         if table is not None:
             new_cache["table"] = table
         return h, new_cache
@@ -233,15 +251,22 @@ class DenseModel:
         per-slot vector (the engine's slot cache) → (B|1, 1)."""
         return pos[:, None] if getattr(pos, "ndim", 0) == 1 else pos
 
+    def _prompt_positions(self, h, pos) -> jax.Array:
+        """Absolute positions (B, s) of prompt tokens written from cache
+        position ``pos``."""
+        b, s = h.shape[0], h.shape[1]
+        with scope("embed"):
+            return (jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+                    + self._base_positions(pos))
+
     def prefill(self, params, batch, cache):
         """Teacher-forced pass that fills the cache; returns last logits."""
         h = self.embed(params, batch)
-        b, s = h.shape[0], h.shape[1]
-        positions = (jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-                     + self._base_positions(cache["pos"]))
+        positions = self._prompt_positions(h, cache["pos"])
         h, cache = self._cached_scan(params, h, cache, positions)
-        h_last = L.rmsnorm(h[:, -1:, :], params["final_norm"], self.cfg.norm_eps)
-        return self._mask_pad(L.linear_apply(self._head_w(params), h_last)), cache
+        with scope("head"):
+            h = h[:, -1:, :]
+        return self._head(params, h), cache
 
     def prefill_at(self, params, batch, cache, lengths):
         """Prefill right-padded prompts: per-row true ``lengths`` (B,).
@@ -253,14 +278,9 @@ class DenseModel:
         the padded tail. The engine's bucketed prefill step drives this.
         """
         h = self.embed(params, batch)
-        b, s = h.shape[0], h.shape[1]
-        positions = (jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-                     + self._base_positions(cache["pos"]))
+        positions = self._prompt_positions(h, cache["pos"])
         h, cache = self._cached_scan(params, h, cache, positions)
-        idx = jnp.clip(lengths - 1, 0, s - 1)
-        h_last = jnp.take_along_axis(h, idx[:, None, None], axis=1)  # (B,1,d)
-        h_last = L.rmsnorm(h_last, params["final_norm"], self.cfg.norm_eps)
-        return self._mask_pad(L.linear_apply(self._head_w(params), h_last)), cache
+        return self._head(params, h, lengths), cache
 
     def prefill_chunk(self, params, batch, cache, lengths):
         """One fixed-width chunk of a longer prompt against a cache that
@@ -278,29 +298,24 @@ class DenseModel:
         dropped, never written onto live rows.
         """
         h = self.embed(params, batch)
-        b, s = h.shape[0], h.shape[1]
-        positions = (jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-                     + self._base_positions(cache["pos"]))
+        positions = self._prompt_positions(h, cache["pos"])
         h, cache = self._cached_scan(params, h, cache, positions,
                                      attend_cache=True)
-        idx = jnp.clip(lengths - 1, 0, s - 1)
-        h_last = jnp.take_along_axis(h, idx[:, None, None], axis=1)  # (B,1,d)
-        h_last = L.rmsnorm(h_last, params["final_norm"], self.cfg.norm_eps)
-        return self._mask_pad(L.linear_apply(self._head_w(params), h_last)), cache
+        return self._head(params, h, lengths), cache
 
     def decode_step(self, params, tokens, cache):
         """One decode step. tokens: (B, 1) int32. ``cache["pos"]`` is a
         scalar (uniform batch) or a per-slot (B,) vector (engine path)."""
-        h = jnp.take(params["embed"], tokens, axis=0)
-        b = h.shape[0]
-        pos = cache["pos"]
-        if getattr(pos, "ndim", 0) == 1:
-            positions = pos[:, None]                       # (B, 1) per-slot
-        else:
-            positions = jnp.broadcast_to(pos[None, None], (b, 1))
+        with scope("embed"):
+            h = jnp.take(params["embed"], tokens, axis=0)
+            b = h.shape[0]
+            pos = cache["pos"]
+            if getattr(pos, "ndim", 0) == 1:
+                positions = pos[:, None]                   # (B, 1) per-slot
+            else:
+                positions = jnp.broadcast_to(pos[None, None], (b, 1))
         h, cache = self._cached_scan(params, h, cache, positions)
-        h = L.rmsnorm(h, params["final_norm"], self.cfg.norm_eps)
-        return self._mask_pad(L.linear_apply(self._head_w(params), h)), cache
+        return self._head(params, h), cache
 
     # -- compression protocol ------------------------------------------------
     def num_blocks(self) -> int:

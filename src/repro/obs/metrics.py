@@ -14,7 +14,7 @@ Design notes
   bookkeeping (``queue_stats``/``page_stats``/dispatch counters) is a thin
   view over them.
 * Export: ``snapshot()`` (plain dict, JSON-serialisable), ``to_prometheus()``
-  (text exposition), ``append_jsonl(path)`` (one snapshot per line).
+  (text exposition), ``dump_json(path)`` (one pretty snapshot).
 """
 
 from __future__ import annotations
@@ -448,13 +448,6 @@ class MetricsRegistry:
                     lines.append(f"{fam.name}_sum{base} {_fmt_value(c.sum)}")
                     lines.append(f"{fam.name}_count{base} {c.count}")
         return "\n".join(lines) + "\n"
-
-    def append_jsonl(self, path: str, extra: Optional[dict] = None) -> None:
-        """Append one snapshot as a single JSON line."""
-        record = dict(extra or {})
-        record["snapshot"] = self.snapshot()
-        with open(path, "a") as f:
-            f.write(json.dumps(record) + "\n")
 
     def dump_json(self, path: str, meta: Optional[dict] = None) -> dict:
         """Write the snapshot (plus optional ``meta`` key) as pretty JSON."""

@@ -10,7 +10,9 @@ Two shapes:
 
 Both are no-ops when the directory is empty. A window that was asked for
 and cannot start raises: a measurement run must not go on unprofiled in
-silence.
+silence. Both record without the Python function tracer (it bloats the
+trace and slows the host) and with host tracer level 2, so the trace holds
+the device ops and the engine's ``engine.*`` spans (``repro.obs.spans``).
 """
 
 from __future__ import annotations
@@ -20,6 +22,14 @@ import contextlib
 __all__ = ["trace_window", "StepTraceWindow"]
 
 
+def _start(log_dir: str) -> None:
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
+
+
 @contextlib.contextmanager
 def trace_window(log_dir: str):
     """Profile the enclosed block into ``log_dir``; no-op if dir is empty."""
@@ -27,7 +37,7 @@ def trace_window(log_dir: str):
         yield False
         return
     import jax
-    jax.profiler.start_trace(log_dir)
+    _start(log_dir)
     try:
         yield True
     finally:
@@ -50,8 +60,7 @@ class StepTraceWindow:
     def start(self) -> None:
         if not self.enabled or self._active:
             return
-        import jax
-        jax.profiler.start_trace(self.log_dir)
+        _start(self.log_dir)
         self._active = True
         self._remaining = self.steps
 

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import MetricsRegistry, Trace
+from repro.obs.spans import scope, span, step_span
 from repro.serving.kv_cache import (KVCacheConfig, cache_bytes,
                                     init_paged_storage, init_slot_cache,
                                     set_slot_rows, slot_rows, write_pages,
@@ -249,6 +250,8 @@ class Engine:
             ("active_slot_steps", "slot-steps that carried a live request"),
             ("prefill_dispatches", "batched-prefill device calls"),
             ("prefill_admitted", "requests admitted via batched prefill"),
+            ("prefill_tokens", "prompt tokens run through the prefill and "
+                               "chunk programs after prefix reuse"),
             ("chunk_dispatches", "chunked-prefill device calls"),
             ("chunked_admitted", "requests admitted via chunking"),
             ("prefix_hits", "admissions with a cached prefix"),
@@ -367,40 +370,48 @@ class Engine:
         def prefill_fn(params, kv, tokens, lengths, slots, temps, topks,
                        seeds):
             b, w = tokens.shape
-            zeros = jnp.zeros((mcfg.num_layers, b, w, mcfg.num_kv_heads,
-                               mcfg.resolved_head_dim), mini_dtype)
-            mini = {"k": zeros, "v": zeros, "pos": jnp.zeros((), jnp.int32)}
+            with scope("kv_splice"):
+                zeros = jnp.zeros((mcfg.num_layers, b, w, mcfg.num_kv_heads,
+                                   mcfg.resolved_head_dim), mini_dtype)
+                mini = {"k": zeros, "v": zeros,
+                        "pos": jnp.zeros((), jnp.int32)}
             logits, mini = model.prefill_at(params, {"tokens": tokens},
                                             mini, lengths=lengths)
-            toks = sample_tokens(logits[:, 0, :], temps, topks, seeds,
-                                 jnp.zeros((b,), jnp.uint32),
-                                 max_top_k=cfg.max_top_k)
-            kv = write_slot(kv, slots, mini["k"], mini["v"])
+            with scope("sample"):
+                toks = sample_tokens(logits[:, 0, :], temps, topks, seeds,
+                                     jnp.zeros((b,), jnp.uint32),
+                                     max_top_k=cfg.max_top_k)
+            with scope("kv_splice"):
+                kv = write_slot(kv, slots, mini["k"], mini["v"])
             return toks, kv
 
         def chunk_fn(params, kv, tokens, start, length, slot, temp, topk,
                      seed):
-            row = {"k": slot_rows(kv["k"], slot),
-                   "v": slot_rows(kv["v"], slot), "pos": start}
+            with scope("kv_splice"):
+                row = {"k": slot_rows(kv["k"], slot),
+                       "v": slot_rows(kv["v"], slot), "pos": start}
             logits, row = model.prefill_chunk(params, {"tokens": tokens},
                                               row, lengths=length[None])
-            tok = sample_tokens(logits[:, 0, :], temp[None], topk[None],
-                                seed[None], jnp.zeros((1,), jnp.uint32),
-                                max_top_k=cfg.max_top_k)
-            kv = {"k": set_slot_rows(kv["k"], slot, row["k"]),
-                  "v": set_slot_rows(kv["v"], slot, row["v"])}
+            with scope("sample"):
+                tok = sample_tokens(logits[:, 0, :], temp[None], topk[None],
+                                    seed[None], jnp.zeros((1,), jnp.uint32),
+                                    max_top_k=cfg.max_top_k)
+            with scope("kv_splice"):
+                kv = {"k": set_slot_rows(kv["k"], slot, row["k"]),
+                      "v": set_slot_rows(kv["v"], slot, row["v"])}
             return tok[0], kv
 
         def decode_fn(params, kv, pos, tokens, temps, topks, seeds, steps):
             cache = {"k": kv["k"], "v": kv["v"], "pos": pos}
             logits, cache = model.decode_step(params, tokens, cache)
-            tok = sample_tokens(logits[:, 0, :], temps, topks, seeds, steps,
-                                max_top_k=cfg.max_top_k)
-            # finite-logit flag rides along in the same int32 transfer: a
-            # slot whose logits went non-finite fails ALONE on the host
-            ok = jnp.all(jnp.isfinite(logits[:, 0, :]), axis=-1)
-            out = jnp.stack([tok.astype(jnp.int32),
-                             ok.astype(jnp.int32)], axis=-1)
+            with scope("sample"):
+                tok = sample_tokens(logits[:, 0, :], temps, topks, seeds,
+                                    steps, max_top_k=cfg.max_top_k)
+                # finite-logit flag rides along in the same int32 transfer:
+                # a slot whose logits went non-finite fails ALONE on the host
+                ok = jnp.all(jnp.isfinite(logits[:, 0, :]), axis=-1)
+                out = jnp.stack([tok.astype(jnp.int32),
+                                 ok.astype(jnp.int32)], axis=-1)
             return out, {"k": cache["k"], "v": cache["v"]}
 
         return (step_jit(prefill_fn, donate_argnums=1),
@@ -423,15 +434,19 @@ class Engine:
         def prefill_fn(params, kv, tokens, lengths, page_maps, temps, topks,
                        seeds):
             b, w = tokens.shape
-            zeros = jnp.zeros((mcfg.num_layers, b, w, mcfg.num_kv_heads,
-                               mcfg.resolved_head_dim), mini_dtype)
-            mini = {"k": zeros, "v": zeros, "pos": jnp.zeros((), jnp.int32)}
+            with scope("kv_splice"):
+                zeros = jnp.zeros((mcfg.num_layers, b, w, mcfg.num_kv_heads,
+                                   mcfg.resolved_head_dim), mini_dtype)
+                mini = {"k": zeros, "v": zeros,
+                        "pos": jnp.zeros((), jnp.int32)}
             logits, mini = model.prefill_at(params, {"tokens": tokens},
                                             mini, lengths=lengths)
-            toks = sample_tokens(logits[:, 0, :], temps, topks, seeds,
-                                 jnp.zeros((b,), jnp.uint32),
-                                 max_top_k=cfg.max_top_k)
-            kv = write_pages(kv, page_maps, mini["k"], mini["v"], pg)
+            with scope("sample"):
+                toks = sample_tokens(logits[:, 0, :], temps, topks, seeds,
+                                     jnp.zeros((b,), jnp.uint32),
+                                     max_top_k=cfg.max_top_k)
+            with scope("kv_splice"):
+                kv = write_pages(kv, page_maps, mini["k"], mini["v"], pg)
             return toks, kv
 
         def chunk_fn(params, kv, tokens, start, length, table_row, temp,
@@ -440,9 +455,10 @@ class Engine:
                      "table": table_row}
             logits, cache = model.prefill_chunk(params, {"tokens": tokens},
                                                 cache, lengths=length[None])
-            tok = sample_tokens(logits[:, 0, :], temp[None], topk[None],
-                                seed[None], jnp.zeros((1,), jnp.uint32),
-                                max_top_k=cfg.max_top_k)
+            with scope("sample"):
+                tok = sample_tokens(logits[:, 0, :], temp[None], topk[None],
+                                    seed[None], jnp.zeros((1,), jnp.uint32),
+                                    max_top_k=cfg.max_top_k)
             return tok[0], {"k": cache["k"], "v": cache["v"]}
 
         def decode_fn(params, kv, pos, tokens, temps, topks, seeds, steps,
@@ -450,11 +466,12 @@ class Engine:
             cache = {"k": kv["k"], "v": kv["v"], "pos": pos,
                      "table": tables}
             logits, cache = model.decode_step(params, tokens, cache)
-            tok = sample_tokens(logits[:, 0, :], temps, topks, seeds, steps,
-                                max_top_k=cfg.max_top_k)
-            ok = jnp.all(jnp.isfinite(logits[:, 0, :]), axis=-1)
-            out = jnp.stack([tok.astype(jnp.int32),
-                             ok.astype(jnp.int32)], axis=-1)
+            with scope("sample"):
+                tok = sample_tokens(logits[:, 0, :], temps, topks, seeds,
+                                    steps, max_top_k=cfg.max_top_k)
+                ok = jnp.all(jnp.isfinite(logits[:, 0, :]), axis=-1)
+                out = jnp.stack([tok.astype(jnp.int32),
+                                 ok.astype(jnp.int32)], axis=-1)
             return out, {"k": cache["k"], "v": cache["v"]}
 
         return (step_jit(prefill_fn, donate_argnums=1),
@@ -686,35 +703,62 @@ class Engine:
         request(s), rolls their page/slot acquisitions back, and leaves the
         rest of the batch serving — :meth:`check_invariants` holds at every
         step boundary."""
+        with step_span(self.decode_steps):
+            self._step()
+
+    def _step(self) -> None:
         sched = self.scheduler
-        if self.faults is not None:
-            self.faults.tick()
-        self._expire_deadlines()
-        # ring-buffered backlog sample: peak/mean/samples accumulate in the
-        # gauge child, the trace keeps the most recent queue_trace_samples
-        # values and counts overwrites in queue_stats()["dropped"]
-        self._g_queue.set(len(sched.queue))
-        if self._paged:
-            self._admit_paged()
-        else:
-            while (batch := sched.admit_batch(
-                    mixed=self.cfg.mixed_admission)) is not None:
-                try:
-                    if batch.chunked:
-                        self._run_chunked(*batch.items[0])
-                    else:
-                        self._run_prefill_batch(batch)
-                except Exception as e:      # noqa: BLE001 — fault isolation
-                    self._abort_admission(batch.items, e)
+        with span("engine.expire"):
+            if self.faults is not None:
+                self.faults.tick()
+            self._expire_deadlines()
+            # ring-buffered backlog sample: peak/mean/samples accumulate in
+            # the gauge child, the trace keeps the most recent
+            # queue_trace_samples values and counts overwrites in
+            # queue_stats()["dropped"]
+            self._g_queue.set(len(sched.queue))
+        with span("engine.admit"):
+            if self._paged:
+                self._admit_paged()
+            else:
+                while (batch := sched.admit_batch(
+                        mixed=self.cfg.mixed_admission)) is not None:
+                    try:
+                        if batch.chunked:
+                            self._run_chunked(*batch.items[0])
+                        else:
+                            with span("engine.prefill",
+                                      lambda: self._prefill_span_args(
+                                          batch.items, batch.bucket)):
+                                self._run_prefill_batch(batch)
+                    except Exception as e:  # noqa: BLE001 — fault isolation
+                        self._abort_admission(batch.items, e)
 
         if sched.num_active == 0:
             return
         if self._paged:
-            self._extend_for_decode()
+            with span("engine.extend"):
+                self._extend_for_decode()
             if sched.num_active == 0:      # extension self-preempted all
                 return
-        out_dev, self.kv = self._decode(*self._decode_args())
-        out = np.asarray(out_dev)  # lint: allow[host-sync] THE one transfer per decode step (S, 2): token + finite flag
+        with span("engine.decode", self._decode_span_args):
+            out_dev, self.kv = self._decode(*self._decode_args())
+        with span("engine.decode.wait"):
+            out = np.asarray(out_dev)  # lint: allow[host-sync] THE one transfer per decode step (S, 2): token + finite flag
+        with span("engine.commit"):
+            self._commit(out)
+
+    def _decode_span_args(self) -> dict:
+        """``engine.decode``'s arguments: the slots it decodes and their
+        live context (each attends its position + 1 tokens)."""
+        active = self.scheduler.active_slots()
+        return {"rows": len(active),
+                "ctx_tokens": int(self._pos[active].sum()) + len(active)}
+
+    def _commit(self, out: np.ndarray) -> None:
+        """Take one decode step's (S, 2) tokens and finite flags into the
+        requests' results; finish or fail slots."""
+        sched = self.scheduler
         toks, finite = out[:, 0], out[:, 1]
         now = self._now()
         self._c["decode_steps"].inc()
@@ -739,6 +783,12 @@ class Engine:
             self._steps[slot] += 1
             if state.done or tok == state.request.eos_id:
                 self._finish(slot, now)
+
+    @staticmethod
+    def _prefill_span_args(items, bucket: int) -> dict:
+        """``engine.prefill``'s arguments: rows, bucket, prompt tokens."""
+        return {"rows": len(items), "bucket": bucket,
+                "prompt_tokens": sum(r.prompt_len for _, r in items)}
 
     def _abort_admission(self, items, exc: Exception) -> None:
         """A prefill dispatch raised mid-admission: terminal-fail exactly
@@ -775,6 +825,7 @@ class Engine:
         toks = np.asarray(tok_dev)  # lint: allow[host-sync] B first tokens, one transfer per batched prefill
         self._c["prefill_dispatches"].inc()
         self._c["prefill_admitted"].inc(b)
+        self._c["prefill_tokens"].inc(int(lengths[:b].sum()))
         now = self._now()
         for i, (slot, req) in enumerate(batch.items):
             self._record_first_token(slot, req, int(toks[i]), now, t_admit)
@@ -792,11 +843,14 @@ class Engine:
             clen = min(w, p - start)
             chunk = np.zeros((1, w), np.int32)
             chunk[0, :clen] = req.prompt[start:start + clen]
-            tok_dev, self.kv = self._chunk(
-                self.params, self.kv, jnp.asarray(chunk), np.int32(start),
-                np.int32(clen), np.int32(slot), np.float32(sp.temperature),
-                np.int32(sp.top_k), np.uint32(sp.seed))
+            with span("engine.chunk", lambda: {"tokens": clen}):
+                tok_dev, self.kv = self._chunk(
+                    self.params, self.kv, jnp.asarray(chunk),
+                    np.int32(start), np.int32(clen), np.int32(slot),
+                    np.float32(sp.temperature), np.int32(sp.top_k),
+                    np.uint32(sp.seed))
             self._c["chunk_dispatches"].inc()
+            self._c["prefill_tokens"].inc(clen)
         self._c["chunked_admitted"].inc()
         # lint: allow[host-sync] one scalar per chunked prefill, by design
         self._record_first_token(slot, req, int(tok_dev), self._now(),
@@ -840,7 +894,8 @@ class Engine:
                 victim, vseq = slot, s
         if victim < 0:
             return False
-        self._preempt(victim)
+        with span("engine.preempt"):
+            self._preempt(victim)
         return True
 
     def _preempt(self, slot: int) -> None:
@@ -894,34 +949,35 @@ class Engine:
                                     allow_preempt=True)
         if pages is None:
             return False
-        slot, ticket = sched.admit_head()
-        try:
-            if self.faults is not None:
-                self.faults.check_spill("restore")
-            self.kv = restore_pages(self.kv, pages, ticket.payload,
-                                    self.alloc.num_pages)
-        except Exception as e:             # noqa: BLE001 — fault isolation
-            # the spilled bytes never reached the device: hand the fresh
-            # pages back and fail the ticket's request (its pre-preemption
-            # tokens survive in the result). Returning True is honest —
-            # the ticket reached a terminal state, the queue moved.
-            self.alloc.decref(pages)
-            self._fail_slot(slot, RequestStatus.ERROR.value,
-                            f"resume restore failed: {e}")
-            return True
-        self._slot_pages[slot] = pages
-        self._set_table_row(slot, pages)
-        sp = ticket.request.sampling
-        self._pos[slot] = ticket.pos
-        self._tok[slot] = ticket.last_token
-        self._temps[slot] = sp.temperature
-        self._topks[slot] = sp.top_k
-        self._seeds[slot] = np.uint32(sp.seed)
-        self._steps[slot] = ticket.generated   # sampling's fold_in counter
-        self._c["resumes"].inc()
-        res = self._results[ticket.request.rid]
-        if res.trace is not None:
-            res.trace.stamp("resume", self._now())
+        with span("engine.resume"):
+            slot, ticket = sched.admit_head()
+            try:
+                if self.faults is not None:
+                    self.faults.check_spill("restore")
+                self.kv = restore_pages(self.kv, pages, ticket.payload,
+                                        self.alloc.num_pages)
+            except Exception as e:      # noqa: BLE001 — fault isolation
+                # the spilled bytes never reached the device: hand the fresh
+                # pages back and fail the ticket's request (its pre-preemption
+                # tokens survive in the result). Returning True is honest —
+                # the ticket reached a terminal state, the queue moved.
+                self.alloc.decref(pages)
+                self._fail_slot(slot, RequestStatus.ERROR.value,
+                                f"resume restore failed: {e}")
+                return True
+            self._slot_pages[slot] = pages
+            self._set_table_row(slot, pages)
+            sp = ticket.request.sampling
+            self._pos[slot] = ticket.pos
+            self._tok[slot] = ticket.last_token
+            self._temps[slot] = sp.temperature
+            self._topks[slot] = sp.top_k
+            self._seeds[slot] = np.uint32(sp.seed)
+            self._steps[slot] = ticket.generated   # sampling's fold_in counter
+            self._c["resumes"].inc()
+            res = self._results[ticket.request.rid]
+            if res.trace is not None:
+                res.trace.stamp("resume", self._now())
         return True
 
     def _extend_for_decode(self) -> None:
@@ -1024,7 +1080,10 @@ class Engine:
         if not pending:
             return
         try:
-            self._dispatch_pending(pending)
+            with span("engine.prefill", lambda: self._prefill_span_args(
+                    pending, max(self.scheduler.bucket_for(r.prompt_len)
+                                 for _, r in pending))):
+                self._dispatch_pending(pending)
         except Exception as e:             # noqa: BLE001 — fault isolation
             self._abort_admission(pending, e)
         del pending[:]
@@ -1056,6 +1115,7 @@ class Engine:
         toks = np.asarray(tok_dev)  # lint: allow[host-sync] one transfer per paged prefill dispatch
         self._c["prefill_dispatches"].inc()
         self._c["prefill_admitted"].inc(b)
+        self._c["prefill_tokens"].inc(int(lengths[:b].sum()))
         now = self._now()
         for i, (slot, req) in enumerate(pending):
             self._record_first_token(slot, req, int(toks[i]), now, t_admit)
@@ -1075,11 +1135,14 @@ class Engine:
             clen = min(w, p - start)
             chunk = np.zeros((1, w), np.int32)
             chunk[0, :clen] = req.prompt[start:start + clen]
-            tok_dev, self.kv = self._chunk(
-                self.params, self.kv, jnp.asarray(chunk), np.int32(start),
-                np.int32(clen), table_row, np.float32(sp.temperature),
-                np.int32(sp.top_k), np.uint32(sp.seed))
+            with span("engine.chunk", lambda: {"tokens": clen}):
+                tok_dev, self.kv = self._chunk(
+                    self.params, self.kv, jnp.asarray(chunk),
+                    np.int32(start), np.int32(clen), table_row,
+                    np.float32(sp.temperature), np.int32(sp.top_k),
+                    np.uint32(sp.seed))
             self._c["chunk_dispatches"].inc()
+            self._c["prefill_tokens"].inc(clen)
         self._c["chunked_admitted"].inc()
         # lint: allow[host-sync] one scalar per chunked prefill, by design
         self._record_first_token(slot, req, int(tok_dev), self._now(),

@@ -35,9 +35,12 @@ pytestmark = pytest.mark.sanitizers
 @pytest.fixture(autouse=True)
 def jax_sanitizers():
     """Enable debug_nans + enable_checks for this module only, restoring
-    the clean config afterwards whatever happens."""
+    the clean config afterwards whatever happens. Compiled programs cached
+    by earlier tests in the process are dropped first: a cached one can be
+    reused under debug_nans without its NaN check."""
     old_nans = jax.config.jax_debug_nans
     old_checks = jax.config.jax_enable_checks
+    jax.clear_caches()
     jax.config.update("jax_debug_nans", True)
     jax.config.update("jax_enable_checks", True)
     try:
@@ -45,6 +48,7 @@ def jax_sanitizers():
     finally:
         jax.config.update("jax_debug_nans", old_nans)
         jax.config.update("jax_enable_checks", old_checks)
+        jax.clear_caches()
 
 
 @pytest.fixture()

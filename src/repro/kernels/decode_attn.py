@@ -6,10 +6,13 @@ program — no materialized dense K/V. Four variants share the kernel body:
 - **slot layout**: per-layer cache ``(B, T, Hk, D)`` (B = slots), dense
   floats or INT8 codes + per-head-group f32 scale / uint8 zero dequantized
   IN-TILE;
-- **paged layout**: per-layer page pools ``(P, page, Hk, D)`` routed through
-  a ``(B, n_pages)`` block table — each K tile is one page, gathered via the
-  scalar-prefetched table in the BlockSpec index map (sentinel entries
-  ``== P`` clip to the last physical page; their garbage is always masked).
+- **paged layout**: the stacked page pools ``(L, P, page, Hk, D)`` of all
+  layers, read as one flat pool ``(L·P, page, Hk, D)`` (a free reshape)
+  through a ``(B, n_pages)`` block table offset to the layer's pages —
+  each K tile is one page, gathered via the scalar-prefetched table in
+  the BlockSpec index map (sentinel entries ``== P`` clip to the layer's
+  last page; their garbage is always masked). No per-layer pool is ever
+  sliced out.
 
 The kernel is **length-aware**: per-row lengths (scalar-prefetched to SMEM)
 bound the K loop. Tiles at or beyond a row's length skip their compute
@@ -142,7 +145,7 @@ def _make_kernel(*, bt: int, sm_scale: float, group: int, quant: bool,
 
 def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
                  k_scale=None, k_zero=None, v_scale=None, v_zero=None,
-                 group_size: int = 0, table=None,
+                 group_size: int = 0, table=None, layer=None,
                  block_t: int = 256, interpret: bool = False) -> jax.Array:
     """Fused flash-decode attention. Returns (B, H, D) in q's dtype.
 
@@ -152,10 +155,15 @@ def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
 
     Slot layout (``table=None``): k/v are (B, T, Hk, D) — dense floats, or
     uint8 codes with (B, T, Hk, D/group) ``*_scale``/``*_zero`` planes.
-    Paged layout: k/v are per-layer pools (P, page, Hk, D) (same quant
-    split) and ``table`` (B, n_pages) int32 maps row positions to physical
-    pages; entries == P are sentinels (masked). ``block_t`` tiles the slot
-    K loop (clamped to T); paged tiles are always one page wide.
+    Paged layout: k/v are the stacked pools (L, P, page, Hk, D) (same
+    quant split), read at layer ``layer`` (an int32 scalar, traced or
+    static), or one layer's pool (P, page, Hk, D) with ``layer=None``;
+    ``table`` (B, n_pages) int32 maps row positions to physical pages;
+    entries == P are sentinels (masked). The stack is read in place as a
+    flat (L·P, page, Hk, D) pool with the table offset by ``layer·P``, so
+    the kernel and its index maps are those of a single pool. ``block_t``
+    tiles the slot K loop (clamped to T); paged tiles are always one page
+    wide.
     """
     b, h, d = q.shape
     quant = k_scale is not None
@@ -170,7 +178,16 @@ def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
     dg = d // group_size if quant else 0
 
     if paged:
-        num_pages, page = store.shape[0], store.shape[1]
+        operands = (k, v) if not quant else (k, k_scale, k_zero,
+                                             v, v_scale, v_zero)
+        if layer is not None:
+            # one flat pool; a sentinel clamps to the layer's last page
+            # before the offset, so no other layer's page is ever read
+            per_layer = store.shape[1]
+            operands = tuple(x.reshape(-1, *x.shape[2:]) for x in operands)
+            table = (jnp.minimum(table, per_layer - 1)
+                     + jnp.asarray(layer, jnp.int32) * per_layer)
+        num_pages, page = operands[0].shape[0], operands[0].shape[1]
         nt = table.shape[1]
         bt = page
         lengths = jnp.minimum(lengths, nt * page)
@@ -188,8 +205,6 @@ def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
         prefetch = (lengths, table.astype(jnp.int32))
         kv_block = (1, page, hk, d)
         sc_block = (1, page, hk, dg)
-        operands = (k, v) if not quant else (k, k_scale, k_zero,
-                                             v, v_scale, v_zero)
     else:
         t_len = store.shape[1]
         bt = max(1, min(block_t, t_len))

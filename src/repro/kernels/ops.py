@@ -104,21 +104,28 @@ def decode_attn(q, k, v, lengths, k_scale=None, k_zero=None, v_scale=None,
 @functools.partial(jax.jit, static_argnames=("group_size", "use_pallas"))
 def decode_attn_paged(q, k, v, table, lengths, k_scale=None, k_zero=None,
                       v_scale=None, v_zero=None, group_size: int = 0,
-                      use_pallas: bool = True):
-    """Paged fused flash-decode: k/v are per-layer page pools
-    (P, page, Hk, D) (same dense/INT8 split as :func:`decode_attn`) and
-    ``table`` (B, n_pages) int32 routes each row's positions to physical
-    pages — gathered tile-by-tile in the kernel's index maps, sentinel
-    entries (== P) masked. The oracle gathers the contiguous view then
-    dequantizes and attends."""
+                      layer=None, use_pallas: bool = True):
+    """Paged fused flash-decode: k/v are the stacked page pools
+    (L, P, page, Hk, D) read at ``layer`` (int32 scalar), or one layer's
+    pool (P, page, Hk, D) with ``layer=None`` (same dense/INT8 split as
+    :func:`decode_attn`), and ``table`` (B, n_pages) int32 routes each
+    row's positions to physical pages — gathered tile-by-tile in the
+    kernel's index maps, sentinel entries (== P) masked. The oracle takes
+    the layer's pool, gathers the contiguous view, then dequantizes and
+    attends."""
     if not use_pallas:
+        if layer is not None:
+            k, v = k[layer], v[layer]
+            if k_scale is not None:
+                k_scale, k_zero = k_scale[layer], k_zero[layer]
+                v_scale, v_zero = v_scale[layer], v_zero[layer]
         if k_scale is not None:
             k = _ref_dequant_kv(k, k_scale, k_zero, group_size)
             v = _ref_dequant_kv(v, v_scale, v_zero, group_size)
         return ref.decode_attn_paged(q, k, v, table, lengths)
     return _da.flash_decode(q, k, v, lengths, k_scale=k_scale, k_zero=k_zero,
                             v_scale=v_scale, v_zero=v_zero,
-                            group_size=group_size, table=table,
+                            group_size=group_size, table=table, layer=layer,
                             interpret=_interpret())
 
 

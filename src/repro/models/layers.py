@@ -128,21 +128,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     and 4k-train shapes at production width (DESIGN.md §4).
     ``q_offset`` is the absolute position of q[0] (decode/prefill continua).
 
-    ``kv_pages=(block_table, page_size)`` switches K/V to the paged layout:
-    k, v are per-layer page POOLS — (P, page, Hk, D) dense or a
-    :class:`~repro.serving.kv_cache.QuantizedKV` with those leading dims —
-    and ``block_table`` (B, n_pages) int32 maps each row's kv positions to
-    physical pages. Each inner step gathers only its own kv_chunk worth of
-    pages in-tile (quantized pools dequantize the gathered tile), so the
-    contiguous (B, Skv) view is never materialized. Sentinel table entries
-    (== P) clip to the last physical page; their garbage is strictly beyond
-    every live query's causal mask, so outputs are bit-identical to the
-    contiguous path over the same written tokens.
+    ``kv_pages=(block_table, layer)`` switches K/V to the paged layout:
+    k, v are the stacked page POOLS of every layer — (L, P, page, Hk, D)
+    dense or a :class:`~repro.serving.kv_cache.QuantizedKV` with those
+    leading dims — read at ``layer``, and ``block_table`` (B, n_pages)
+    int32 maps each row's kv positions to physical pages. Each inner step
+    gathers only its own kv_chunk worth of that layer's pages in-tile
+    (quantized pools dequantize the gathered tile), so neither the layer's
+    pool nor the contiguous (B, Skv) view is materialized. Sentinel table
+    entries (== P) clip to the last physical page; their garbage is strictly
+    beyond every live query's causal mask, so outputs are bit-identical to
+    the contiguous path over the same written tokens.
     """
     b, sq, h, d = q.shape
     if kv_pages is not None:
-        table, page_size = kv_pages
+        table, layer = kv_pages
         store = k.codes if isinstance(k, QuantizedKV) else k
+        page_size = store.shape[2]
         hk = store.shape[-2]
         skv = table.shape[1] * page_size
     else:
@@ -171,14 +173,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         npg_p = -(-npg // ppc) * ppc
         if npg_p != npg:                     # sentinel-pad the table itself
             table = jnp.pad(table, ((0, 0), (0, npg_p - npg)),
-                            constant_values=store.shape[0])
+                            constant_values=store.shape[1])
         skv0, skv_p = skv, npg_p * page_size
 
         def fetch(ki):
             pages = jax.lax.dynamic_slice(table, (0, ki * ppc), (b, ppc))
             def grab(pool):
-                gt = pool[pages]             # (B, ppc, page, ...)
-                return gt.reshape(b, kv_chunk, *pool.shape[2:])
+                gt = pool[layer, pages]      # (B, ppc, page, ...)
+                return gt.reshape(b, kv_chunk, *pool.shape[3:])
             if isinstance(k, QuantizedKV):
                 return (kv_dequantize(QuantizedKV(
                             grab(k.codes), grab(k.scale), grab(k.zero),
@@ -264,9 +266,11 @@ def _pv(p: jax.Array, vc: jax.Array, g: int) -> jax.Array:
     return out.reshape(b, h, qn, -1)
 
 
-def paged_write(entry, table, pos, new, page_size: int):
-    """Scatter ``new`` (B, s, Hk, D) tokens into a per-layer page pool
-    through the block table.
+def paged_write(entry, layer, table, pos, new):
+    """Scatter ``new`` (B, s, Hk, D) tokens into layer ``layer`` of the
+    stacked page pools (L, P, page, Hk, D) through the block table, in
+    place: each token lands at ``[layer, page, offset]`` and nothing else
+    of the stack is read or rewritten.
 
     ``pos`` vector (B,) with s == 1 (the engine decode path: each row
     writes at its own position) or scalar with s >= 1 (the chunked
@@ -275,8 +279,8 @@ def paged_write(entry, table, pos, new, page_size: int):
     padded tail past the request's allocated pages — is dropped, never
     written (in particular nothing ever lands in another request's page)."""
     b, s = new.shape[0], new.shape[1]
-    num_pages = (entry.codes if isinstance(entry, QuantizedKV)
-                 else entry).shape[0]
+    _, num_pages, page_size = (entry.codes if isinstance(entry, QuantizedKV)
+                               else entry).shape[:3]
     npg = table.shape[1]
     if getattr(pos, "ndim", 0) == 1:
         assert s == 1, "per-slot paged writes are one token per step"
@@ -290,11 +294,11 @@ def paged_write(entry, table, pos, new, page_size: int):
     offs = cols % page_size
     if isinstance(entry, QuantizedKV):
         qn = kv_quantize(new, entry.group_size)
-        return QuantizedKV(entry.codes.at[pages, offs].set(qn.codes),
-                           entry.scale.at[pages, offs].set(qn.scale),
-                           entry.zero.at[pages, offs].set(qn.zero),
+        return QuantizedKV(entry.codes.at[layer, pages, offs].set(qn.codes),
+                           entry.scale.at[layer, pages, offs].set(qn.scale),
+                           entry.zero.at[layer, pages, offs].set(qn.zero),
                            entry.group_size)
-    return entry.at[pages, offs].set(new.astype(entry.dtype))
+    return entry.at[layer, pages, offs].set(new.astype(entry.dtype))
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -352,7 +356,7 @@ def mlp_params(key, cfg, dtype=jnp.float32, d_ff: Optional[int] = None):
 def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
                positions=None, capture=None,
                kv_cache=None, cache_pos=None, attend_cache: bool = False,
-               block_table=None, fused_decode: bool = False,
+               block_table=None, layer=None, fused_decode: bool = False,
                attn_chunk: int = 1024, attn_p_dtype=jnp.float32):
     """Pre-norm attention block (residual added by caller).
 
@@ -376,10 +380,13 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
     (quantize-rounded) keys.
 
     ``block_table`` (B, n_pages) int32 switches the cache to the PAGED
-    layout: cache entries are per-layer page pools (P, page, Hk, D) —
-    ``page`` is read off the pool shape — and every position routes
-    through the table (writes via :func:`paged_write`, decode reads via a
-    page gather, chunked-prefill reads via the in-tile paged flash path).
+    layout: cache entries are the stacked page pools of every layer
+    (L, P, page, Hk, D), this block's being ``layer`` (int32 scalar), and
+    every position routes through the table (writes via
+    :func:`paged_write`, decode reads via the fused kernel or a page
+    gather, chunked-prefill reads via the in-tile paged flash path), each
+    at ``layer`` of the stack: no per-layer pool is sliced out or written
+    back, and the returned pools are the updated stacks.
     Gathered views hold the same written values at the same positions as a
     slot-cache row (everything else is causally masked), so paged greedy
     output is bit-identical to the slot path, dense and INT8 alike.
@@ -414,20 +421,19 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
                                   kv_chunk=attn_chunk, p_dtype=attn_p_dtype)
         new_kv = (k, v)
     elif block_table is not None:
-        k_cache, v_cache = kv_cache                  # pools (P, page, Hk, D)
-        page = (k_cache.codes if isinstance(k_cache, QuantizedKV)
-                else k_cache).shape[1]
+        assert layer is not None, "paged caches are read at a layer index"
+        k_cache, v_cache = kv_cache            # stacks (L, P, page, Hk, D)
         with scope("kv_write"):
-            k_cache = paged_write(k_cache, block_table, cache_pos, k, page)
-            v_cache = paged_write(v_cache, block_table, cache_pos, v, page)
+            k_cache = paged_write(k_cache, layer, block_table, cache_pos, k)
+            v_cache = paged_write(v_cache, layer, block_table, cache_pos, v)
         with scope("attn"):
             if s == 1:
                 if fused_decode:
                     out = fused_decode_attn(q, k_cache, v_cache, positions,
-                                            table=block_table)
+                                            table=block_table, layer=layer)
                 else:
-                    k_r = paged_view(k_cache, block_table)
-                    v_r = paged_view(v_cache, block_table)
+                    k_r = paged_view(k_cache, block_table, layer)
+                    v_r = paged_view(v_cache, block_table, layer)
                     if isinstance(k_r, QuantizedKV):
                         k_r = kv_dequantize(k_r, q.dtype)
                         v_r = kv_dequantize(v_r, q.dtype)
@@ -441,7 +447,7 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
                                       q_offset=cache_pos, q_chunk=attn_chunk,
                                       kv_chunk=attn_chunk,
                                       p_dtype=attn_p_dtype,
-                                      kv_pages=(block_table, page))
+                                      kv_pages=(block_table, layer))
         new_kv = (k_cache, v_cache)
     else:
         k_cache, v_cache = kv_cache                  # (B, Smax, Hk, D)

@@ -284,6 +284,7 @@ def moe_apply(p, x, cfg: ModelConfig, rules: ShardingRules = NO_RULES, *,
 # ---------------------------------------------------------------------------
 
 import dataclasses
+import functools
 
 from repro.models import transformer as T
 
@@ -296,12 +297,13 @@ def moe_block_params(key, cfg: ModelConfig, dtype=jnp.float32):
 
 def moe_block_apply(p, x, cfg, rules=NO_RULES, *, positions=None, capture=None,
                     kv_cache=None, cache_pos=None, attend_cache=False,
-                    block_table=None, fused_decode=False, prefer_a2a=True,
+                    block_table=None, layer=None, fused_decode=False,
+                    prefer_a2a=True,
                     attn_chunk: int = 1024, attn_p_dtype=jnp.float32):
     a, new_kv = L.attn_apply(p["attn"], x, cfg, rules, positions=positions,
                              capture=capture, kv_cache=kv_cache,
                              cache_pos=cache_pos, attend_cache=attend_cache,
-                             block_table=block_table,
+                             block_table=block_table, layer=layer,
                              fused_decode=fused_decode,
                              attn_chunk=attn_chunk,
                              attn_p_dtype=attn_p_dtype)
@@ -355,42 +357,12 @@ class MoEModel(T.DenseModel):
         h, _ = jax.lax.scan(body_fn, h, params["blocks"])
         return h
 
-    def _cached_scan(self, params, h, cache, positions, *,
-                     attend_cache: bool = False):
-        cfg, rules = self.cfg, self.rules
+    def _cached_block(self, positions):
         # prefill (many tokens) uses the a2a path; decode (1 token) the
         # masked-dense path (DESIGN.md §4 MoE path table)
-        a2a_ok = self.prefer_a2a and positions.shape[1] > 1
-        table = cache.get("table")      # paged layout (see DenseModel)
-        def body(x, scanned):
-            layer_p, kc, vc = scanned
-            y, (kc2, vc2) = moe_block_apply(layer_p, x, cfg, rules,
-                                            positions=positions,
-                                            kv_cache=(kc, vc),
-                                            cache_pos=cache["pos"],
-                                            attend_cache=attend_cache,
-                                            block_table=table,
-                                            fused_decode=self.use_fused_decode,
-                                            prefer_a2a=a2a_ok,
-                                            attn_chunk=self.attn_chunk,
-                                            attn_p_dtype=self.attn_p_dtype)
-            return y, (kc2, vc2)
-        if self.unroll:
-            kvs = []
-            for i in range(cfg.num_layers):
-                layer_kv = jax.tree.map(lambda x: x[i],
-                                        (cache["k"], cache["v"]))
-                h, kv2 = body(h, (self.block_slice(params, i),) + layer_kv)
-                kvs.append(kv2)
-            k_new, v_new = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
-        else:
-            h, (k_new, v_new) = jax.lax.scan(
-                body, h, (params["blocks"], cache["k"], cache["v"]))
-        out = {"k": k_new, "v": v_new,
-               "pos": cache["pos"] + positions.shape[1]}
-        if table is not None:
-            out["table"] = table
-        return h, out
+        return functools.partial(
+            moe_block_apply, cfg=self.cfg, rules=self.rules,
+            prefer_a2a=self.prefer_a2a and positions.shape[1] > 1)
 
     def block_apply_one(self, params, i, h, *, capture=False):
         cfg = self.cfg

@@ -10,6 +10,7 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -32,12 +33,12 @@ def block_params(key, cfg: ModelConfig, dtype=jnp.float32):
 
 def block_apply(p, x, cfg, rules=NO_RULES, *, positions=None, capture=None,
                 kv_cache=None, cache_pos=None, attend_cache: bool = False,
-                block_table=None, fused_decode: bool = False,
+                block_table=None, layer=None, fused_decode: bool = False,
                 attn_chunk: int = 1024, attn_p_dtype=jnp.float32):
     a, new_kv = L.attn_apply(p["attn"], x, cfg, rules, positions=positions,
                              capture=capture, kv_cache=kv_cache,
                              cache_pos=cache_pos, attend_cache=attend_cache,
-                             block_table=block_table,
+                             block_table=block_table, layer=layer,
                              fused_decode=fused_decode,
                              attn_chunk=attn_chunk,
                              attn_p_dtype=attn_p_dtype)
@@ -207,38 +208,62 @@ class DenseModel:
                 "v": (None, "batch", "seq_kv", None, None),
                 "pos": ()}
 
+    def _cached_block(self, positions):
+        """The block a cached pass applies: ``f(layer_p, x, **kw)`` →
+        ``(x, new_kv)`` (the MoE model swaps in its own)."""
+        return functools.partial(block_apply, cfg=self.cfg, rules=self.rules)
+
     def _cached_scan(self, params, h, cache, positions, *,
                      attend_cache: bool = False):
-        cfg, rules = self.cfg, self.rules
-        # paged layout: cache["table"] (B, n_pages) routes every cache
-        # access; it has no layer axis, so it rides into the scan body as a
-        # closed-over constant rather than a scanned operand
+        """The blocks over ``h`` against the cache, one layer at a time.
+
+        Slot caches scan each layer's (S, T, Hk, D) rows as ``xs`` and
+        return them as ``ys``. Paged caches (a ``table`` (B, n_pages) in
+        the cache) carry the stacked pools (L, P, page, Hk, D) through the
+        loop as one buffer each and hand every block its layer index: the
+        block writes and reads the pool at that index, so no layer's pool
+        is sliced out or written back and the step programs' donated pools
+        come back in place."""
+        block = functools.partial(
+            self._cached_block(positions), positions=positions,
+            cache_pos=cache["pos"], attend_cache=attend_cache,
+            fused_decode=self.use_fused_decode, attn_chunk=self.attn_chunk,
+            attn_p_dtype=self.attn_p_dtype)
         table = cache.get("table")
-        def body(x, scanned):
-            layer_p, kc, vc = scanned
-            y, (kc2, vc2) = block_apply(layer_p, x, cfg, rules,
-                                        positions=positions,
-                                        kv_cache=(kc, vc),
-                                        cache_pos=cache["pos"],
-                                        attend_cache=attend_cache,
-                                        block_table=table,
-                                        fused_decode=self.use_fused_decode,
-                                        attn_chunk=self.attn_chunk,
-                                        attn_p_dtype=self.attn_p_dtype)
-            return y, (kc2, vc2)
+        n = self.cfg.num_layers
+        if table is None:
+            def body(x, scanned):
+                layer_p, kc, vc = scanned
+                return block(layer_p, x, kv_cache=(kc, vc))
+            carry = h
+        else:
+            def body(carry, scanned):
+                x, kc, vc = carry
+                layer_p, layer = scanned
+                x, kv = block(layer_p, x, kv_cache=(kc, vc),
+                              block_table=table, layer=layer)
+                return (x, *kv), None
+            carry = (h, cache["k"], cache["v"])
         with scope("blocks"):
-            if self.unroll:
-                kvs = []
-                for i in range(cfg.num_layers):
-                    layer_kv = jax.tree.map(lambda x: x[i],
-                                            (cache["k"], cache["v"]))
-                    h, kv2 = body(h, (self.block_slice(params, i),)
-                                  + layer_kv)
-                    kvs.append(kv2)
-                k_new, v_new = jax.tree.map(lambda *xs: jnp.stack(xs), *kvs)
+            if self.unroll:     # python loop, static layer index (COST)
+                ys = []
+                for i in range(n):
+                    rest = ((i,) if table is not None else
+                            jax.tree.map(lambda a: a[i],
+                                         (cache["k"], cache["v"])))
+                    carry, y = body(carry, (self.block_slice(params, i),
+                                            *rest))
+                    ys.append(y)
+                ys = jax.tree.map(lambda *a: jnp.stack(a), *ys)
             else:
-                h, (k_new, v_new) = jax.lax.scan(
-                    body, h, (params["blocks"], cache["k"], cache["v"]))
+                xs = ((cache["k"], cache["v"]) if table is None
+                      else (jnp.arange(n, dtype=jnp.int32),))
+                carry, ys = jax.lax.scan(body, carry,
+                                         (params["blocks"], *xs))
+            if table is None:
+                h, (k_new, v_new) = carry, ys
+            else:
+                h, k_new, v_new = carry
             new_cache = {"k": k_new, "v": v_new,
                          "pos": cache["pos"] + positions.shape[1]}
         if table is not None:

@@ -116,7 +116,8 @@ def kv_dequantize(q: QuantizedKV, dtype=jnp.float32) -> jax.Array:
 
 
 def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, *,
-                      table=None, block_t: int = 256) -> jax.Array:
+                      table=None, layer=None,
+                      block_t: int = 256) -> jax.Array:
     """Fused flash-decode read of the cache: one query token per row
     attends its history in a single Pallas program — INT8 codes dequantize
     IN-TILE, no materialized dense K/V, per-row lengths bound the K loop.
@@ -125,8 +126,9 @@ def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, *,
     positions (row b's cache holds lengths[b] = positions[b] + 1 live
     tokens — the current token's K/V must already be written).
     ``k_entry``/``v_entry`` are the per-layer storage: (B, T, Hk, D) slot
-    rows, or — with ``table`` (B, n_pages) — (P, page, Hk, D) page pools
-    (dense or :class:`QuantizedKV` either way). Returns (B, 1, H, D) in
+    rows, or — with ``table`` (B, n_pages) — the stacked page pools
+    (L, P, page, Hk, D) of every layer, read at ``layer`` in place (dense
+    or :class:`QuantizedKV` either way). Returns (B, 1, H, D) in
     q's dtype. The escape hatch is the caller's: ``use_fused_decode=False``
     keeps the dequant-then-attend reference path.
     """
@@ -142,7 +144,7 @@ def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, *,
         k_entry, v_entry = k_entry.codes, v_entry.codes
     if table is not None:
         out = ops.decode_attn_paged(q2, k_entry, v_entry, table, lengths,
-                                    **kwargs)
+                                    layer=layer, **kwargs)
     else:
         out = ops.decode_attn(q2, k_entry, v_entry, lengths,
                               block_t=block_t, **kwargs)
@@ -339,16 +341,18 @@ def write_pages(kv: dict, page_map, k_new: jax.Array, v_new: jax.Array,
     return out
 
 
-def paged_view(entry, table):
-    """Gather each block-table row's pages into a contiguous per-request
-    view: per-layer pool (P, page, Hk, D) + table (B, n_pages) →
-    (B, n_pages·page, Hk, D). Sentinel table entries clip to the last
-    physical page — those positions are strictly beyond every live query's
-    causal mask, so the view attends identically to a slot-cache row."""
+def paged_view(entry, table, layer):
+    """Gather each block-table row's pages of one layer into a contiguous
+    per-request view: stacked pools (L, P, page, Hk, D) + table
+    (B, n_pages) + layer index → (B, n_pages·page, Hk, D), read straight
+    from the stack (no per-layer pool is sliced out). Sentinel table
+    entries clip to the last physical page — those positions are strictly
+    beyond every live query's causal mask, so the view attends identically
+    to a slot-cache row."""
     def gather(pool):
         b, npg = table.shape
-        g = pool[table]                                 # (B, npg, page, ...)
-        return g.reshape(b, npg * pool.shape[1], *pool.shape[2:])
+        g = pool[layer, table]                          # (B, npg, page, ...)
+        return g.reshape(b, npg * pool.shape[2], *pool.shape[3:])
     if isinstance(entry, QuantizedKV):
         return QuantizedKV(gather(entry.codes), gather(entry.scale),
                            gather(entry.zero), entry.group_size)

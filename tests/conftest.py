@@ -120,3 +120,39 @@ def assert_flat_compiles():
                 f"expected {want}")
 
     return guard
+
+
+POOL_MOVES = ("copy", "dynamic-slice", "dynamic-update-slice")
+
+
+def pool_carry_faults(engine, hlo: str) -> list:
+    """How an optimized paged step program (HLO text, parameters in the
+    order ``(params, kv, ...)``, outputs ``(out, kv)``) moves the engine's
+    KV pools as data: each copy, dynamic-slice or dynamic-update-slice with
+    a pool-shaped result — ending in a pool plane's per-layer shape
+    (pages, page size, KV heads, ·), so per-layer slices and whole stacks
+    alike, or in the stack flattened to (layers · pages, page size, ...) —
+    and each pool plane not aliased input to output. Empty when the pools
+    stay in place (the token scatter is not a move)."""
+    import re
+
+    import jax
+
+    planes = jax.tree.leaves(engine.kv)
+    tails = {tuple(p.shape[1:]) for p in planes}
+    tails |= {(p.shape[0] * p.shape[1],) + tuple(p.shape[2:]) for p in planes}
+    faults = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                         r"([\w-]+)\(", hlo, re.M):
+        shape = tuple(int(x) for x in m.group(2).split(",") if x)
+        if m.group(3) in POOL_MOVES and any(shape[-len(t):] == t
+                                             for t in tails):
+            faults.append(f"{m.group(3)} {shape} %{m.group(1)}")
+    header = hlo.splitlines()[0]
+    aliased = set(re.findall(r"\{(\d+)\}: \((\d+), \{\}", header))
+    n_params = len(jax.tree.leaves(engine.params))
+    for i in range(len(planes)):
+        if (str(1 + i), str(n_params + i)) not in aliased:
+            faults.append(f"pool plane {i} (parameter {n_params + i}) is "
+                          f"not aliased to output {1 + i}")
+    return faults

@@ -1,6 +1,7 @@
 """AOT compile guards: the main-path Pallas kernels (and ``topk_mask``) at
-llama32-1b widths, compiled by the TPU compiler for a described (not
-attached) v5e chip.
+llama32-1b widths, and the paged engine's decode and chunk programs at the
+tiny size, compiled by the TPU compiler for a described (not attached) v5e
+chip.
 
 Interpret mode accepts block shapes, casts and reshapes that Mosaic refuses;
 these compiles catch that without a chip. The topology is described inside
@@ -16,9 +17,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_config
+from conftest import pool_carry_faults
+from repro.configs import get_config, get_tiny_config
 from repro.kernels import (awp_pgd, decode_attn, dequant_matmul, kv_dequant,
-                           topk_mask)
+                           ops, topk_mask)
+from repro.models import build_model
+from repro.serving import Engine, EngineConfig
 
 CFG = get_config("llama32-1b")
 D, F = CFG.d_model, CFG.d_ff                     # 2048, 8192
@@ -69,16 +73,24 @@ def _dequant(m: int, n: int, k: int):
         ((n, k // GROUP),)]
 
 
-def _flash(paged: bool, int8: bool):
+def _flash(paged: bool, int8: bool, stacked: bool = False):
+    """flash_decode on a slot cache, one layer's page pool, or (stacked)
+    the pools of every layer read at a traced layer index."""
     lead = (PAGES, PAGE) if paged else (SLOTS, T)
+    if stacked:
+        lead = (CFG.num_layers,) + lead
     kv = [(lead + (HK, HD), jnp.uint8 if int8 else jnp.float32)]
     if int8:          # one group per head: f32 scale, uint8 zero-point
         kv += [(lead + (HK, 1),), (lead + (HK, 1), jnp.uint8)]
     shapes = [((SLOTS, H, HD),)] + kv + kv + [((SLOTS,), jnp.int32)]
     if paged:
         shapes.append(((SLOTS, T // PAGE), jnp.int32))
+    if stacked:
+        shapes.append(((), jnp.int32))
 
     def fn(q, *rest):
+        layer = rest[-1] if stacked else None
+        rest = rest[:-1] if stacked else rest
         table = rest[-1] if paged else None
         lengths = rest[-2] if paged else rest[-1]
         planes = rest[:len(kv) * 2]
@@ -86,9 +98,9 @@ def _flash(paged: bool, int8: bool):
             kc, ks, kz, vc, vs, vz = planes
             return decode_attn.flash_decode(
                 q, kc, vc, lengths, k_scale=ks, k_zero=kz, v_scale=vs,
-                v_zero=vz, group_size=HD, table=table)
+                v_zero=vz, group_size=HD, table=table, layer=layer)
         return decode_attn.flash_decode(q, planes[0], planes[1], lengths,
-                                        table=table)
+                                        table=table, layer=layer)
     return fn, shapes
 
 
@@ -108,6 +120,8 @@ CASES = {
     "flash_decode_slot_int8": lambda: _flash(False, True),
     "flash_decode_paged_dense": lambda: _flash(True, False),
     "flash_decode_paged_int8": lambda: _flash(True, True),
+    "flash_decode_paged_stacked_dense": lambda: _flash(True, False, True),
+    "flash_decode_paged_stacked_int8": lambda: _flash(True, True, True),
     "kv_dequant": _kv_dequant,
     "topk_mask": lambda: ((lambda z: topk_mask.topk_row(z, F // 2)),
                           [((D, F),)]),
@@ -121,3 +135,34 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
                                  sharding=one_chip) for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture
+def native_kernels(monkeypatch):
+    """The kernel wrappers lower natively (not in interpret mode), as they
+    do on the chip; their traces from other tests are dropped around it."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    ops.decode_attn_paged.clear_cache()
+    yield
+    ops.decode_attn_paged.clear_cache()
+
+
+@pytest.mark.parametrize("name", ["decode", "chunk"])
+def test_paged_step_programs_keep_pools_in_place_on_v5e(
+        name, one_chip, no_persistent_cache, native_kernels):
+    """With the fused paged flash-decode kernel, the chip's decode and
+    chunk programs take no slice of a pool, write none back and copy none:
+    the stacked pools stay in the donated buffers."""
+    cfg = get_tiny_config("llama32-1b")
+    model = build_model(cfg, remat=False)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)), EngineConfig(
+        num_slots=4, max_len=64, prompt_buckets=(16, 32), kv_layout="paged",
+        page_size=16, num_pages=19))
+    fn, args = {"decode": (eng._decode, eng._decode_args),
+                "chunk": (eng._chunk, eng._dummy_chunk_args)}[name]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        jnp.shape(x), jnp.result_type(x), sharding=one_chip), args())
+    hlo = fn.lower(*args).compile().as_text()
+    assert pool_carry_faults(eng, hlo) == []
+    if name == "decode":
+        assert "tpu_custom_call" in hlo

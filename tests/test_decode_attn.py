@@ -152,6 +152,52 @@ def test_paged_int8_parity():
                                atol=1e-5, rtol=1e-5)
 
 
+LAYERS = 3
+
+
+@pytest.mark.parametrize("layer", [0, 1, LAYERS - 1])
+@pytest.mark.parametrize("int8", [False, True], ids=["dense", "int8"])
+def test_paged_stacked_pool_reads_layer_in_place(layer, int8):
+    """The stacked pools (L, P, page, Hk, D) read at a traced layer index
+    give bit for bit what the same kernel gives on that layer's pool alone
+    — sentinel table entries and the length-0 row included — and the other
+    layers' pages are never read."""
+    rng = np.random.default_rng(8)
+    q, k, v = _qkv(rng, len(LENS), T, 2, 2)
+    lens = jnp.asarray(LENS, jnp.int32)
+    pool_k, pool_v, table, _ = _paged_pool(rng, k, v, LENS, page=8)
+    assert int(table[0, 0]) == pool_k.shape[0]       # length-0 row: sentinel
+    others = [jnp.asarray(rng.normal(size=pool_k.shape), jnp.float32)
+              for _ in range(LAYERS)]
+
+    def stack(pool):
+        return jnp.stack([pool if i == layer else others[i]
+                          for i in range(LAYERS)])
+
+    if int8:
+        group = D // 2
+        planes = [kv_quantize(p, group) for p in (pool_k, pool_v)]
+        stacked = [kv_quantize(stack(p), group) for p in (pool_k, pool_v)]
+        args = lambda kq, vq: (kq.codes, vq.codes, table, lens, kq.scale,
+                               kq.zero, vq.scale, vq.zero)
+        one = ops.decode_attn_paged(q, *args(*planes), group_size=group)
+        got = ops.decode_attn_paged(q, *args(*stacked), group_size=group,
+                                    layer=jnp.int32(layer))
+        ref = ops.decode_attn_paged(q, *args(*stacked), group_size=group,
+                                    layer=jnp.int32(layer), use_pallas=False)
+    else:
+        one = ops.decode_attn_paged(q, pool_k, pool_v, table, lens)
+        got = ops.decode_attn_paged(q, stack(pool_k), stack(pool_v), table,
+                                    lens, layer=jnp.int32(layer))
+        ref = ops.decode_attn_paged(q, stack(pool_k), stack(pool_v), table,
+                                    lens, layer=jnp.int32(layer),
+                                    use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(one))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=1e-5, rtol=1e-5)
+    assert np.all(np.asarray(got[0]) == 0.0)         # length-0 row
+
+
 # ---------------------------------------------------------------------------
 # the serving-facing wrapper + failure semantics
 # ---------------------------------------------------------------------------

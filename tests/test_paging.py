@@ -14,6 +14,8 @@ from repro.serving import (Engine, EngineConfig, GenerationRequest,
                            Scheduler, pow2_at_least)
 from repro.serving.scheduler import ResumeTicket
 
+from conftest import pool_carry_faults
+
 
 # ---------------------------------------------------------------------------
 # shared tiny model (compiles are the dominant test cost)
@@ -274,6 +276,26 @@ def test_paged_preempt_then_resume_matches_slot(tiny_lm):
     for req in reqs:
         assert got[req.rid] == want[req.rid], req.rid
     assert paged.alloc.pages_in_use == 0       # everything returned
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("name", ["decode", "chunk"])
+def test_paged_programs_keep_pools_in_place(tiny_lm, name, quant):
+    """The paged decode and chunk programs write and read the stacked pools
+    at each layer's index: no op slices, copies or writes back a pool (the
+    token scatter aside), and every pool plane aliases input to output.
+    Decode reads through the page gather here: the fused kernel's
+    interpret-mode discharge moves its operands on the CPU, and its TPU
+    program is checked in ``test_chip_compile``."""
+    cfg, model, params = tiny_lm
+    eng = Engine(model, params, EngineConfig(
+        num_slots=2, max_len=32, prompt_buckets=(8, 16), kv_layout="paged",
+        page_size=8, num_pages=11, use_fused_decode=False,
+        **(dict(kv_quantized=True, kv_dtype=jnp.bfloat16) if quant else {})))
+    fn, args = {"decode": (eng._decode, eng._decode_args),
+                "chunk": (eng._chunk, eng._dummy_chunk_args)}[name]
+    hlo = fn.lower(*args()).compile().as_text()
+    assert pool_carry_faults(eng, hlo) == []
 
 
 def test_paged_pool_must_fit_one_request(tiny_lm):

@@ -28,7 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[0:1] = [ROOT, os.path.join(ROOT, "src")]
 
 
-def readings(seed, dims, recipe, picked, prompts, spec):
+def readings(seed, block, dims, recipe, picked, prompts, spec):
     import numpy as np
     from bench.harness import reference, serve
     from bench.harness.cli import Check
@@ -36,13 +36,13 @@ def readings(seed, dims, recipe, picked, prompts, spec):
     served = [np.asarray(r.tokens) for r in picked]
 
     def gap(targets):
-        stats = reference.token_stats(seed, dims, recipe, seqs, starts,
-                                      targets)
+        stats = reference.token_stats(seed, block, dims, recipe, seqs,
+                                      starts, targets)
         return np.concatenate([serve.gaps(st) for st in stats])
 
     def firsts(**precision):
         return [st["argmax"] for st in reference.token_stats(
-            seed, dims, recipe, seqs, starts, served, **precision)]
+            seed, block, dims, recipe, seqs, starts, served, **precision)]
     prog = gap(served)
     ctrl = gap(firsts(precision="high"))
     blocks = gap(firsts(precision="high", head_precision="highest"))

@@ -1,2 +1,3 @@
 """The benchmark's general machinery: device check, weights, traffic,
-the cell runners, the plain reference and the trace reduction."""
+the cell runners, the plain reference and the trace reduction. What
+depends on a model's architecture is in ``blocks/``, one module a block."""

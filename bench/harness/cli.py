@@ -2,10 +2,20 @@
 the result.
 
 Everything that belongs to one cell is found by name from
-``BENCHMARK.json``: the configuration's file, ``bench/traffic/<traffic>.json``
-(whose ``kind`` names the runner module ``bench.harness.<kind>``) and one
-reader ``bench/metrics/<metric>.py`` per per-layer metric. Adding a cell,
-a configuration or a metric adds files and entries; it edits none.
+``BENCHMARK.json``: the configuration's file, the block module
+``bench/harness/blocks/<block>.py`` that the file's ``"block"`` names,
+``bench/traffic/<traffic>.json`` (whose ``kind`` names the runner module
+``bench.harness.<kind>``) and one reader ``bench/metrics/<metric>.py`` per
+per-layer metric. Adding a cell, a configuration, an architecture or a
+metric adds files and entries; it edits none.
+
+A block module holds everything that depends on the architecture: its keys
+of the published config (read strictly: a key of the file's ``model`` that
+the block does not read is an error before any work), the seeded float
+weights, the program's parameter tree, the plain reference of one block and
+the model's operation count. Its contract is the docstring of
+``bench/harness/blocks/__init__.py``; ``blocks/dense.py`` is the Llama
+block granite-8b is served as.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -17,11 +27,13 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import importlib
 import importlib.util
 import json
 import os
 import sys
+import types
 from typing import Any, Callable, Dict, List, Optional
 
 
@@ -46,6 +58,8 @@ class Cell:
     name: str
     chips: int
     config: dict                # the configuration's file
+    block: types.ModuleType     # bench/harness/blocks/<block>.py
+    dims: Any                   # block.Dims of the file's model
     traffic: dict               # bench/traffic/<traffic>.json
     end_to_end: List[dict]      # the BENCHMARK.json entries it reports
     per_layer: List[dict]
@@ -76,6 +90,10 @@ def find_cell(root: str, name: str) -> Cell:
     w = work[name]
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = load_json(os.path.join(root, cfg["file"]))
+    if "block" not in config:
+        raise BenchError(f"{cfg['file']} names no block")
+    block = load_block(root, config["block"])
+    dims = block.Dims.from_config(config["model"])
     traffic = load_json(os.path.join(root, "bench", "traffic",
                                      w["traffic"] + ".json"))
 
@@ -86,17 +104,37 @@ def find_cell(root: str, name: str) -> Cell:
     per = [m for m in bench["per_layer"]
            if (name in m["workloads"] if "workloads" in m
                else m["moves"] in moved)]
-    return Cell(name, int(w["chips"]), config, traffic, e2e, per)
+    return Cell(name, int(w["chips"]), config, block, dims, traffic, e2e,
+                per)
+
+
+def _module(path: str, name: str):
+    """The module at ``path``, registered as ``name`` (a dataclass looks
+    its module up there)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_load_block = functools.cache(_module)
+
+
+def load_block(root: str, block: str):
+    """The block module bench/harness/blocks/<block>.py, loaded once per
+    path (its jitted reference keeps its compiled programs across runs)."""
+    path = os.path.join(root, "bench", "harness", "blocks", block + ".py")
+    if not os.path.isfile(path):
+        raise BenchError(f"no block {block!r}: {path} is missing")
+    return _load_block(os.path.abspath(path),
+                       "bench_block_" + block.replace(".", "_"))
 
 
 def reader(root: str, metric: str) -> Callable:
     """``read(context, peaks) -> float | None`` of bench/metrics/<metric>.py."""
-    path = os.path.join(root, "bench", "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(os.path.join(root, "bench", "metrics", metric + ".py"),
+                   "bench_metric_" + metric.replace(".", "_")).read
 
 
 def device_info(chips: int) -> dict:

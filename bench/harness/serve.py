@@ -10,7 +10,8 @@ waits for its one transfer from the device); a first token's time is the
 engine's own stamp.
 
 After the window: the device's peak memory is read, the program's state
-is freed, and the plain reference (``reference.py``) runs over a sample of
+is freed, and the plain reference (``reference.py`` over the cell's block
+module, ``blocks/``) runs over a sample of
 the finished requests, drawn from the seed, with the longest among them.
 The compared number is the widest gap by which a served token's reference
 logit lies below the reference's best at its position, as a share of the
@@ -25,6 +26,7 @@ import os
 import shutil
 import sys
 import time
+import types
 from typing import Dict, List
 
 import numpy as np
@@ -46,24 +48,22 @@ class StepRecord:
 
 @dataclasses.dataclass
 class ServeContext:
-    """What the per-layer readers of a serving cell read."""
-    dims: W.Dims
+    """What the per-layer readers of a serving cell read: the cell's block
+    module (its model operations and attention layers) and sizes."""
+    block: types.ModuleType
+    dims: object
     engine_cfg: dict
     steps: List[StepRecord] = dataclasses.field(default_factory=list)
     trace: object = None               # trace_reduce.Reduction
 
 
-def model_config(cfg: dict, dims: W.Dims):
-    """The program's ModelConfig at the benchmark file's sizes."""
+def program_model(block, dims, arch: str):
+    """The program's model: the repo's config of ``arch`` at the
+    configuration's sizes, by the block's ``program_config``."""
     from repro.configs import get_config
-    base = get_config(cfg["arch"])
-    return dataclasses.replace(
-        base, num_layers=dims.num_hidden_layers, d_model=dims.hidden_size,
-        num_heads=dims.num_attention_heads,
-        num_kv_heads=dims.num_key_value_heads, head_dim=dims.head_dim,
-        d_ff=dims.intermediate_size, vocab_size=dims.vocab_size,
-        mlp_act="silu" if dims.gated else "gelu",
-        rope_theta=float(dims.rope_theta), norm_eps=dims.rms_norm_eps)
+    from repro.models import build_model
+    return build_model(block.program_config(get_config(arch), dims),
+                       remat=False)
 
 
 def engine_config(settings: dict):
@@ -150,25 +150,24 @@ class Tracker:
 
 def run(cell, *, seed: int, seconds: float, trace: bool, started: float,
         root: str, verify=None) -> Outcome:
-    """One run of a serving cell. ``verify(seed, dims, recipe, picked,
-    prompts, spec) -> [Check]`` judges the sampled finished requests; by default
-    :func:`served_token_checks`."""
+    """One run of a serving cell. ``verify(seed, block, dims, recipe,
+    picked, prompts, spec) -> [Check]`` judges the sampled finished requests;
+    by default :func:`served_token_checks`."""
     import jax
-    from repro.models import build_model
     from repro.obs import MetricsRegistry
     from repro.serving import Engine
     from bench import trace_reduce
     from bench.harness.cli import memory_peak_bytes
 
     cfg, mix = cell.config, cell.traffic
-    dims = W.Dims.from_config(cfg["model"])
+    block, dims = cell.block, cell.dims
     recipe = W.Recipe.from_config(cfg["weights"])
     ecfg = engine_config(cfg["engine"])
     spans = Spans(enabled=trace)
 
     # -- set-up --------------------------------------------------------------
-    params = W.served_params(seed, dims, recipe)
-    model = build_model(model_config(cfg, dims), remat=False)
+    params = W.served_params(seed, block, dims, recipe)
+    model = program_model(block, dims, cfg["arch"])
     engine = Engine(model, params, ecfg, registry=MetricsRegistry())
     del params
     window = T.open_loop(mix, seed, seconds, dims.vocab_size, ecfg.max_len)
@@ -240,7 +239,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool, started: float,
           file=sys.stderr, flush=True)
 
     mem = memory_peak_bytes(cell.chips)
-    ctx = ServeContext(dims, cfg["engine"])
+    ctx = ServeContext(block, dims, cfg["engine"])
     if trace:
         ctx.steps = steps[tracer.first:tracer.last]
         ctx.trace = trace_reduce.reduce_dir(trace_dir)
@@ -251,14 +250,14 @@ def run(cell, *, seed: int, seconds: float, trace: bool, started: float,
     del engine, model
     gc.collect()
     picked = sample(finished, seed, mix["check"]["served_tokens"])
-    checks = (verify or served_token_checks)(seed, dims, recipe, picked,
-                                             prompts, mix["check"])
+    checks = (verify or served_token_checks)(seed, block, dims, recipe,
+                                             picked, prompts, mix["check"])
     return Outcome(values, len(window) + len(pre), failed, checks, mem, ctx)
 
 
-def served_token_checks(seed, dims, recipe, picked, prompts,
+def served_token_checks(seed, block, dims, recipe, picked, prompts,
                         spec) -> List[Check]:
-    worst, n = served_token_gap(seed, dims, recipe, picked, prompts)
+    worst, n = served_token_gap(seed, block, dims, recipe, picked, prompts)
     print(f"[bench] checked {len(picked)} finished requests, {n} served "
           f"tokens", file=sys.stderr, flush=True)
     return [Check("served_token_gap", worst, spec["served_token_gap"])]
@@ -301,14 +300,14 @@ def sequences(picked, prompts):
     return seqs, starts
 
 
-def served_token_gap(seed, dims, recipe, picked, prompts) -> tuple:
+def served_token_gap(seed, block, dims, recipe, picked, prompts) -> tuple:
     """(widest gap over every served token of ``picked``, tokens read);
     a run with nothing finished to read reads as infinitely far."""
     if not picked:
         return math.inf, 0
     from bench.harness import reference
     seqs, starts = sequences(picked, prompts)
-    stats = reference.token_stats(seed, dims, recipe, seqs, starts,
+    stats = reference.token_stats(seed, block, dims, recipe, seqs, starts,
                                   [np.asarray(r.tokens) for r in picked])
     worst = max(float(gaps(st).max()) for st in stats)
     return worst, sum(len(r.tokens) for r in picked)

@@ -34,18 +34,16 @@ def main():
     from bench.harness import cli, serve
     from bench.harness import traffic as T
     from bench.harness import weights as W
-    from repro.models import build_model
     from repro.serving import Engine
 
     cell = cli.find_cell(ROOT, args.workload)
     cli.device_info(cell.chips)
     cli.use_compile_cache(ROOT)
-    cfg, mix = cell.config, cell.traffic
-    dims = W.Dims.from_config(cfg["model"])
+    cfg, mix, dims = cell.config, cell.traffic, cell.dims
     ecfg = serve.engine_config(cfg["engine"])
-    params = W.served_params(args.seed, dims,
+    params = W.served_params(args.seed, cell.block, dims,
                              W.Recipe.from_config(cfg["weights"]))
-    engine = Engine(build_model(serve.model_config(cfg, dims), remat=False),
+    engine = Engine(serve.program_model(cell.block, dims, cfg["arch"]),
                     params, ecfg)
     del params
     reqs = {r: T.open_loop(dict(mix, rate_per_s=r), args.seed, args.seconds,

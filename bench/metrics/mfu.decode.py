@@ -1,7 +1,7 @@
 """Step programs, decode: model operations of the traced decode tokens
-(every block linear, the head, attention over each live context) over the
-device time of the decode programs times the bf16 peak."""
-from bench.harness import model_cost
+(every block linear, the head, attention over each live context; the cell's
+block module counts them) over the device time of the decode programs
+times the bf16 peak."""
 
 
 def read(ctx, peaks):
@@ -12,6 +12,6 @@ def read(ctx, peaks):
     tokens = sum(s.decoded for s in ctx.steps)
     if t <= 0 or tokens == 0:
         return None
-    flop = model_cost.decode_flop(ctx.dims, tokens,
-                                  sum(s.decode_ctx for s in ctx.steps))
+    flop = ctx.block.decode_flop(ctx.dims, tokens,
+                                 sum(s.decode_ctx for s in ctx.steps))
     return 100.0 * flop / (t * peaks["bf16_flop_per_s"])

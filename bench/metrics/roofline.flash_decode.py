@@ -1,8 +1,9 @@
 """Decode attention: the least time ``flash_decode`` could take over the
 traced decode steps (per layer and step, the larger of its operations over
 the bf16 peak and the live K/V bytes over HBM bandwidth, bench/cost; the
-live context of each step is the harness's count) over the device time of
-its events in the decode programs."""
+live context of each step is the harness's count, the layers and heads the
+cell's block module's) over the device time of its events in the decode
+programs."""
 from bench.cost import flash_decode
 
 
@@ -14,15 +15,14 @@ def read(ctx, peaks):
     t = tr.kernel_s("flash_decode", "decode_fn")
     if t <= 0:
         return None
-    d = ctx.dims
+    layers, heads, kv_heads, head_dim = ctx.block.decode_attention(ctx.dims)
     rows = ctx.engine_cfg["num_slots"]
     ideal = 0.0
     for s in steps:
         if s.decoded:
-            flop, moved = flash_decode.cost(s.decode_ctx, rows,
-                                            d.num_attention_heads,
-                                            d.num_key_value_heads, d.head_dim)
-            ideal += d.num_hidden_layers * max(
+            flop, moved = flash_decode.cost(s.decode_ctx, rows, heads,
+                                            kv_heads, head_dim)
+            ideal += layers * max(
                 flop / peaks["bf16_flop_per_s"],
                 moved / peaks["hbm_byte_per_s"])
     return 100.0 * ideal / t

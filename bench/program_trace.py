@@ -301,12 +301,14 @@ def newest(root: str = ROOT) -> Optional[str]:
     return max(paths, key=os.path.getmtime) if paths else None
 
 
-def pool_shape(dims, engine_cfg: dict) -> Optional[tuple]:
-    """Per-layer KV pool shape of a paged engine configuration."""
+def pool_shape(block, dims, engine_cfg: dict) -> Optional[tuple]:
+    """Per-layer KV pool shape of a paged engine configuration: its pages
+    of the block's KV heads and head size."""
     if engine_cfg.get("kv_layout") != "paged":
         return None
+    _, _, kv_heads, head_dim = block.decode_attention(dims)
     return (int(engine_cfg["num_pages"]), int(engine_cfg["page_size"]),
-            int(dims.num_key_value_heads), int(dims.head_dim))
+            int(kv_heads), int(head_dim))
 
 
 def for_context(ctx) -> Optional[ProgramTrace]:
@@ -316,7 +318,7 @@ def for_context(ctx) -> Optional[ProgramTrace]:
     path = newest()
     if tr is None or path is None:
         return None
-    return read(path, pool_shape(ctx.dims, ctx.engine_cfg),
+    return read(path, pool_shape(ctx.block, ctx.dims, ctx.engine_cfg),
                 tuple(tr.window))
 
 

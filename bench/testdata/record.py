@@ -26,18 +26,18 @@ def main(out: str) -> None:
     from bench.harness import cli, serve
     from bench.harness import weights as W
     from bench.harness.spans import Spans
-    from repro.models import build_model
     from repro.serving import Engine
     from repro.serving.scheduler import GenerationRequest
 
     cli.device_info(1)
     cfg = cli.load_json(os.path.join(ROOT, "bench/configs/granite-8b.json"))
-    dims = dataclasses.replace(W.Dims.from_config(cfg["model"]),
+    block = cli.load_block(ROOT, cfg["block"])
+    dims = dataclasses.replace(block.Dims.from_config(cfg["model"]),
                                num_hidden_layers=2)
     ecfg = serve.engine_config(dict(cfg["engine"], num_slots=4, max_len=256,
                                     prompt_buckets=[64], num_pages=64))
-    engine = Engine(build_model(serve.model_config(cfg, dims), remat=False),
-                    W.served_params(0, dims, W.Recipe()), ecfg)
+    engine = Engine(serve.program_model(block, dims, cfg["arch"]),
+                    W.served_params(0, block, dims, W.Recipe()), ecfg)
     rng = np.random.default_rng(0)
     reqs = [GenerationRequest(rid=i, prompt=rng.integers(0, 49152, p,
                                                          dtype=np.int32),

@@ -5,6 +5,7 @@ without a TPU."""
 import io
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -66,6 +67,23 @@ def test_traced_run_reports_per_layer_metrics_and_breakdown(bench_root,
     bd = r["breakdown"]
     assert set(bd) == {"device_ops", "idle_gaps"}
     assert 0 < len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 10
+
+
+def test_a_block_added_as_a_new_file_serves_the_cell(bench_root):
+    """An architecture enters as one new block file that its configuration
+    names; here a copy of the dense block under a name of its own."""
+    blocks = os.path.join(bench_root, "bench/harness/blocks")
+    shutil.copy(os.path.join(blocks, "dense.py"),
+                os.path.join(blocks, "tiny_dense.py"))
+    path = os.path.join(bench_root, "bench/configs/tiny-granite.json")
+    cfg = json.load(open(path))
+    cfg["block"] = "tiny_dense"
+    json.dump(cfg, open(path, "w"))
+    cell = cli.find_cell(bench_root, TINY_CELL)
+    assert cell.block.__file__ == os.path.join(blocks, "tiny_dense.py")
+    r = run(bench_root)
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert set(r["metrics"]) == {"tpot_ms", "setup_s"}
 
 
 def test_no_tpu_exits_nonzero_before_any_work(bench_root, monkeypatch,

@@ -1,12 +1,12 @@
-"""Operation and byte counts of the kernels and of the model, checked
-against hand counts at small shapes."""
+"""Operation and byte counts of the kernels and of the model (the dense
+block's, ``bench/harness/blocks/dense.py``), checked against hand counts at
+small shapes."""
 import pytest
 
 import benchutil  # noqa: F401  (the checkout root on the path)
 
 from bench.cost import dequant_matmul, flash_decode
-from bench.harness import model_cost
-from bench.harness.weights import Dims
+from bench.harness.blocks import dense
 
 
 def test_dequant_matmul_hand_count():
@@ -49,24 +49,29 @@ def test_flash_decode_hand_count():
     assert moved == 2 * 10 * 2 * 8 * 4 + 2 * 3 * 4 * 8 * 4
 
 
-DIMS = Dims(hidden_size=8, num_hidden_layers=2, num_attention_heads=2,
-            num_key_value_heads=1, head_dim=4, intermediate_size=16,
-            vocab_size=10, hidden_act="silu", rope_theta=1e4,
-            rms_norm_eps=1e-5)
+DIMS = dense.Dims(hidden_size=8, num_hidden_layers=2, num_attention_heads=2,
+                  num_key_value_heads=1, head_dim=4, intermediate_size=16,
+                  vocab_size=10, hidden_act="silu", rope_theta=1e4,
+                  rms_norm_eps=1e-5, max_position_embeddings=64,
+                  tie_word_embeddings=False)
 
 
 def test_model_flop_hand_count():
     # per block: q 8x8, k 8x4, v 8x4, o 8x8, gate/up 8x16 each, down 16x8
     per_block = 64 + 32 + 32 + 64 + 3 * 128
-    assert model_cost.linear_flop_per_token(DIMS) == 2 * 2 * per_block
-    assert model_cost.head_flop(DIMS) == 2 * 8 * 10
-    assert model_cost.attn_flop(DIMS, 5) == 4 * 2 * 2 * 4 * 5
-    assert model_cost.decode_flop(DIMS, 3, 12) == (
+    assert dense.linear_flop_per_token(DIMS) == 2 * 2 * per_block
+    assert dense.head_flop(DIMS) == 2 * 8 * 10
+    assert dense.attn_flop(DIMS, 5) == 4 * 2 * 2 * 4 * 5
+    assert dense.decode_flop(DIMS, 3, 12) == (
         3 * (2 * 2 * per_block + 160) + 4 * 2 * 2 * 4 * 12)
 
 
 def test_non_gated_block_has_six_linears():
-    gelu = Dims(**{**DIMS.__dict__, "hidden_act": "gelu_pytorch_tanh"})
+    gelu = dense.Dims(**{**DIMS.__dict__, "hidden_act": "gelu_pytorch_tanh"})
     assert [n for n, _, _ in gelu.linears()] == ["wq", "wk", "wv", "wo",
                                                    "wu", "wd"]
     assert len(DIMS.linears()) == 7
+
+
+def test_decode_attention_is_every_layer():
+    assert dense.decode_attention(DIMS) == (2, 2, 1, 4)

@@ -17,7 +17,7 @@ from benchutil import ROOT
 
 from bench import program_trace as P
 from bench import trace_reduce
-from bench.harness import weights as W
+from bench.harness import cli
 
 POOL = (64, 16, 8, 128)            # record.py's pool: 64 pages of 16
 TRACES = {"plain": "serve.xplane.pb.gz", "scoped": "serve_scoped.xplane.pb.gz"}
@@ -187,14 +187,18 @@ def test_weight_slices_and_the_pool_copy(plain):
 
 # -- the trace of a program with names ----------------------------------------
 
-def _context(path):
+def _context(path, steps=()):
     """What a serving cell's readers get, at record.py's sizes."""
     conf = json.load(open(os.path.join(ROOT, "bench/configs/granite-8b.json")))
-    dims = W.Dims.from_config(dict(conf["model"], num_hidden_layers=2))
-    return types.SimpleNamespace(
-        dims=dims, trace=trace_reduce.reduce(path),
+    block = cli.load_block(ROOT, conf["block"])
+    dims = block.Dims.from_config(dict(conf["model"], num_hidden_layers=2))
+    ctx = types.SimpleNamespace(
+        block=block, dims=dims, trace=trace_reduce.reduce(path),
         engine_cfg=dict(conf["engine"], num_slots=4, max_len=256,
                         prompt_buckets=[64], num_pages=64))
+    if steps:
+        ctx.steps = list(steps)
+    return ctx
 
 
 @pytest.mark.parametrize("metric", ["share.kv_carry.decode",
@@ -202,12 +206,35 @@ def _context(path):
                                     "idle_ms.engine_step"])
 def test_each_new_reader_reads_the_scoped_trace(scoped_path, monkeypatch,
                                                 metric):
-    from bench.harness import cli
     monkeypatch.setattr(P, "newest", lambda root=P.ROOT: scoped_path)
     value = cli.reader(ROOT, metric)(_context(scoped_path), {})
     assert value is not None and value > 0
     if metric.startswith("share."):
         assert value < 100
+
+
+# The readers that count through the block module read what they read
+# before the block moved into bench/harness/blocks/: the same floats, to
+# the bit, on the same trace and steps. The steps are the trace's own three
+# decode steps, two rows each (its engine.decode spans: 6 rows, 312 live
+# tokens in all).
+@pytest.mark.parametrize("metric,value", [
+    ("mfu.decode", 0.2523665984343416),
+    ("roofline.flash_decode", 5.898294205323746)])
+def test_block_readers_read_as_before_on_the_scoped_trace(scoped_path,
+                                                          metric, value):
+    from bench.harness.serve import StepRecord
+    steps = [StepRecord(2, 102), StepRecord(2, 104), StepRecord(2, 106)]
+    peaks = cli.peaks_for(ROOT, "TPU v5 lite")
+    assert cli.reader(ROOT, metric)(_context(scoped_path, steps),
+                                    peaks) == value
+
+
+def test_pool_shape_is_the_blocks_kv_page(scoped_path):
+    ctx = _context(scoped_path)
+    assert P.pool_shape(ctx.block, ctx.dims, ctx.engine_cfg) == POOL
+    assert P.pool_shape(ctx.block, ctx.dims,
+                        dict(ctx.engine_cfg, kv_layout="slot")) is None
 
 
 def test_every_gap_in_an_engine_step_names_an_engine_phase(scoped):

@@ -152,9 +152,13 @@ def act(x, kind):
     return 0.5 * x * (1.0 + jnp.tanh(c * (x + 0.044715 * x ** 3)))
 
 
+def layer(w, x, dims: Dims, i: int):
+    """Block ``i`` over one sequence x (T, d): every block alike."""
+    return _layer(w, x, dims)
+
+
 @functools.partial(jax.jit, static_argnames=("dims", "q_chunk"))
-def layer(w, x, dims: Dims, q_chunk: int = 512):
-    """One block over one sequence x (T, d)."""
+def _layer(w, x, dims: Dims, q_chunk: int = 512):
     t = x.shape[0]
     h, hk, hd = (dims.num_attention_heads, dims.num_key_value_heads,
                  dims.head_dim)
@@ -207,14 +211,14 @@ def attn_flop(dims: Dims, keys: int) -> int:
             * dims.head_dim * keys)
 
 
-def decode_flop(dims: Dims, tokens: int, context: int) -> int:
-    """``tokens`` decode tokens whose live contexts sum to ``context``."""
-    return (tokens * (linear_flop_per_token(dims) + head_flop(dims))
-            + attn_flop(dims, context))
+def decode_flop(dims: Dims, contexts) -> int:
+    """One decode token for each live context in ``contexts``; every layer
+    attends the whole context."""
+    return (len(contexts) * (linear_flop_per_token(dims) + head_flop(dims))
+            + attn_flop(dims, sum(contexts)))
 
 
 def decode_attention(dims: Dims) -> tuple:
-    """Every layer runs ``flash_decode``: (layers, heads, KV heads, head
-    size)."""
-    return (dims.num_hidden_layers, dims.num_attention_heads,
-            dims.num_key_value_heads, dims.head_dim)
+    """Every layer runs ``flash_decode`` over the whole context."""
+    return ((dims.num_attention_heads, dims.num_key_value_heads,
+             dims.head_dim, None),) * dims.num_hidden_layers

@@ -25,22 +25,22 @@ SEQ_PAD = 1024     # sequences are right-padded to a multiple of this
 def rtn_int4(w: jax.Array, group: int) -> jax.Array:
     """Quantize-dequantize a stored (d_in, d_out) weight onto the
     asymmetric min/max INT4 grid, per output row and group of ``group``
-    input columns."""
-    wt = w.T
-    d_out, d_in = wt.shape
-    g = wt.reshape(d_out, d_in // group, group)
+    input columns; a stack (n, d_in, d_out) slice by slice."""
+    wt = jnp.swapaxes(w, -1, -2)
+    *lead, d_out, d_in = wt.shape
+    g = wt.reshape(*lead, d_out, d_in // group, group)
     lo = g.min(axis=-1, keepdims=True)
     hi = g.max(axis=-1, keepdims=True)
     scale = jnp.maximum((hi - lo) / 15.0, 1e-8)
     zero = jnp.clip(jnp.round(-lo / scale), 0.0, 15.0)
     q = jnp.clip(jnp.round(g / scale) + zero, 0.0, 15.0)
-    return ((q - zero) * scale).reshape(d_out, d_in).T
+    return jnp.swapaxes(((q - zero) * scale).reshape(wt.shape), -1, -2)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "dims", "group"))
 def dequant_block(key, block, dims, group: int):
     """Block ``key``'s floats with its linears on the INT4 grid."""
-    linears = {n for n, _, _ in dims.linears()}
+    linears = {entry[0] for entry in dims.linears()}
     return {n: (rtn_int4(v, group) if n in linears else v)
             for n, v in block.block_f32(key, dims).items()}
 
@@ -83,7 +83,7 @@ def token_stats(seed: int, block, dims, recipe: W.Recipe, seqs, starts,
         for i in range(dims.num_hidden_layers):
             w = dequant_block(jax.random.fold_in(key, i), block, dims,
                               recipe.group_size)
-            hs = [block.layer(w, x, dims) for x in hs]
+            hs = [block.layer(w, x, dims, i) for x in hs]
             del w
     with jax.default_matmul_precision(head_precision or precision):
         out = []

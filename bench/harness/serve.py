@@ -27,7 +27,7 @@ import shutil
 import sys
 import time
 import types
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -41,9 +41,9 @@ PREROLL_RID = 1_000_000
 
 @dataclasses.dataclass
 class StepRecord:
-    """One ``Engine.step`` as the harness saw it."""
-    decoded: int               # requests that got a decode token
-    decode_ctx: int            # their live context, summed (tokens)
+    """One ``Engine.step`` as the harness saw it: the live context (tokens)
+    of each request that got a decode token."""
+    contexts: Tuple[int, ...]
 
 
 @dataclasses.dataclass
@@ -126,8 +126,9 @@ class Tracker:
 
     def after_step(self, t: float, window: tuple) -> tuple:
         """Account the tokens made by the step that returned at ``t``.
-        Returns (decoded, decode_ctx)."""
-        decoded, ctx = 0, 0
+        Returns the live context of each request that got a decode
+        token."""
+        contexts = []
         for rid, res in self.res.items():
             n, before = len(res.tokens), self.seen[rid]
             if n == before:
@@ -135,13 +136,12 @@ class Tracker:
             if before == 0:
                 self.last[rid] = res.t_first_token
             if n > max(before, 1):                 # one decode token
-                decoded += 1
-                ctx += res.prompt_len + n - 1
+                contexts.append(res.prompt_len + n - 1)
                 if window[0] <= t <= window[1]:
                     self.gaps.append(t - self.last[rid])
                 self.last[rid] = t
             self.seen[rid] = n
-        return decoded, ctx
+        return tuple(contexts)
 
     def drop_finished(self, rids) -> None:
         for rid in rids:
@@ -215,12 +215,12 @@ def run(cell, *, seed: int, seconds: float, trace: bool, started: float,
             continue
         with spans("Engine.step"):
             engine.step()
-        dec, ctx = tracker.after_step(time.perf_counter(), (t0, t_end))
+        contexts = tracker.after_step(time.perf_counter(), (t0, t_end))
         for r in engine._done:
             done[r.rid] = r
         tracker.drop_finished([r.rid for r in engine._done])
         engine._done.clear()
-        steps.append(StepRecord(dec, ctx))
+        steps.append(StepRecord(contexts))
     tracer.at(math.inf, len(steps), engine)
     t_close = time.perf_counter()
 

@@ -38,22 +38,30 @@ def base_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, np.uint32((seed >> 32) & 0xFFFFFFFF))
 
 
+def quantize(w: jax.Array, group_size: int):
+    """A stored linear packed INT4 by the program's own packer
+    (``QTensor.from_dense``): (d_in, d_out) into one ``QTensor`` of shape
+    (d_out, d_in); a stack (n, d_in, d_out) slice by slice into one whose
+    children lead with n and whose aux shape is one slice's, the layout
+    ``repro.models.layers.expert_apply`` reads."""
+    from repro.quant import QTensor
+    if w.ndim == 3:
+        return jax.vmap(lambda s: quantize(s, group_size))(w)
+    return QTensor.from_dense(w.T, bits=4, group_size=group_size)
+
+
 def served_params(seed: int, block, dims, recipe: Recipe):
     """The program's parameter tree for serving: the ``block`` module's
-    ``served_block`` of every block, its linears stacked INT4 ``QTensor``s
-    packed by ``QTensor.from_dense`` from ``block_f32``, and its
+    ``served_block`` of every block, its linears INT4 ``QTensor``s packed by
+    :func:`quantize` from ``block_f32`` and stacked over the blocks, and its
     ``outer_f32`` in float32. One jitted call; a loop over blocks keeps one
     block's floats live at a time."""
-    from repro.quant import QTensor
-
     nl = dims.num_hidden_layers
-
-    def quantize(w):                      # stored (d_in, d_out) → packed
-        return QTensor.from_dense(w.T, bits=4, group_size=recipe.group_size)
 
     def one_block(key0, i):
         return block.served_block(
-            block.block_f32(jax.random.fold_in(key0, i), dims), quantize)
+            block.block_f32(jax.random.fold_in(key0, i), dims),
+            lambda w: quantize(w, recipe.group_size))
 
     @jax.jit
     def build(key0):

@@ -51,6 +51,7 @@ import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from bench import trace_reduce
+from bench.harness.cli import BenchError
 
 try:                        # the program's vocabulary; absent before it
     from repro.obs.spans import SCOPES
@@ -61,6 +62,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCAN_OPS = frozenset(("dynamic_slice", "squeeze", "dynamic_update_slice"))
 CONTAINERS = frozenset(trace_reduce.CONTAINERS)
 KV_BUCKETS = ("scan.kv", "unscoped.kv")
+DEQUANT_WRAPPERS = frozenset(("jit(dequant_matmul)",
+                              "vmap(jit(dequant_matmul))"))
 ENGINE = "engine."
 _INSTR = re.compile(r"^%?([^\s=]+)")
 
@@ -262,9 +265,10 @@ def bucket(ins: Optional[Instr], pool, loops=frozenset()) -> str:
 
 def _weight_gather(ins: Optional[Instr]) -> bool:
     """An op of the dequant-matmul wrapper that is not its Pallas kernel:
-    the scale/zero gathers and the activation split."""
+    the scale/zero gathers and the activation split, of one linear or of
+    stacked experts (the wrapper under ``vmap``)."""
     return (ins is not None and ins.opcode != "custom-call"
-            and "jit(dequant_matmul)" in ins.op_name.split("/"))
+            and not DEQUANT_WRAPPERS.isdisjoint(ins.op_name.split("/")))
 
 
 # -- the reduction ------------------------------------------------------------
@@ -303,10 +307,18 @@ def newest(root: str = ROOT) -> Optional[str]:
 
 def pool_shape(block, dims, engine_cfg: dict) -> Optional[tuple]:
     """Per-layer KV pool shape of a paged engine configuration: its pages
-    of the block's KV heads and head size."""
+    of the block's KV heads and head size. There is one pool, so the
+    block's attention layers all have one page shape; a block whose layers
+    differ there is an error."""
     if engine_cfg.get("kv_layout") != "paged":
         return None
-    _, _, kv_heads, head_dim = block.decode_attention(dims)
+    pages = {(kv_heads, head_dim) for _, kv_heads, head_dim, _
+             in block.decode_attention(dims)}
+    if len(pages) != 1:
+        raise BenchError(f"the block's attention layers have (KV heads, "
+                         f"head size) {sorted(pages)}; the paged pool "
+                         f"holds one page shape")
+    (kv_heads, head_dim), = pages
     return (int(engine_cfg["num_pages"]), int(engine_cfg["page_size"]),
             int(kv_heads), int(head_dim))
 

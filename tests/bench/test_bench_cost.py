@@ -19,7 +19,8 @@ def test_dequant_matmul_hand_count():
 def test_dequant_matmul_call_shape_from_trace_event():
     shapes = ((16, 14336), (16, 2048), (16, 2048), (14336, 2048),
               (14336, 32), (14336, 32))
-    assert dequant_matmul.call_shape(shapes) == (16, 4096, 14336, 128)
+    assert dequant_matmul.call_shape(shapes) == (1, (16, 4096, 14336,
+                                                      128))
 
 
 class _Trace:
@@ -62,7 +63,8 @@ def test_model_flop_hand_count():
     assert dense.linear_flop_per_token(DIMS) == 2 * 2 * per_block
     assert dense.head_flop(DIMS) == 2 * 8 * 10
     assert dense.attn_flop(DIMS, 5) == 4 * 2 * 2 * 4 * 5
-    assert dense.decode_flop(DIMS, 3, 12) == (
+    # three decode tokens whose live contexts sum to 12
+    assert dense.decode_flop(DIMS, [3, 4, 5]) == (
         3 * (2 * 2 * per_block + 160) + 4 * 2 * 2 * 4 * 12)
 
 
@@ -74,4 +76,4 @@ def test_non_gated_block_has_six_linears():
 
 
 def test_decode_attention_is_every_layer():
-    assert dense.decode_attention(DIMS) == (2, 2, 1, 4)
+    assert dense.decode_attention(DIMS) == ((2, 1, 4, None),) * 2

@@ -217,14 +217,15 @@ def test_each_new_reader_reads_the_scoped_trace(scoped_path, monkeypatch,
 # before the block moved into bench/harness/blocks/: the same floats, to
 # the bit, on the same trace and steps. The steps are the trace's own three
 # decode steps, two rows each (its engine.decode spans: 6 rows, 312 live
-# tokens in all).
+# tokens in all), each row one token further on at each step.
 @pytest.mark.parametrize("metric,value", [
     ("mfu.decode", 0.2523665984343416),
     ("roofline.flash_decode", 5.898294205323746)])
 def test_block_readers_read_as_before_on_the_scoped_trace(scoped_path,
                                                           metric, value):
     from bench.harness.serve import StepRecord
-    steps = [StepRecord(2, 102), StepRecord(2, 104), StepRecord(2, 106)]
+    steps = [StepRecord((50, 52)), StepRecord((51, 53)),
+             StepRecord((52, 54))]
     peaks = cli.peaks_for(ROOT, "TPU v5 lite")
     assert cli.reader(ROOT, metric)(_context(scoped_path, steps),
                                     peaks) == value

@@ -10,6 +10,7 @@ test worker collects the same tests and only the worker that runs this file
 loads the TPU compiler. The persistent compilation cache is off around the
 compiles: entries for a described device cannot be read back here.
 """
+import dataclasses
 import os
 
 import jax
@@ -73,18 +74,20 @@ def _dequant(m: int, n: int, k: int):
         ((n, k // GROUP),)]
 
 
-def _flash(paged: bool, int8: bool, stacked: bool = False):
+def _flash(paged: bool, int8: bool, stacked: bool = False, *,
+           slots=SLOTS, t=T, pages=PAGES, layers=CFG.num_layers, heads=H,
+           kv_heads=HK, head_dim=HD):
     """flash_decode on a slot cache, one layer's page pool, or (stacked)
     the pools of every layer read at a traced layer index."""
-    lead = (PAGES, PAGE) if paged else (SLOTS, T)
+    lead = (pages, PAGE) if paged else (slots, t)
     if stacked:
-        lead = (CFG.num_layers,) + lead
-    kv = [(lead + (HK, HD), jnp.uint8 if int8 else jnp.float32)]
+        lead = (layers,) + lead
+    kv = [(lead + (kv_heads, head_dim), jnp.uint8 if int8 else jnp.float32)]
     if int8:          # one group per head: f32 scale, uint8 zero-point
-        kv += [(lead + (HK, 1),), (lead + (HK, 1), jnp.uint8)]
-    shapes = [((SLOTS, H, HD),)] + kv + kv + [((SLOTS,), jnp.int32)]
+        kv += [(lead + (kv_heads, 1),), (lead + (kv_heads, 1), jnp.uint8)]
+    shapes = [((slots, heads, head_dim),)] + kv + kv + [((slots,), jnp.int32)]
     if paged:
-        shapes.append(((SLOTS, T // PAGE), jnp.int32))
+        shapes.append(((slots, t // PAGE), jnp.int32))
     if stacked:
         shapes.append(((), jnp.int32))
 
@@ -98,7 +101,7 @@ def _flash(paged: bool, int8: bool, stacked: bool = False):
             kc, ks, kz, vc, vs, vz = planes
             return decode_attn.flash_decode(
                 q, kc, vc, lengths, k_scale=ks, k_zero=kz, v_scale=vs,
-                v_zero=vz, group_size=HD, table=table, layer=layer)
+                v_zero=vz, group_size=head_dim, table=table, layer=layer)
         return decode_attn.flash_decode(q, planes[0], planes[1], lengths,
                                         table=table, layer=layer)
     return fn, shapes
@@ -122,6 +125,16 @@ CASES = {
     "flash_decode_paged_int8": lambda: _flash(True, True),
     "flash_decode_paged_stacked_dense": lambda: _flash(True, False, True),
     "flash_decode_paged_stacked_int8": lambda: _flash(True, True, True),
+    # head_dim 128: the paged loop over live pages
+    "flash_decode_paged_loop_dense": lambda: _flash(True, False,
+                                                    head_dim=128),
+    "flash_decode_paged_loop_stacked_dense": lambda: _flash(
+        True, False, True, head_dim=128),
+    # granite-8b.serve.assist: 12 rows, a 256-page table, the f32 pools of
+    # 36 layers (a flat (36·1024, 16, 8, 128) pool in the kernel)
+    "flash_decode_paged_loop_granite_cell": lambda: _flash(
+        True, False, True, slots=12, t=4096, pages=1024, layers=36,
+        heads=32, kv_heads=8, head_dim=128),
     "kv_dequant": _kv_dequant,
     "topk_mask": lambda: ((lambda z: topk_mask.topk_row(z, F // 2)),
                           [((D, F),)]),
@@ -135,6 +148,12 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
                                  sharding=one_chip) for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if case.startswith("flash_decode_paged_loop"):
+        # one grid step a row, whatever the table's length
+        grids = [e.params["grid_mapping"].grid
+                 for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        assert grids == [(shapes[0][0][0],)]
 
 
 @pytest.fixture
@@ -147,13 +166,17 @@ def native_kernels(monkeypatch):
     ops.decode_attn_paged.clear_cache()
 
 
-@pytest.mark.parametrize("name", ["decode", "chunk"])
+@pytest.mark.parametrize("name", ["decode", "chunk", "decode_hd128"])
 def test_paged_step_programs_keep_pools_in_place_on_v5e(
         name, one_chip, no_persistent_cache, native_kernels):
     """With the fused paged flash-decode kernel, the chip's decode and
     chunk programs take no slice of a pool, write none back and copy none:
-    the stacked pools stay in the donated buffers."""
+    the stacked pools stay in the donated buffers. At head_dim 128 the
+    decode program runs the paged loop over live pages."""
     cfg = get_tiny_config("llama32-1b")
+    if name.endswith("_hd128"):
+        cfg = dataclasses.replace(cfg, head_dim=128)
+        name = name[:-len("_hd128")]
     model = build_model(cfg, remat=False)
     eng = Engine(model, model.init(jax.random.PRNGKey(0)), EngineConfig(
         num_slots=4, max_len=64, prompt_buckets=(16, 32), kv_layout="paged",

@@ -1,4 +1,4 @@
-"""Continuous-batching engine load test: dense-KV vs INT8-KV slot cache,
+"""Continuous-batching engine load test: dense-KV vs INT8-KV page pool,
 plus burst-arrival and long-prompt scenarios.
 
 Generates a Zipf-length request trace (many short prompts/outputs, a heavy
@@ -15,8 +15,8 @@ ride along:
 - **long_prompt** — prompts beyond the largest bucket stream through the
   bucket-width chunked-prefill program; greedy output stays bit-identical
   to the static path;
-- **shared_prefix** — a Zipf trace behind one shared system prefix on the
-  PAGED engine: repeat prefixes admit copy-free off the prefix cache
+- **shared_prefix** — a Zipf trace behind one shared system prefix:
+  repeat prefixes admit copy-free off the prefix cache
   (reports hit rate and prompt tokens reused), parity-checked;
 - **overload** — an oversubscribed page pool behind a bounded queue
   (``max_queue``): decode extension preempts the youngest request (pages
@@ -29,20 +29,20 @@ ride along:
   terminal, the pool drains to zero, and each ``ok`` survivor's output is
   bit-identical to a fault-free run of the same trace.
 
-The main dense/int8 slot rows are joined by ``paged_dense``/``paged_int8``
-rows (same trace through the paged pool) carrying ``page_stats``.
+Every row carries the pool's ``page_stats``.
 
     PYTHONPATH=src python -m benchmarks.engine_bench [--tiny]
 
 Emits ``results/BENCH_engine.json`` via the shared emitter (CI uploads it
-next to the other BENCH artifacts). A greedy parity check against the
-static serving path runs on the first few requests of the dense trace —
-the engine must be bit-identical per request. The engine decodes through
-the fused flash-decode kernel by default, so that slice doubles as the
-fused-vs-reference gate; the INT8 rows additionally rerun their trace
-through the reference dequant-then-attend path (bit-identical greedy
-tokens) and bound the fused decode-logit gap at the 0.05·scale tolerance
-test_engine.py uses. Every throughput row carries per-status token
+next to the other BENCH artifacts). Its times are CPU/interpreter
+measurements of the control flow, not speed claims. A greedy parity check
+against the static serving path runs on the first few requests of the
+dense trace — the engine must be bit-identical per request. The engine
+decodes through the fused flash-decode kernel by default, so that slice
+doubles as the fused-vs-reference gate; the INT8 row additionally reruns
+its trace through the reference gather-then-attend path (bit-identical
+greedy tokens) and bounds the fused decode-logit gap at the 0.05·scale
+tolerance test_engine.py uses. Every throughput row carries per-status token
 accounting (``tokens_by_status``, ``ok_tok_per_s``) so scenarios that
 shed or fault stay comparable to their fault-free baselines.
 """
@@ -154,7 +154,7 @@ def run_engine(model, params, cfg, ecfg: EngineConfig, reqs):
         "rejected": qs["rejected"],
         "compiled_programs": compiled,
         "recompiled_after_warmup": compiled != compiled_warm,
-        **({"page_stats": ps} if (ps := engine.page_stats()) else {}),
+        "page_stats": engine.page_stats(),
     }, results
 
 
@@ -184,23 +184,24 @@ def check_fused_reference_tokens(model, params, cfg, ecfg, reqs, results):
     return len(ref)
 
 
-def check_int8_fused_logits(model, params, cfg, max_len):
-    """One decode step over a shared INT8 cache, fused vs reference read:
-    logits must agree within the 0.05·scale bound test_engine.py enforces
-    for quantized storage. The measured gap is ~1e-6 — the kernel's
-    in-tile dequant reproduces the reference expansion's op order — and
-    lands in the JSON so regressions are visible, not just pass/fail."""
-    from repro.serving.kv_cache import KVCacheConfig, init_slot_cache
-    rng = np.random.default_rng(7)
-    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, size=(2, 12)),
-                       jnp.int32)
-    cache = init_slot_cache(cfg, KVCacheConfig(num_slots=2, max_len=max_len,
-                                               quantized=True))
-    # static-style scalar pos: multi-token prefill writes need it (the
-    # per-slot vector path is one token per step); decode broadcasts it
-    cache["pos"] = jnp.zeros((), jnp.int32)
-    logits, cache = jax.jit(model.prefill)(params, {"tokens": toks}, cache)
-    tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+def check_int8_fused_logits(model, params, cfg, ecfg, reqs):
+    """One decode step over an INT8 page pool that served ``reqs``, fused
+    vs reference read: logits must agree within the 0.05·scale bound
+    test_engine.py enforces for quantized storage. Each slot reads a run
+    of pages at a position spread over [0, max_len). The measured gap is
+    ~1e-6 — the kernel's in-tile dequant reproduces the reference
+    expansion's op order — and lands in the JSON so regressions are
+    visible, not just pass/fail."""
+    engine = Engine(model, params, ecfg)
+    for r in reqs:
+        engine.submit(r)
+    engine.run()
+    s, n = ecfg.num_slots, engine.pages_per_slot
+    cache = {"k": engine.kv["k"], "v": engine.kv["v"],
+             "pos": jnp.linspace(0, ecfg.max_len - 1, s).astype(jnp.int32),
+             "table": jnp.arange(s * n, dtype=jnp.int32).reshape(s, n)
+             % engine.alloc.num_pages}
+    tok = jnp.arange(1, s + 1, dtype=jnp.int32)[:, None]
     fused_m = dataclasses.replace(model, use_fused_decode=True)
     d_ref, _ = jax.jit(model.decode_step)(params, tok, cache)
     d_fused, _ = jax.jit(fused_m.decode_step)(params, tok, cache)
@@ -284,8 +285,7 @@ def shared_prefix_scenario(model, params, cfg, *, slots, requests, seed=3):
             rid=i, prompt=np.concatenate([prefix, tail.astype(np.int32)]),
             max_new_tokens=gen, sampling=SamplingParams()))
     ecfg = EngineConfig(num_slots=slots, max_len=max_len,
-                        kv_dtype=jnp.float32, kv_layout="paged",
-                        page_size=pg)
+                        kv_dtype=jnp.float32, page_size=pg)
     row, results = run_engine(model, params, cfg, ecfg, reqs)
     ps = row["page_stats"]
     hits, misses = ps["prefix_hits"], ps["prefix_misses"]
@@ -325,7 +325,7 @@ def overload_scenario(model, params, cfg, *, requests=8, max_queue=6,
     pg, max_len, gen, slots, num_pages = 8, 48, 12, 3, 9
     reqs = _overload_requests(cfg, requests, gen, seed)
     ecfg = EngineConfig(num_slots=slots, max_len=max_len,
-                        kv_dtype=jnp.float32, kv_layout="paged",
+                        kv_dtype=jnp.float32,
                         page_size=pg, num_pages=num_pages,
                         prefix_caching=False, max_queue=max_queue)
     engine = Engine(model, params, ecfg)
@@ -373,7 +373,7 @@ def _result_row(engine, results, wall):
         "queue_depth_mean": qs["mean"],
         "queue_depth_dropped": qs["dropped"],
         "rejected": qs["rejected"],
-        **({"page_stats": ps} if (ps := engine.page_stats()) else {}),
+        "page_stats": engine.page_stats(),
     }, results
 
 
@@ -391,7 +391,7 @@ def chaos_scenario(model, params, cfg, *, requests=8, seed=5):
     pg, max_len, gen, slots, num_pages = 8, 48, 12, 3, 9
     max_queue = 6
     ecfg = EngineConfig(num_slots=slots, max_len=max_len,
-                        kv_dtype=jnp.float32, kv_layout="paged",
+                        kv_dtype=jnp.float32,
                         page_size=pg, num_pages=num_pages,
                         prefix_caching=False, max_queue=max_queue)
 
@@ -475,17 +475,14 @@ def main():
           f"requests={args.requests} max_len={max_len} "
           f"(mean prompt {mean_p:.1f}, mean new {mean_n:.1f})")
 
-    # page_size must divide max_len for paged/slot bit-parity; 12 divides
-    # both the CI (24+12) and default (48+24) shapes
+    # page_size must divide max_len for bit-parity with the static path;
+    # 12 divides both the CI (24+12) and default (48+24) shapes
     page = 12 if max_len % 12 == 0 else 8
     rows = {}
-    for name, quant, layout in (("dense", False, "slots"),
-                                ("int8", True, "slots"),
-                                ("paged_dense", False, "paged"),
-                                ("paged_int8", True, "paged")):
+    for name, quant in (("dense", False), ("int8", True)):
         ecfg = EngineConfig(num_slots=args.slots, max_len=max_len,
                             kv_dtype=jnp.bfloat16, kv_quantized=quant,
-                            kv_layout=layout, page_size=page)
+                            page_size=page)
         rows[name], results = run_engine(model, params, cfg, ecfg, reqs)
         if name == "dense" and args.parity_check:
             # bf16 cache rounds K/V — rerun the parity slice on an f32
@@ -493,7 +490,7 @@ def main():
             # on) while static_greedy_reference runs the unfused reference
             # model, so this is the fused-vs-reference greedy gate.
             ecfg32 = EngineConfig(num_slots=args.slots, max_len=max_len,
-                                  kv_dtype=jnp.float32)
+                                  kv_dtype=jnp.float32, page_size=page)
             _, res32 = run_engine(model, params, cfg, ecfg32, reqs)
             n = check_parity(model, params, reqs, res32, max_len,
                              args.parity_check,
@@ -506,6 +503,12 @@ def main():
             rows[name]["fused_parity_checked"] = n
             print(f"  parity: {name} fused == reference path for "
                   f"{n}/{n} requests (greedy tokens)")
+            gap, lscale = check_int8_fused_logits(model, params, cfg, ecfg,
+                                                  reqs)
+            rows[name]["fused_logit_gap"] = gap
+            rows[name]["fused_logit_bound"] = 0.05 * lscale
+            print(f"  parity: int8 fused decode logits within {gap:.2e} of "
+                  f"the reference read (bound {0.05 * lscale:.2e})")
         r = rows[name]
         print(f"  {name:11s} {r['tok_per_s']:8.0f} tok/s   "
               f"p50 {r['latency_p50_ms']:7.1f}ms   "
@@ -513,12 +516,6 @@ def main():
               f"util {r['slot_utilization']:.2f}   "
               f"kv {r['kv_cache_bytes'] / 1e6:6.2f}MB   "
               f"recompiled={r['recompiled_after_warmup']}")
-
-    gap, lscale = check_int8_fused_logits(model, params, cfg, max_len)
-    rows["int8"]["fused_logit_gap"] = gap
-    rows["int8"]["fused_logit_bound"] = 0.05 * lscale
-    print(f"  parity: int8 fused decode logits within {gap:.2e} of the "
-          f"reference read (bound {0.05 * lscale:.2e})")
 
     ratio = rows["dense"]["kv_cache_bytes"] / max(rows["int8"]["kv_cache_bytes"], 1)
     assert rows["int8"]["kv_cache_bytes"] < rows["dense"]["kv_cache_bytes"], \
@@ -593,7 +590,6 @@ def main():
         "max_len": max_len,
         "mean_prompt_len": mean_p, "mean_new_tokens": mean_n,
         "dense": rows["dense"], "int8": rows["int8"],
-        "paged_dense": rows["paged_dense"], "paged_int8": rows["paged_int8"],
         "burst": burst, "long_prompt": longp,
         "shared_prefix": shared, "overload": overload, "chaos": chaos,
         "kv_compression_x": ratio,

@@ -76,8 +76,8 @@ def run():
 
 
 def _decode_attn_rows(rng):
-    """Fused flash-decode vs reference dequant-then-attend over the
-    slot/paged × dense/INT8 matrix at two cache depths. Derived metric is
+    """Fused flash-decode vs reference gather-then-attend over paged dense
+    and INT8 pools at two cache depths. Derived metric is
     effective KV bandwidth (bytes the dense read would stream) — decode
     attention is memory-bound, so that's the roofline axis. Each fused/ref
     pair is allclose-checked before timing (the benchmark doubles as an
@@ -87,29 +87,22 @@ def _decode_attn_rows(rng):
     rows = []
     for t in (128, 512):
         q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.float32)
-        kc = jnp.asarray(rng.normal(size=(b, t, hk, d)), jnp.float32)
-        vc = jnp.asarray(rng.normal(size=(b, t, hk, d)), jnp.float32)
         lens = jnp.asarray(rng.integers(1, t + 1, size=b), jnp.int32)
         kv_bytes = 2 * b * t * hk * d * 4            # dense f32 K+V stream
-        qk, qv = kv_quantize(kc, d), kv_quantize(vc, d)
-        qargs = dict(k_scale=qk.scale, k_zero=qk.zero, v_scale=qv.scale,
-                     v_zero=qv.zero, group_size=d)
 
-        # paged pool: identity page mapping is enough for timing — the
-        # kernel's gather cost doesn't depend on the permutation
+        # identity page mapping is enough for timing — the kernel's
+        # gather cost doesn't depend on the permutation
         npg = t // page
-        pool_k = kc.reshape(b * npg, page, hk, d)
-        pool_v = vc.reshape(b * npg, page, hk, d)
+        pool_k = jnp.asarray(rng.normal(size=(b * npg, page, hk, d)),
+                             jnp.float32)
+        pool_v = jnp.asarray(rng.normal(size=(b * npg, page, hk, d)),
+                             jnp.float32)
         table = jnp.arange(b * npg, dtype=jnp.int32).reshape(b, npg)
         pk, pv = kv_quantize(pool_k, d), kv_quantize(pool_v, d)
         pargs = dict(k_scale=pk.scale, k_zero=pk.zero, v_scale=pv.scale,
                      v_zero=pv.zero, group_size=d)
 
         cases = [
-            ("slot_dense", lambda use: ops.decode_attn(
-                q, kc, vc, lens, use_pallas=use)),
-            ("slot_int8", lambda use: ops.decode_attn(
-                q, qk.codes, qv.codes, lens, use_pallas=use, **qargs)),
             ("paged_dense", lambda use: ops.decode_attn_paged(
                 q, pool_k, pool_v, table, lens, use_pallas=use)),
             ("paged_int8", lambda use: ops.decode_attn_paged(

@@ -16,8 +16,8 @@ Phases, each printing its wall and compile seconds:
   (d) ckpt     — ``save_packed_checkpoint`` → ``load_packed_checkpoint``;
                  every quantized leaf must come back as a nibble-packed
                  ``QTensor`` (the ``dequant_matmul`` kernel path);
-  (e) serve    — continuous batching on the packed weights, slot/dense KV
-                 and paged/INT8 KV: every request ``ok``, no recompilation
+  (e) serve    — continuous batching on the packed weights, paged dense KV
+                 and paged INT8 KV: every request ``ok``, no recompilation
                  after warmup, the engine's decode and chunk programs hold
                  the expected Pallas kernels, fused-decode logits close to
                  the reference cache read, dense-KV greedy tokens
@@ -304,37 +304,33 @@ def serve(jax, jnp, cfg, model, params, seed: int, **ecfg_kw):
 
 
 def decode_parity(jax, jnp, model, engine) -> None:
-    """The fused flash-decode read against the reference dequant-then-attend
-    read: one decode step on the cache the engine's run left behind, 8 rows
-    at positions spread over [0, max_len), so rows end in different tiles
-    (pages) and skip different dead ones."""
+    """The fused flash-decode read against the reference gather-then-attend
+    read: one decode step on the pool the engine's run left behind, 8 rows
+    at positions spread over [0, max_len), so rows end in different pages
+    and skip different dead ones."""
     from repro.serving.engine import step_jit
 
     vocab = model.cfg.vocab_size
     s = engine.cfg.num_slots
     pos = jnp.linspace(0, MAX_LEN - 1, s).astype(jnp.int32)
     tok = jnp.arange(1, s + 1, dtype=jnp.int32)[:, None]
-    table = ()
-    if engine.alloc is not None:            # paged: slot i owns a page run
-        n = engine.pages_per_slot
-        table = (jnp.arange(s * n, dtype=jnp.int32).reshape(s, n)
-                 % engine.alloc.num_pages,)
+    n = engine.pages_per_slot                # slot i owns a page run
+    table = (jnp.arange(s * n, dtype=jnp.int32).reshape(s, n)
+             % engine.alloc.num_pages)
 
     def logits_fn(fused: bool):
         m = dataclasses.replace(model, use_fused_decode=fused)
 
-        def f(params, kv, *tbl):
-            cache = {"k": kv["k"], "v": kv["v"], "pos": pos}
-            if tbl:
-                cache["table"] = tbl[0]
+        def f(params, kv, tbl):
+            cache = {"k": kv["k"], "v": kv["v"], "pos": pos, "table": tbl}
             return m.decode_step(params, tok, cache)[0][:, 0, :vocab]
         return step_jit(f)
 
     fused, ref = logits_fn(True), logits_fn(False)
-    native_kernels(fused.lower(engine.params, engine.kv, *table).as_text(),
+    native_kernels(fused.lower(engine.params, engine.kv, table).as_text(),
                    ["flash_decode"], "fused decode step")
-    k = fused(engine.params, engine.kv, *table)
-    r = ref(engine.params, engine.kv, *table)
+    k = fused(engine.params, engine.kv, table)
+    r = ref(engine.params, engine.kv, table)
     rel = float(jnp.max(jnp.abs(k - r)) / jnp.max(jnp.abs(r)))
     same = int(jnp.sum(jnp.argmax(k, -1) == jnp.argmax(r, -1)))
     print(f"[smoke]   decode logits fused vs reference read at positions "
@@ -395,23 +391,22 @@ def logit_parity(jax, jnp, cfg, model, params, reqs) -> None:
 
 
 def pin_cost(jax, jnp, model, params) -> None:
-    """Device time of the packed model's decode step (8 rows over a
-    max_len cache, fused read) and prefill (8 x 32 tokens), built with
+    """Device time of the packed model's static decode step (8 rows over
+    a max_len cache, reference read) and prefill (8 x 32 tokens), built with
     ``step_jit`` (f32 dots) and with plain ``jax.jit`` (TPU default
     precision); the variants alternate call by call, median of
     TIMED_CALLS each."""
     from repro.serving.engine import step_jit
 
-    m = dataclasses.replace(model, use_fused_decode=True)
     b, w = SLOTS, PROMPT_BUCKETS[-1]
     tokens = jnp.ones((b, w), jnp.int32)
 
     def prefill(p, toks):
-        return m.prefill(p, {"tokens": toks},
-                         m.init_cache(b, MAX_LEN, jnp.float32))
+        return model.prefill(p, {"tokens": toks},
+                             model.init_cache(b, MAX_LEN, jnp.float32))
 
     def decode(p, tok, cache):
-        return m.decode_step(p, tok, cache)
+        return model.decode_step(p, tok, cache)
 
     variants = {"highest": step_jit, "default": jax.jit}
     progs = {k: (jit(prefill), jit(decode, donate_argnums=2))
@@ -475,10 +470,9 @@ def main() -> None:
         packed = checkpoint_roundtrip(jax, model, cp, report, args.seed,
                                       workdir)
     del cp, report
-    with phase("e serve slot/dense", clock, times):
+    with phase("e serve paged/dense", clock, times):
         engine, reqs, results = serve(jax, jnp, cfg, model, packed,
-                                      args.seed, kv_layout="slots",
-                                      kv_quantized=False)
+                                      args.seed, kv_quantized=False)
         decode_parity(jax, jnp, model, engine)
         del engine
         verify_static(model, packed, reqs, results)
@@ -486,7 +480,7 @@ def main() -> None:
         pin_cost(jax, jnp, model, packed)
     with phase("e serve paged/int8", clock, times):
         engine, _, _ = serve(jax, jnp, cfg, model, packed, args.seed,
-                             kv_layout="paged", kv_quantized=True)
+                             kv_quantized=True)
         decode_parity(jax, jnp, model, engine)
         del engine
 

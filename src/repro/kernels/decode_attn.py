@@ -1,18 +1,15 @@
-"""Pallas TPU kernel: fused flash-decode attention over the serving KV cache.
+"""Pallas TPU kernel: fused flash-decode attention over the serving KV pool.
 
 One query token per row attends its whole cache history in a single fused
-program — no materialized dense K/V. Four variants share the online
-softmax, the in-tile INT8 dequant and the emit guard:
-
-- **slot layout**: per-layer cache ``(B, T, Hk, D)`` (B = slots), dense
-  floats or INT8 codes + per-head-group f32 scale / uint8 zero dequantized
-  IN-TILE.
-- **paged layout**: the stacked page pools ``(L, P, page, Hk, D)`` of all
-  layers, read as one flat pool ``(L·P, page, Hk, D)`` (a free reshape)
-  through a ``(B, n_pages)`` block table offset to the layer's pages.
-  Sentinel entries ``== P`` clip to the layer's last page before the
-  ``layer·P`` offset, so no read leaves the layer, and no per-layer pool
-  is ever sliced out.
+program — no materialized dense K/V. The cache is the stacked page pools
+``(L, P, page, Hk, D)`` of all layers, dense floats or INT8 codes +
+per-head-group f32 scale / uint8 zero dequantized IN-TILE, read as one
+flat pool ``(L·P, page, Hk, D)`` (a free reshape) through a
+``(B, n_pages)`` block table offset to the layer's pages. Sentinel
+entries ``== P`` clip to the layer's last page before the ``layer·P``
+offset, so no read leaves the layer, and no per-layer pool is ever sliced
+out. Two variants share the online softmax, the in-tile INT8 dequant and
+the emit guard:
 
 Paged pools of 32-bit floats whose head_dim is whole 128-lane tiles run
 the **paged loop**: grid ``(B,)``, the pools left in HBM. Each row loops
@@ -23,13 +20,13 @@ scalar-prefetched table into a double-buffered VMEM block; the next block
 one KV head at a time. No page at or past a row's length is copied and a
 length-0 row copies nothing.
 
-Everything else runs the **tiled grid** ``(B, num_k_tiles)``, K tiles
-innermost, each fetched by its BlockSpec: ``block_t`` tokens of a slot
-cache, or one page gathered through the table in the index map (Mosaic
-slices an HBM ref only along a minor dim of whole 128-lane tiles, so INT8
-planes and narrow heads cannot be copied by hand). A tile at or beyond a
-row's length skips its compute (``pl.when``) and its index map clamps to
-the last live tile, so the pipeline elides the copy (same-index revisit).
+Everything else runs the **tiled grid** ``(B, n_pages)``, pages
+innermost, each page gathered through the table in its BlockSpec's index
+map (Mosaic slices an HBM ref only along a minor dim of whole 128-lane
+tiles, so INT8 planes and narrow heads cannot be copied by hand). A page
+at or beyond a row's length skips its compute (``pl.when``) and its index
+map clamps to the last live page, so the pipeline elides the copy
+(same-index revisit).
 
 Softmax is accumulated online (m, l, acc scratch), f32 statistics, causal
 mask ``k_idx < length``. Rows with ``length == 0`` produce exactly zero
@@ -155,12 +152,10 @@ def _emit(o_ref, l_ref, acc_ref):
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def _make_tiled_kernel(*, bt: int, sm_scale: float, group: int, n_kv: int,
-                       n_prefetch: int):
-    """Grid (B, K tiles): each tile fetched by its BlockSpec."""
-    def kernel(*refs):
-        lens_ref = refs[0]
-        q_ref, *rest = refs[n_prefetch:]
+def _make_tiled_kernel(*, bt: int, sm_scale: float, group: int, n_kv: int):
+    """Grid (B, pages): each page fetched by its BlockSpec."""
+    def kernel(lens_ref, table_ref, *refs):
+        q_ref, *rest = refs
         kv_refs = rest[:n_kv]
         o_ref, m_ref, l_ref, acc_ref = rest[n_kv:]
         b = pl.program_id(0)
@@ -325,28 +320,26 @@ def _paged_loop(q, pools, lengths, table, *, page: int, sm_scale: float,
     )(lengths, table, q, *pools)
 
 
-def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
-                 k_scale=None, k_zero=None, v_scale=None, v_zero=None,
-                 group_size: int = 0, table=None, layer=None,
-                 block_t: int = 256, interpret: bool = False) -> jax.Array:
+def flash_decode(q: jax.Array, k, v, lengths: jax.Array, table: jax.Array,
+                 *, k_scale=None, k_zero=None, v_scale=None, v_zero=None,
+                 group_size: int = 0, layer=None,
+                 interpret: bool = False) -> jax.Array:
     """Fused flash-decode attention. Returns (B, H, D) in q's dtype.
 
     q: (B, H, D) — one decode token per row, RoPE already applied.
     lengths: (B,) int32 — row b attends cache positions [0, lengths[b]);
     length 0 → exactly-zero output (a parked slot).
 
-    Slot layout (``table=None``): k/v are (B, T, Hk, D) — dense floats, or
-    uint8 codes with (B, T, Hk, D/group) ``*_scale``/``*_zero`` planes;
-    ``block_t`` tiles the K loop (clamped to T).
-    Paged layout: k/v are the stacked pools (L, P, page, Hk, D) (same
-    quant split), read at layer ``layer`` (an int32 scalar, traced or
-    static), or one layer's pool (P, page, Hk, D) with ``layer=None``;
-    ``table`` (B, n_pages) int32 maps row positions to physical pages;
-    entries == P are sentinels (masked). The stack is read in place as a
-    flat (L·P, page, Hk, D) pool with the table offset by ``layer·P``.
-    f32 pools with D a multiple of 128 run the paged loop (each row copies
-    its live pages only, ``pages_per_block`` pages to a block); others
-    tile the K loop one page a grid step. ``block_t`` plays no part.
+    k/v are the stacked pools (L, P, page, Hk, D) — dense floats, or uint8
+    codes with (L, P, page, Hk, D/group) ``*_scale``/``*_zero`` planes —
+    read at layer ``layer`` (an int32 scalar, traced or static), or one
+    layer's pool (P, page, Hk, D) with ``layer=None``; ``table``
+    (B, n_pages) int32 maps row positions to physical pages; entries == P
+    are sentinels (masked). The stack is read in place as a flat
+    (L·P, page, Hk, D) pool with the table offset by ``layer·P``. f32
+    pools with D a multiple of 128 run the paged loop (each row copies its
+    live pages only, ``pages_per_block`` pages to a block); others tile
+    the K loop one page a grid step.
     """
     b, h, d = q.shape
     quant = k_scale is not None
@@ -363,58 +356,36 @@ def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
                pltpu.VMEM((h, 128), jnp.float32),
                pltpu.VMEM((h, d), jnp.float32)]
 
-    if table is not None:
-        per_layer = k.shape[-4]
-        offset = 0
-        if layer is not None:
-            # one flat pool; a sentinel clamps to the layer's last page
-            # before the offset, so no other layer's page is ever read
-            operands = tuple(x.reshape(-1, *x.shape[2:]) for x in operands)
-            offset = jnp.asarray(layer, jnp.int32) * per_layer
-        table = jnp.minimum(table.astype(jnp.int32), per_layer - 1) + offset
-        page, n_pages = k.shape[-3], table.shape[1]
-        lengths = jnp.minimum(lengths, n_pages * page)
-        if all(_copies_by_page(x) for x in operands):
-            return _paged_loop(q, operands, lengths, table, page=page,
-                               sm_scale=sm_scale, scratch=scratch,
-                               interpret=interpret)
-        bt = page
+    per_layer = k.shape[-4]
+    offset = 0
+    if layer is not None:
+        # one flat pool; a sentinel clamps to the layer's last page before
+        # the offset, so no other layer's page is ever read
+        operands = tuple(x.reshape(-1, *x.shape[2:]) for x in operands)
+        offset = jnp.asarray(layer, jnp.int32) * per_layer
+    table = jnp.minimum(table.astype(jnp.int32), per_layer - 1) + offset
+    page, n_pages = k.shape[-3], table.shape[1]
+    lengths = jnp.minimum(lengths, n_pages * page)
+    if all(_copies_by_page(x) for x in operands):
+        return _paged_loop(q, operands, lengths, table, page=page,
+                           sm_scale=sm_scale, scratch=scratch,
+                           interpret=interpret)
 
-        def kv_map(bi, ti, lens, tbl):
-            last = jnp.maximum(_cdiv(lens[bi], page) - 1, 0)
-            return (tbl[bi, jnp.minimum(ti, last)], 0, 0, 0)
-
-        grid = (b, n_pages)
-        prefetch = (lengths, table)
-    else:
-        t_len = k.shape[1]
-        bt = max(1, min(block_t, t_len))
-        pad = (-t_len) % bt
-        if pad:
-            operands = tuple(
-                jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2),
-                        constant_values=1 if i in (1, 4) and quant else 0)
-                for i, x in enumerate(operands))
-        lengths = jnp.minimum(lengths, t_len)
-
-        def kv_map(bi, ti, lens):
-            last = jnp.maximum(_cdiv(lens[bi], bt) - 1, 0)
-            return (bi, jnp.minimum(ti, last), 0, 0)
-
-        grid = (b, (t_len + pad) // bt)
-        prefetch = (lengths,)
+    def kv_map(bi, ti, lens, tbl):
+        last = jnp.maximum(_cdiv(lens[bi], page) - 1, 0)
+        return (tbl[bi, jnp.minimum(ti, last)], 0, 0, 0)
 
     def qo_map(bi, ti, *_):
         return (bi, 0, 0)
 
     return pl.pallas_call(
-        _make_tiled_kernel(bt=bt, sm_scale=sm_scale, group=group_size,
-                           n_kv=n_kv, n_prefetch=len(prefetch)),
+        _make_tiled_kernel(bt=page, sm_scale=sm_scale, group=group_size,
+                           n_kv=n_kv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=grid,
+            num_scalar_prefetch=2,
+            grid=(b, n_pages),
             in_specs=[pl.BlockSpec((1, h, d), qo_map)]
-            + [pl.BlockSpec((1, bt) + x.shape[2:], kv_map)
+            + [pl.BlockSpec((1, page) + x.shape[2:], kv_map)
                for x in operands],
             out_specs=pl.BlockSpec((1, h, d), qo_map),
             scratch_shapes=scratch,
@@ -422,7 +393,7 @@ def flash_decode(q: jax.Array, k, v, lengths: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         name="flash_decode",
         interpret=interpret,
-    )(*prefetch, q, *operands)
+    )(lengths, table, q, *operands)
 
 
 __all__ = ["flash_decode", "pages_per_block"]

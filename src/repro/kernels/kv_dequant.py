@@ -1,10 +1,10 @@
 """Pallas TPU kernel: INT8 KV-cache dequant — the attention-read side of the
-quantized slot cache (``repro.serving.kv_cache``).
+quantized page pools (``repro.serving.kv_cache``).
 
 Layout mirrors ``dequant_matmul``'s weight side: codes (R, K) uint8 with
-scale/zero (R, K/group) — the caller flattens (L·)S·T·Hk leading dims into
-rows R and folds heads into K, so K = Hk·D is lane-aligned for real head
-dims. Grid (R/bm, K/bk), bk a multiple of group_size so each block sees
+scale/zero (R, K/group) — the caller flattens the (L·)P·page leading dims
+into rows R and folds heads into K, so K = Hk·D is lane-aligned for real
+head dims. Grid (R/bm, K/bk), bk a multiple of group_size so each block sees
 whole groups; pure VPU elementwise expansion, emitted as the requested
 float dtype (HBM keeps the 1-byte codes, VMEM gets the floats). Codes widen
 through int32 and scales expand with lane masks

@@ -59,7 +59,7 @@ def dequant_matmul(x, packed, scale, zero, group_size: int = 128,
 @functools.partial(jax.jit, static_argnames=("group_size", "use_pallas"))
 def kv_dequant(codes, scale, zero, group_size: int, use_pallas: bool = True):
     """INT8 KV-cache expansion: codes (R, K) uint8 + per-group scale/zero →
-    (R, K) f32 (the attention-read side of the quantized slot cache)."""
+    (R, K) f32 (the attention-read side of the quantized page pools)."""
     if not use_pallas:
         return ref.kv_dequant(codes, scale, zero, group_size)
     return _kv.kv_dequant(codes, scale, zero, group_size=group_size,
@@ -67,7 +67,7 @@ def kv_dequant(codes, scale, zero, group_size: int, use_pallas: bool = True):
 
 
 def _ref_dequant_kv(codes, scale, zero, group_size: int):
-    """(B, T, Hk, D) codes + (B, T, Hk, D/g) planes → dense f32, via the
+    """(..., Hk, D) codes + (..., Hk, D/g) planes → dense f32, via the
     flattened-row reference expansion (the dequant-then-attend oracle)."""
     lead = codes.shape[:-1]
     rows = 1
@@ -79,40 +79,19 @@ def _ref_dequant_kv(codes, scale, zero, group_size: int):
     return flat.reshape(codes.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("group_size", "block_t",
-                                             "use_pallas"))
-def decode_attn(q, k, v, lengths, k_scale=None, k_zero=None, v_scale=None,
-                v_zero=None, group_size: int = 0, block_t: int = 256,
-                use_pallas: bool = True):
-    """Fused flash-decode over a slot-layout cache: q (B, H, D) one token
-    per row against k/v (B, T, Hk, D) — dense floats, or uint8 codes with
-    per-head-group scale/zero planes dequantized in-tile. ``lengths`` (B,)
-    bounds each row's K loop (length 0 → zero output). The jnp oracle
-    (``use_pallas=False``) dequantizes then attends — the pre-fusion
-    reference path."""
-    if not use_pallas:
-        if k_scale is not None:
-            k = _ref_dequant_kv(k, k_scale, k_zero, group_size)
-            v = _ref_dequant_kv(v, v_scale, v_zero, group_size)
-        return ref.decode_attn(q, k, v, lengths)
-    return _da.flash_decode(q, k, v, lengths, k_scale=k_scale, k_zero=k_zero,
-                            v_scale=v_scale, v_zero=v_zero,
-                            group_size=group_size, block_t=block_t,
-                            interpret=_interpret())
-
-
 @functools.partial(jax.jit, static_argnames=("group_size", "use_pallas"))
 def decode_attn_paged(q, k, v, table, lengths, k_scale=None, k_zero=None,
                       v_scale=None, v_zero=None, group_size: int = 0,
                       layer=None, use_pallas: bool = True):
-    """Paged fused flash-decode: k/v are the stacked page pools
-    (L, P, page, Hk, D) read at ``layer`` (int32 scalar), or one layer's
-    pool (P, page, Hk, D) with ``layer=None`` (same dense/INT8 split as
-    :func:`decode_attn`), and ``table`` (B, n_pages) int32 routes each
-    row's positions to physical pages — gathered tile-by-tile in the
-    kernel's index maps, sentinel entries (== P) masked. The oracle takes
-    the layer's pool, gathers the contiguous view, then dequantizes and
-    attends."""
+    """Fused flash-decode over the paged pools: q (B, H, D) one token per
+    row against k/v, the stacked page pools (L, P, page, Hk, D) read at
+    ``layer`` (int32 scalar), or one layer's pool (P, page, Hk, D) with
+    ``layer=None`` — dense floats, or uint8 codes with per-head-group
+    scale/zero planes dequantized in-tile. ``table`` (B, n_pages) int32
+    routes each row's positions to physical pages, sentinel entries
+    (== P) masked; ``lengths`` (B,) bounds each row's K loop (length 0 →
+    zero output). The jnp oracle (``use_pallas=False``) takes the layer's
+    pool, gathers the contiguous view, then dequantizes and attends."""
     if not use_pallas:
         if layer is not None:
             k, v = k[layer], v[layer]
@@ -142,5 +121,5 @@ def awp_prune_fused(w, c, k: int, eta, iters: int, theta0=None,
 
 
 __all__ = ["awp_pgd_step", "topk_row", "quant_project", "dequant_matmul",
-           "kv_dequant", "decode_attn", "decode_attn_paged",
+           "kv_dequant", "decode_attn_paged",
            "awp_prune_fused"]

@@ -6,20 +6,20 @@ Static path (one prefill + fixed-length greedy decode, uniform batch):
   PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --tiny \
       --batch 8 --prompt-len 32 --gen 32 [--ckpt results/compressed_ckpt]
 
-Engine path (slot-based continuous batching over a mixed-length trace,
-batched same-bucket admissions, chunked prefill for prompts beyond the
-largest bucket, per-request sampling, optional INT8 KV cache — see
+Engine path (continuous batching over a mixed-length trace, batched
+same-bucket admissions, chunked prefill for prompts beyond the largest
+bucket, per-request sampling, optional INT8 KV cache — see
 docs/serving.md):
 
   PYTHONPATH=src python -m repro.launch.serve --tiny --engine continuous \
       --requests 32 --slots 8 --gen 32 [--buckets 8,16] [--kv-quant] \
-      [--kv paged --page-size 16 --pages 0] [--mixed-admission] [--verify]
+      [--page-size 16 --pages 0] [--mixed-admission] [--verify]
 
-``--kv paged`` swaps the slot cache for a fixed-size-page pool with a
-free-list allocator, refcounted shared-prefix page reuse, and
-preemption-aware scheduling (youngest spills to host under page
-pressure, resumes bit-identically). Greedy outputs stay bit-identical
-to the slot engine whenever page-size divides max-len.
+The engine's KV cache is a fixed-size-page pool with a free-list
+allocator, refcounted shared-prefix page reuse, and preemption-aware
+scheduling (youngest spills to host under page pressure, resumes
+bit-identically). Greedy outputs stay bit-identical to the static path
+whenever page-size divides max-len.
 
 With ``--packed`` the checkpoint is a packed QTensor checkpoint (written by
 ``repro.launch.compress --save-packed``): quantized layers stay packed
@@ -184,7 +184,7 @@ def build_trace(cfg, *, num_requests: int, max_prompt: int, max_new: int,
 def static_greedy_reference(model, params, req, max_len,
                             step_fns=None) -> list:
     """One request on the STATIC path: batch=1, exact prompt, greedy decode,
-    same cache length as the engine's slots — the per-request bit-parity
+    same cache length as the engine's max_len — the per-request bit-parity
     oracle shared by ``--verify``, engine_bench, and the engine tests."""
     prefill, decode = step_fns or make_step_fns(model)
     cache = model.init_cache(1, max_len, jnp.float32)
@@ -249,7 +249,6 @@ def _serve_engine(args, cfg, model, params):
                         prompt_buckets=buckets,
                         kv_quantized=args.kv_quant,
                         kv_dtype=jnp.float32,
-                        kv_layout=args.kv,
                         page_size=args.page_size,
                         num_pages=args.pages,
                         prefix_caching=not args.no_prefix_cache,
@@ -312,19 +311,18 @@ def _serve_engine(args, cfg, model, params):
     print(admit_note)
     print(f"[serve] kv cache resident "
           f"{engine.kv_cache_bytes() / 1e6:.2f}MB "
-          f"({'int8' if args.kv_quant else 'dense'}, {args.kv}), "
+          f"({'int8' if args.kv_quant else 'dense'}, paged), "
           f"compiled programs {after} (warmup {compiled})")
     ps = engine.page_stats()
-    if ps:
-        print(f"[serve] pages: {ps['pages_in_use']}/{ps['num_pages']} in use "
-              f"(peak {ps['peak_pages_in_use']}, size {ps['page_size']}), "
-              f"{ps['prefix_cached_pages']} prefix-cached")
-        hits, misses = ps["prefix_hits"], ps["prefix_misses"]
-        rate = hits / max(hits + misses, 1)
-        print(f"[serve] prefix cache: {hits} hits / {misses} misses "
-              f"({rate:.0%}), {ps['prefix_hit_tokens']} prompt tokens reused")
-        print(f"[serve] preemptions {ps['preemptions']}, resumes "
-              f"{ps['resumes']}, pages spilled {ps['pages_spilled']}")
+    print(f"[serve] pages: {ps['pages_in_use']}/{ps['num_pages']} in use "
+          f"(peak {ps['peak_pages_in_use']}, size {ps['page_size']}), "
+          f"{ps['prefix_cached_pages']} prefix-cached")
+    hits, misses = ps["prefix_hits"], ps["prefix_misses"]
+    rate = hits / max(hits + misses, 1)
+    print(f"[serve] prefix cache: {hits} hits / {misses} misses "
+          f"({rate:.0%}), {ps['prefix_hit_tokens']} prompt tokens reused")
+    print(f"[serve] preemptions {ps['preemptions']}, resumes "
+          f"{ps['resumes']}, pages spilled {ps['pages_spilled']}")
     if after != compiled:
         print("[serve] WARNING: recompilation after warmup")
     if args.verify:
@@ -352,7 +350,7 @@ def _serve_engine(args, cfg, model, params):
         engine.metrics_snapshot()      # refresh the state gauges
         engine.metrics.dump_json(args.metrics_json, meta={
             "source": "serve", "engine": "continuous", "arch": args.arch,
-            "layout": args.kv, "requests": len(reqs), "wall_s": wall})
+            "layout": "paged", "requests": len(reqs), "wall_s": wall})
         prom = args.metrics_json + ".prom"
         with open(prom, "w") as f:
             f.write(engine.metrics.to_prometheus())
@@ -383,34 +381,32 @@ def main():
     ap.add_argument("--engine", choices=("static", "continuous"),
                     default="static",
                     help="static: uniform batch, one prefill + N decodes; "
-                         "continuous: slot-based continuous batching")
+                         "continuous: continuous batching over a paged "
+                         "KV pool")
     ap.add_argument("--slots", type=int, default=8,
                     help="engine: device slots (concurrent requests)")
     ap.add_argument("--requests", type=int, default=32,
                     help="engine: trace length (mixed-length requests)")
     ap.add_argument("--max-len", type=int, default=0,
-                    help="engine: slot KV length (0 -> prompt+gen)")
+                    help="engine: longest request, prompt + output "
+                         "tokens (0 -> prompt+gen)")
     ap.add_argument("--buckets", default="",
                     help="engine: comma-separated prompt buckets (empty -> "
                          "pow2 buckets covering max-len); prompts beyond "
                          "the largest bucket stream via chunked prefill")
     ap.add_argument("--kv-quant", action="store_true",
                     help="engine: INT8 per-head-group KV cache")
-    ap.add_argument("--kv", choices=("slots", "paged"), default="slots",
-                    help="engine KV layout: slots (one contiguous max-len "
-                         "row per slot) or paged (fixed-size-page pool with "
-                         "shared-prefix reuse and preemption)")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="engine --kv paged: tokens per KV page (greedy "
-                         "bit-parity with slots needs page-size | max-len)")
+                    help="engine: tokens per KV page (greedy bit-parity "
+                         "with the static path needs page-size | max-len)")
     ap.add_argument("--pages", type=int, default=0,
-                    help="engine --kv paged: page-pool size (0 -> "
-                         "slots * ceil(max-len / page-size), i.e. the slot "
-                         "engine's footprint; smaller pools oversubscribe "
-                         "and trigger preemption)")
+                    help="engine: page-pool size (0 -> slots * "
+                         "ceil(max-len / page-size), max-len for every "
+                         "slot; smaller pools oversubscribe and trigger "
+                         "preemption)")
     ap.add_argument("--no-prefix-cache", action="store_true",
-                    help="engine --kv paged: disable shared-prefix page "
-                         "reuse across requests")
+                    help="engine: disable shared-prefix page reuse across "
+                         "requests")
     ap.add_argument("--mixed-admission", action="store_true",
                     help="engine: admit mixed-bucket FIFO head-runs in one "
                          "right-padded prefill dispatch")

@@ -20,8 +20,7 @@ import jax.numpy as jnp
 from repro.obs.spans import scope
 from repro.quant import QTensor
 from repro.serving.kv_cache import (QuantizedKV, fused_decode_attn,
-                                    kv_dequantize, kv_update, kv_quantize,
-                                    paged_view)
+                                    kv_dequantize, kv_quantize, paged_view)
 from repro.sharding import ShardingRules, NO_RULES, hint
 
 
@@ -128,10 +127,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     and 4k-train shapes at production width (DESIGN.md §4).
     ``q_offset`` is the absolute position of q[0] (decode/prefill continua).
 
-    ``kv_pages=(block_table, layer)`` switches K/V to the paged layout:
+    ``kv_pages=(table, layer)`` switches K/V to the paged layout:
     k, v are the stacked page POOLS of every layer — (L, P, page, Hk, D)
     dense or a :class:`~repro.serving.kv_cache.QuantizedKV` with those
-    leading dims — read at ``layer``, and ``block_table`` (B, n_pages)
+    leading dims — read at ``layer``, and ``table`` (B, n_pages)
     int32 maps each row's kv positions to physical pages. Each inner step
     gathers only its own kv_chunk worth of that layer's pages in-tile
     (quantized pools dequantize the gathered tile), so neither the layer's
@@ -356,47 +355,49 @@ def mlp_params(key, cfg, dtype=jnp.float32, d_ff: Optional[int] = None):
 def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
                positions=None, capture=None,
                kv_cache=None, cache_pos=None, attend_cache: bool = False,
-               block_table=None, layer=None, fused_decode: bool = False,
+               table=None, layer=None, fused_decode: bool = False,
                attn_chunk: int = 1024, attn_p_dtype=jnp.float32):
     """Pre-norm attention block (residual added by caller).
 
     Returns (out, new_kv): new_kv is (k, v) of this call when kv_cache is
-    None (training / prefill cache fill) or the updated (k_cache, v_cache)
-    for decode. ``cache_pos`` is the write position: a scalar (uniform
-    across the batch — the static serving path) or a per-row (B,) vector
-    (the continuous-batching engine, where each slot decodes at its own
-    position; requires s == 1). Cache entries may be dense arrays or
-    INT8 :class:`~repro.serving.kv_cache.QuantizedKV` storage — quantized
-    caches quantize on write and dequantize on the attention read.
+    None (training / prefill cache fill) or the updated (k_cache, v_cache).
 
-    ``attend_cache=True`` is the chunked-prefill contract: for s > 1 with a
-    scalar ``cache_pos``, this chunk's K/V is written at
-    [cache_pos, cache_pos + s) first (token columns past the cache edge —
-    a final chunk's padded tail — are dropped, never shifted), then the
-    queries attend the CACHE rows under the offset causal mask
-    (key index <= cache_pos + query offset) instead of only the fresh
-    chunk, so earlier chunks of the same prompt are visible. Quantized
-    caches attend the dequantized rows, including this chunk's own
-    (quantize-rounded) keys.
+    A contiguous cache (no ``table``) is a dense (B, Smax, Hk, D)
+    pair written at a scalar ``cache_pos`` (uniform across the batch: the
+    static serving path and the engine's prefill mini-cache); s > 1 runs
+    flash attention over the new tokens (``cache_pos`` 0), s == 1 reads
+    the cache through :func:`decode_attention`.
 
-    ``block_table`` (B, n_pages) int32 switches the cache to the PAGED
-    layout: cache entries are the stacked page pools of every layer
-    (L, P, page, Hk, D), this block's being ``layer`` (int32 scalar), and
-    every position routes through the table (writes via
+    ``table`` (B, n_pages) int32 selects the engine's PAGED pools:
+    cache entries are the stacked page pools of every layer
+    (L, P, page, Hk, D), dense or INT8
+    :class:`~repro.serving.kv_cache.QuantizedKV` (quantized on write,
+    dequantized on the read), this block's being ``layer`` (int32
+    scalar), and every position routes through the table (writes via
     :func:`paged_write`, decode reads via the fused kernel or a page
     gather, chunked-prefill reads via the in-tile paged flash path), each
     at ``layer`` of the stack: no per-layer pool is sliced out or written
-    back, and the returned pools are the updated stacks.
-    Gathered views hold the same written values at the same positions as a
-    slot-cache row (everything else is causally masked), so paged greedy
-    output is bit-identical to the slot path, dense and INT8 alike.
+    back, and the returned pools are the updated stacks. ``cache_pos`` is
+    a per-row (B,) vector for decode (each slot at its own position;
+    s == 1) or a scalar for a chunk. Gathered views hold the same written
+    values at the same positions as a contiguous cache (everything else
+    is causally masked), so greedy output matches the static path.
 
-    ``fused_decode=True`` routes the s == 1 decode read (slot and paged)
-    through the fused Pallas flash-decode kernel
+    ``attend_cache=True`` is the chunked-prefill contract (paged, s > 1,
+    scalar ``cache_pos``): this chunk's K/V is written at
+    [cache_pos, cache_pos + s) first (positions past the row's pages — a
+    final chunk's padded tail — are dropped), then the queries attend the
+    pool under the offset causal mask (key index <= cache_pos + query
+    offset), so earlier chunks of the same prompt are visible. Quantized
+    pools attend the dequantized pages, including this chunk's own
+    (quantize-rounded) keys.
+
+    ``fused_decode=True`` routes the paged s == 1 decode read through the
+    fused Pallas flash-decode kernel
     (:func:`~repro.serving.kv_cache.fused_decode_attn`): INT8 codes
-    dequantize in-tile, per-row lengths bound the K loop, and the paged
-    gather happens in the kernel's index maps — no materialized dense KV.
-    False (the default) keeps the dequant-then-attend reference path.
+    dequantize in-tile, per-row lengths bound the K loop, and the page
+    gather happens in the kernel — no materialized dense KV. False (the
+    default) keeps the gather-then-attend reference path.
     """
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
@@ -420,20 +421,20 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
             out = flash_attention(q, k, v, causal=True, q_chunk=attn_chunk,
                                   kv_chunk=attn_chunk, p_dtype=attn_p_dtype)
         new_kv = (k, v)
-    elif block_table is not None:
+    elif table is not None:
         assert layer is not None, "paged caches are read at a layer index"
         k_cache, v_cache = kv_cache            # stacks (L, P, page, Hk, D)
         with scope("kv_write"):
-            k_cache = paged_write(k_cache, layer, block_table, cache_pos, k)
-            v_cache = paged_write(v_cache, layer, block_table, cache_pos, v)
+            k_cache = paged_write(k_cache, layer, table, cache_pos, k)
+            v_cache = paged_write(v_cache, layer, table, cache_pos, v)
         with scope("attn"):
             if s == 1:
                 if fused_decode:
                     out = fused_decode_attn(q, k_cache, v_cache, positions,
-                                            table=block_table, layer=layer)
+                                            table, layer)
                 else:
-                    k_r = paged_view(k_cache, block_table, layer)
-                    v_r = paged_view(v_cache, block_table, layer)
+                    k_r = paged_view(k_cache, table, layer)
+                    v_r = paged_view(v_cache, table, layer)
                     if isinstance(k_r, QuantizedKV):
                         k_r = kv_dequantize(k_r, q.dtype)
                         v_r = kv_dequantize(v_r, q.dtype)
@@ -447,50 +448,19 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
                                       q_offset=cache_pos, q_chunk=attn_chunk,
                                       kv_chunk=attn_chunk,
                                       p_dtype=attn_p_dtype,
-                                      kv_pages=(block_table, layer))
+                                      kv_pages=(table, layer))
         new_kv = (k_cache, v_cache)
     else:
         k_cache, v_cache = kv_cache                  # (B, Smax, Hk, D)
+        assert not attend_cache, \
+            "chunked prefill attends the paged pools (table)"
         with scope("kv_write"):
-            if isinstance(k_cache, QuantizedKV):
-                k_cache = kv_update(k_cache, k, cache_pos)
-                v_cache = kv_update(v_cache, v, cache_pos)
-            elif getattr(cache_pos, "ndim", 0) == 1:  # per-slot positions
-                assert s == 1, "per-slot cache writes are one token per step"
-                rows = jnp.arange(b)
-                k_cache = k_cache.at[rows, cache_pos].set(
-                    k[:, 0].astype(k_cache.dtype))
-                v_cache = v_cache.at[rows, cache_pos].set(
-                    v[:, 0].astype(v_cache.dtype))
-            elif attend_cache:
-                # chunked prefill: per-column scatter so a final chunk's
-                # padded tail past the cache edge is dropped, never shifted
-                # back onto live rows like dynamic_update_slice would
-                cols = cache_pos + jnp.arange(s)
-                k_cache = k_cache.at[:, cols].set(k.astype(k_cache.dtype))
-                v_cache = v_cache.at[:, cols].set(v.astype(v_cache.dtype))
-            else:
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    k_cache, k.astype(k_cache.dtype), cache_pos, axis=1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    v_cache, v.astype(v_cache.dtype), cache_pos, axis=1)
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), cache_pos, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), cache_pos, axis=1)
         with scope("attn"):
-            if s > 1 and attend_cache:
-                # chunked prefill: attend the cache rows (which now include
-                # this chunk's K/V) under the offset causal mask — flash
-                # with q_offset keeps per-query numerics bit-compatible with
-                # the fresh-prefill path, so chunked greedy output matches
-                # the static path exactly on dense f32 caches
-                if isinstance(k_cache, QuantizedKV):
-                    k_r = kv_dequantize(k_cache, q.dtype)
-                    v_r = kv_dequantize(v_cache, q.dtype)
-                else:
-                    k_r, v_r = k_cache, v_cache
-                out = flash_attention(q, k_r, v_r, causal=True,
-                                      q_offset=cache_pos, q_chunk=attn_chunk,
-                                      kv_chunk=attn_chunk,
-                                      p_dtype=attn_p_dtype)
-            elif s > 1:
+            if s > 1:
                 # prefill: flash attention over the new tokens (assumes
                 # cache_pos == 0 — the serving manager's convention);
                 # decode_attention here would materialize (B,H,S,Smax)
@@ -498,15 +468,8 @@ def attn_apply(p, x, cfg, rules: ShardingRules = NO_RULES, *,
                 out = flash_attention(q, k, v, causal=True,
                                       q_chunk=attn_chunk, kv_chunk=attn_chunk,
                                       p_dtype=attn_p_dtype)
-            elif fused_decode:
-                out = fused_decode_attn(q, k_cache, v_cache, positions)
             else:
-                if isinstance(k_cache, QuantizedKV):
-                    k_r = kv_dequantize(k_cache, q.dtype)
-                    v_r = kv_dequantize(v_cache, q.dtype)
-                else:
-                    k_r, v_r = k_cache, v_cache
-                out = decode_attention(q, k_r, v_r, positions, rules,
+                out = decode_attention(q, k_cache, v_cache, positions, rules,
                                        p_dtype=attn_p_dtype)
         new_kv = (k_cache, v_cache)
 

@@ -297,13 +297,13 @@ def moe_block_params(key, cfg: ModelConfig, dtype=jnp.float32):
 
 def moe_block_apply(p, x, cfg, rules=NO_RULES, *, positions=None, capture=None,
                     kv_cache=None, cache_pos=None, attend_cache=False,
-                    block_table=None, layer=None, fused_decode=False,
+                    table=None, layer=None, fused_decode=False,
                     prefer_a2a=True,
                     attn_chunk: int = 1024, attn_p_dtype=jnp.float32):
     a, new_kv = L.attn_apply(p["attn"], x, cfg, rules, positions=positions,
                              capture=capture, kv_cache=kv_cache,
                              cache_pos=cache_pos, attend_cache=attend_cache,
-                             block_table=block_table, layer=layer,
+                             table=table, layer=layer,
                              fused_decode=fused_decode,
                              attn_chunk=attn_chunk,
                              attn_p_dtype=attn_p_dtype)

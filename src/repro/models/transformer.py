@@ -33,12 +33,12 @@ def block_params(key, cfg: ModelConfig, dtype=jnp.float32):
 
 def block_apply(p, x, cfg, rules=NO_RULES, *, positions=None, capture=None,
                 kv_cache=None, cache_pos=None, attend_cache: bool = False,
-                block_table=None, layer=None, fused_decode: bool = False,
+                table=None, layer=None, fused_decode: bool = False,
                 attn_chunk: int = 1024, attn_p_dtype=jnp.float32):
     a, new_kv = L.attn_apply(p["attn"], x, cfg, rules, positions=positions,
                              capture=capture, kv_cache=kv_cache,
                              cache_pos=cache_pos, attend_cache=attend_cache,
-                             block_table=block_table, layer=layer,
+                             table=table, layer=layer,
                              fused_decode=fused_decode,
                              attn_chunk=attn_chunk,
                              attn_p_dtype=attn_p_dtype)
@@ -66,11 +66,11 @@ class DenseModel:
     # single-iteration: used by the dry-run COST lowering, where XLA's
     # cost_analysis counts loop bodies once (see analysis/roofline.py).
     unroll: bool = False
-    # route s == 1 decode cache reads through the fused Pallas flash-decode
-    # kernel (in-tile INT8 dequant, length-bounded K loop) instead of the
-    # dequant-then-attend reference. Off by default so the static paths
-    # keep their exact numerics; the serving engine flips it per
-    # EngineConfig.use_fused_decode.
+    # route the paged pools' s == 1 decode reads through the fused Pallas
+    # flash-decode kernel (in-tile INT8 dequant, length-bounded K loop)
+    # instead of the gather-then-attend reference. A contiguous cache (the
+    # static path) is always read by the reference; the serving engine
+    # sets it per EngineConfig.use_fused_decode.
     use_fused_decode: bool = False
 
     # -- params ------------------------------------------------------------
@@ -217,12 +217,13 @@ class DenseModel:
                      attend_cache: bool = False):
         """The blocks over ``h`` against the cache, one layer at a time.
 
-        Slot caches scan each layer's (S, T, Hk, D) rows as ``xs`` and
-        return them as ``ys``. Paged caches (a ``table`` (B, n_pages) in
-        the cache) carry the stacked pools (L, P, page, Hk, D) through the
-        loop as one buffer each and hand every block its layer index: the
-        block writes and reads the pool at that index, so no layer's pool
-        is sliced out or written back and the step programs' donated pools
+        Contiguous caches (the static path's, the engine's prefill
+        mini-cache) scan each layer's (B, T, Hk, D) rows as ``xs`` and
+        return them as ``ys``. The engine's paged pools (a ``table``
+        (B, n_pages) in the cache) are carried through the loop as one
+        buffer each, and every block gets its layer index: the block
+        writes and reads the pool at that index, so no layer's pool is
+        sliced out or written back and the step programs' donated pools
         come back in place."""
         block = functools.partial(
             self._cached_block(positions), positions=positions,
@@ -241,7 +242,7 @@ class DenseModel:
                 x, kc, vc = carry
                 layer_p, layer = scanned
                 x, kv = block(layer_p, x, kv_cache=(kc, vc),
-                              block_table=table, layer=layer)
+                              table=table, layer=layer)
                 return (x, *kv), None
             carry = (h, cache["k"], cache["v"])
         with scope("blocks"):
@@ -270,19 +271,12 @@ class DenseModel:
             new_cache["table"] = table
         return h, new_cache
 
-    @staticmethod
-    def _base_positions(pos: jax.Array) -> jax.Array:
-        """Cache position as a broadcastable base: scalar (static path) or
-        per-slot vector (the engine's slot cache) → (B|1, 1)."""
-        return pos[:, None] if getattr(pos, "ndim", 0) == 1 else pos
-
     def _prompt_positions(self, h, pos) -> jax.Array:
-        """Absolute positions (B, s) of prompt tokens written from cache
-        position ``pos``."""
+        """Absolute positions (B, s) of prompt tokens written from the
+        scalar cache position ``pos``."""
         b, s = h.shape[0], h.shape[1]
         with scope("embed"):
-            return (jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-                    + self._base_positions(pos))
+            return jnp.broadcast_to(jnp.arange(s)[None, :], (b, s)) + pos
 
     def prefill(self, params, batch, cache):
         """Teacher-forced pass that fills the cache; returns last logits."""
@@ -330,7 +324,8 @@ class DenseModel:
 
     def decode_step(self, params, tokens, cache):
         """One decode step. tokens: (B, 1) int32. ``cache["pos"]`` is a
-        scalar (uniform batch) or a per-slot (B,) vector (engine path)."""
+        scalar (uniform batch, the static path) or a per-slot (B,) vector
+        (the engine's paged pools)."""
         with scope("embed"):
             h = jnp.take(params["embed"], tokens, axis=0)
             b = h.shape[0]

@@ -35,7 +35,7 @@ SCOPES = (
     "mlp",          # the MLP block, norm included
     "head",         # final norm, vocabulary head, pad mask
     "sample",       # sampling and the finite-logit flag
-    "kv_splice",    # prefill's mini-cache splice (write_slot / write_pages)
+    "kv_splice",    # prefill's mini-cache splice (write_pages)
 )
 
 # Engine.step phases

@@ -1,50 +1,49 @@
 """Continuous-batching serving engine: variable-length requests in fixed
-device slots, no recompilation after warmup.
+device slots over a paged KV pool, no recompilation after warmup.
 
-The engine owns a slot-indexed KV cache (``serving/kv_cache.py``; S slots ×
-max_len tokens, dense or INT8 per-head-group quantized) and three jitted
-step functions:
+The engine owns a fixed pool of ``page_size``-token KV pages per layer
+(``serving/kv_cache.py``; dense or INT8 per-head-group quantized), routed
+to each slot through a host-side block table (``serving/paging.py`` owns
+the refcounted allocator, ``serving/prefix_cache.py`` the shared-prefix
+index), and three jitted step functions:
 
 - **prefill** (one compile per (prompt bucket, batch bucket) pair): runs
-  the model over a whole same-bucket admission batch of right-padded
-  prompts against a fresh (L, B, W) mini-cache, gathers logits at each
-  row's true last token, samples the first output tokens on device, and
-  splices the B mini-caches into the admitted slots' rows in ONE dispatch
-  (``write_slot``; batch sizes round up to pow2 batch buckets, padding
-  rows carry slot == num_slots so their writes are dropped);
-- **chunk** (one compile, ever): one bucket-width chunk of a prompt LONGER
-  than the largest bucket, run against the slot's own cache rows — the
-  chunk's K/V is written at [start, start+W) and attention reads the cache
-  under the offset causal mask (``model.prefill_chunk``), so max_len-scale
-  prompts serve without a max_len-wide compile;
+  the model over a whole admission batch of right-padded prompts against
+  a fresh dense (L, B, W) mini-cache, gathers logits at each row's true
+  last token, samples the first output tokens on device, and scatters the
+  B mini-caches into the admitted slots' pages through per-row page maps
+  in ONE dispatch (``write_pages``; batch sizes round up to pow2 batch
+  buckets, padding rows carry all-sentinel maps so their writes are
+  dropped);
+- **chunk** (one compile, ever): one bucket-width chunk of a prompt's
+  unmatched suffix — a prefix-cache hit, or a prompt LONGER than the
+  largest bucket — written into the slot's pages at [start, start+W)
+  while the queries attend the pool through the block table under the
+  offset causal mask (``model.prefill_chunk``), so max_len-scale prompts
+  serve without a max_len-wide compile;
 - **decode** (one compile, ever): one token for ALL slots at once — each
-  slot reads/writes the cache at its own position (``pos`` is a vector),
-  per-slot sampling params ride along as arrays, and exactly one int32 per
-  slot crosses the device boundary per step.
+  slot reads and writes the pool at its own position (``pos`` is a
+  vector) through its table row, per-slot sampling params ride along as
+  arrays, and exactly one (token, finite-flag) pair per slot crosses the
+  device boundary per step.
 
 The host-side :class:`~repro.serving.scheduler.Scheduler` feeds it: FIFO
-admission onto the slot free-list (``admit_batch`` groups the FIFO head-run
-by prompt bucket so a burst of B same-bucket arrivals costs one device call
-instead of B), prompt-length bucketing (the only shape degree of freedom
-besides the batch bucket), retire-on-completion. Retired slots keep
-decoding garbage at position 0 until reused — their writes land below the
-next request's prefill splice and are never attended.
+admission onto the slot free-list (a head-run of same-bucket arrivals
+costs one prefill dispatch instead of B), prompt-length bucketing (the
+only shape degree of freedom besides the batch bucket), retire-on-
+completion. Parked slots carry all-sentinel table rows, so their decode
+writes are dropped. On top of the indirection ride copy-free shared-prefix
+admission (a prefix cache maps page-aligned token prefixes to live pages;
+only the suffix prefills) and preempt-and-resume (under page pressure the
+youngest request's pages spill to host memory and its ResumeTicket
+re-enters the queue by seq).
 
-``kv_layout="paged"`` (the paged subsystem: ``serving/paging.py`` +
-``serving/prefix_cache.py``, docs/serving.md §paging) swaps the slot rows
-for a fixed pool of fixed-size pages routed through per-slot block tables:
-prefill splices through per-row page maps, the chunk program reads the
-pool via in-tile paged flash, decode gathers each slot's pages. On top of
-the indirection ride copy-free shared-prefix admission (a prefix cache
-maps page-aligned token prefixes to live pages; only the suffix prefills)
-and preempt-and-resume (under page pressure the youngest request's pages
-spill to host memory and its ResumeTicket re-enters the queue by seq).
-Greedy outputs stay token-identical to the slot layout.
-
-`launch/serve.py --engine continuous` drives it (``--kv paged|slots``);
-`benchmarks/engine_bench.py` load-tests it (Zipf, burst, long-prompt,
-shared-prefix, and overload/preemption traces) into
-``results/BENCH_engine.json``.
+`launch/serve.py --engine continuous` drives it;
+`benchmarks/engine_bench.py` load-tests it on the CPU (Zipf, burst,
+long-prompt, shared-prefix, and overload/preemption traces). The
+``results/BENCH_*.json`` files CI writes from those scripts are CPU and
+interpreter artifacts, not speed claims; the chip benchmark is
+``bench/run.py``.
 """
 from __future__ import annotations
 
@@ -59,14 +58,12 @@ import numpy as np
 
 from repro.obs import MetricsRegistry, Trace
 from repro.obs.spans import scope, span, step_span
-from repro.serving.kv_cache import (KVCacheConfig, cache_bytes,
-                                    init_paged_storage, init_slot_cache,
-                                    set_slot_rows, slot_rows, write_pages,
-                                    write_slot)
+from repro.serving.kv_cache import (cache_bytes, init_paged_storage,
+                                    write_pages)
 from repro.serving.paging import (PageAllocator, restore_pages, spill_pages)
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import sample_tokens
-from repro.serving.scheduler import (AdmittedBatch, DuplicateRequestError,
+from repro.serving.scheduler import (DuplicateRequestError,
                                      EngineInvariantError, EngineStalledError,
                                      GenerationRequest, GenerationResult,
                                      InvalidRequestError, QueueFullError,
@@ -94,22 +91,23 @@ def step_jit(fn, **jit_kwargs):
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    """Engine shape/storage policy. ``kv_quantized`` switches the slot
-    cache to INT8 per-head-group storage (``kv_group_size=0`` → one group
-    per head); ``prompt_buckets=()`` → power-of-two buckets covering
-    max_len. A custom ``prompt_buckets`` whose largest bucket is smaller
-    than max_len turns prompts beyond it into chunked prefills.
+    """Engine shape/storage policy. The KV cache is a fixed pool of
+    ``page_size``-token pages routed through per-slot block tables
+    (``num_pages=0`` → num_slots · ceil(max_len/page_size): every slot can
+    hold max_len tokens at once). ``kv_quantized`` switches the pool to
+    INT8 per-head-group storage (``kv_group_size=0`` → one group per
+    head); ``prompt_buckets=()`` → power-of-two buckets covering max_len.
+    A custom ``prompt_buckets`` whose largest bucket is smaller than
+    max_len turns prompts beyond it into chunked prefills.
 
-    ``kv_layout="paged"`` switches the cache from per-slot contiguous rows
-    to a fixed pool of ``page_size``-token pages routed through per-slot
-    block tables (``num_pages=0`` → num_slots · ceil(max_len/page_size),
-    the same token capacity as the slot layout). Paged mode enables
-    copy-free shared-prefix admission (``prefix_caching``) and
-    preempt-and-resume under page pressure. Greedy output is bit-identical
-    to the slot layout when ``page_size`` divides ``max_len`` (otherwise
-    the gathered cache view is wider than max_len and reduction shapes
-    differ by masked-out zeros). ``mixed_admission`` lets one prefill
-    dispatch admit a FIFO head-run that crosses prompt buckets
+    ``prefix_caching`` enables copy-free shared-prefix admission;
+    preempt-and-resume under page pressure is always on. Greedy output is
+    bit-identical to the static path when ``page_size`` divides
+    ``max_len`` (otherwise the gathered cache view is wider than max_len
+    and reduction shapes differ by masked-out zeros). ``kv_layout`` has
+    one legal value, ``"paged"`` (the slot layout was removed); the field
+    stays for configuration files that name it. ``mixed_admission`` lets
+    one prefill dispatch admit a FIFO head-run that crosses prompt buckets
     (right-padded to the largest member's bucket) — fewer dispatches,
     identical outputs.
 
@@ -123,8 +121,8 @@ class EngineConfig:
 
     ``use_fused_decode`` (default on) routes the decode step's cache read
     through the fused Pallas flash-decode kernel — INT8 codes dequantize
-    in-tile, per-slot positions bound the K loop, paged tables gather in
-    the kernel — instead of dequantizing/gathering the whole cache then
+    in-tile, per-slot positions bound the K loop, the block tables gather
+    in the kernel — instead of gathering/dequantizing the whole cache then
     attending. ``False`` is the escape hatch back to the reference
     dequant-then-attend path (bit-exact pre-fusion numerics)."""
     num_slots: int = 8
@@ -134,15 +132,22 @@ class EngineConfig:
     kv_quantized: bool = False
     kv_group_size: int = 0
     max_top_k: int = 64
-    kv_layout: str = "slots"           # "slots" | "paged"
+    kv_layout: str = "paged"           # the only layout
     page_size: int = 16
-    num_pages: int = 0                 # 0 → auto (slot-equivalent capacity)
-    prefix_caching: bool = True        # paged only
+    num_pages: int = 0                 # 0 → max_len for every slot
+    prefix_caching: bool = True
     mixed_admission: bool = False      # cross-bucket admission runs
     max_queue: int = 0                 # 0 → unbounded backlog
     stall_patience: int = 8            # no-progress steps before stalling
     use_fused_decode: bool = True      # fused flash-decode cache reads
     queue_trace_samples: int = 4096    # queue-depth ring-buffer capacity
+
+    def __post_init__(self):
+        if self.kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout {self.kv_layout!r}: the engine serves from the "
+                f"paged pool only (the slot layout was removed); use "
+                f"'paged' or leave the field at its default")
 
 
 def batch_buckets(num_slots: int) -> tuple:
@@ -161,7 +166,8 @@ def _counter_view(key: str):
 
 
 class Engine:
-    """Slot-based continuous batching over a fixed-shape decode program."""
+    """Continuous batching over a paged KV pool and fixed-shape step
+    programs."""
 
     def __init__(self, model, params, cfg: EngineConfig = EngineConfig(),
                  faults=None, registry: Optional[MetricsRegistry] = None):
@@ -175,44 +181,28 @@ class Engine:
         self.scheduler = Scheduler(cfg.num_slots, cfg.max_len,
                                    cfg.prompt_buckets)
         self.batch_buckets = batch_buckets(cfg.num_slots)
-        if cfg.kv_layout not in ("slots", "paged"):
-            raise ValueError(f"kv_layout must be 'slots' or 'paged', got "
-                             f"{cfg.kv_layout!r}")
-        self._paged = cfg.kv_layout == "paged"
-        s = cfg.num_slots
-        if self._paged:
-            pg = cfg.page_size
-            if pg < 1:
-                raise ValueError(f"page_size must be >= 1, got {pg}")
-            self.pages_per_slot = -(-cfg.max_len // pg)
-            num_pages = cfg.num_pages or s * self.pages_per_slot
-            if num_pages < self.pages_per_slot:
-                # the oldest request is unpreemptable; it must always be
-                # able to grow to max_len or admission can deadlock
-                raise ValueError(
-                    f"num_pages {num_pages} < pages_per_slot "
-                    f"{self.pages_per_slot}: one max_len request must fit")
-            self.kv = init_paged_storage(
-                mcfg, num_pages, pg, dtype=cfg.kv_dtype,
-                quantized=cfg.kv_quantized, group_size=cfg.kv_group_size)
-            self.alloc = PageAllocator(num_pages, faults=faults)
-            self.prefix = (PrefixCache(pg, self.alloc)
-                           if cfg.prefix_caching else None)
-            # block tables are host state; rows ride to the device as plain
-            # int32 data each dispatch (sentinel == num_pages everywhere a
-            # slot has no page: parked slots' decode writes are dropped)
-            self._table = np.full((s, self.pages_per_slot), num_pages,
-                                  np.int32)
-            self._slot_pages: List[List[int]] = [[] for _ in range(s)]
-        else:
-            kv_cfg = KVCacheConfig(num_slots=s, max_len=cfg.max_len,
-                                   dtype=cfg.kv_dtype,
-                                   quantized=cfg.kv_quantized,
-                                   group_size=cfg.kv_group_size)
-            cache = init_slot_cache(mcfg, kv_cfg)
-            self.kv = {"k": cache["k"], "v": cache["v"]}  # pos is host-side
-            self.alloc = None
-            self.prefix = None
+        s, pg = cfg.num_slots, cfg.page_size
+        if pg < 1:
+            raise ValueError(f"page_size must be >= 1, got {pg}")
+        self.pages_per_slot = -(-cfg.max_len // pg)
+        num_pages = cfg.num_pages or s * self.pages_per_slot
+        if num_pages < self.pages_per_slot:
+            # the oldest request is unpreemptable; it must always be able
+            # to grow to max_len or admission can deadlock
+            raise ValueError(
+                f"num_pages {num_pages} < pages_per_slot "
+                f"{self.pages_per_slot}: one max_len request must fit")
+        self.kv = init_paged_storage(
+            mcfg, num_pages, pg, dtype=cfg.kv_dtype,
+            quantized=cfg.kv_quantized, group_size=cfg.kv_group_size)
+        self.alloc = PageAllocator(num_pages, faults=faults)
+        self.prefix = (PrefixCache(pg, self.alloc)
+                       if cfg.prefix_caching else None)
+        # block tables are host state; rows ride to the device as plain
+        # int32 data each dispatch (sentinel == num_pages everywhere a slot
+        # has no page: parked slots' decode writes are dropped)
+        self._table = np.full((s, self.pages_per_slot), num_pages, np.int32)
+        self._slot_pages: List[List[int]] = [[] for _ in range(s)]
         self._pos = np.zeros(s, np.int32)
         self._tok = np.zeros(s, np.int32)
         self._temps = np.zeros(s, np.float32)
@@ -313,10 +303,9 @@ class Engine:
     def _reset_counters(self) -> None:
         for child in self._metric_children:
             child.reset()
-        if self.alloc is not None:
-            self.alloc.peak_in_use = self.alloc.pages_in_use
-            self.alloc.alloc_calls = 0
-            self.alloc.alloc_failures = 0
+        self.alloc.peak_in_use = self.alloc.pages_in_use
+        self.alloc.alloc_calls = 0
+        self.alloc.alloc_failures = 0
 
     # legacy counter attributes — read-only views over the registry
     decode_steps = _counter_view("decode_steps")
@@ -343,8 +332,7 @@ class Engine:
         so scripted fault steps count from the first real step (warmup
         disables injection regardless)."""
         self.faults = plan
-        if self.alloc is not None:
-            self.alloc.faults = plan
+        self.alloc.faults = plan
 
     def _now(self) -> float:
         """Engine clock: the fault plan's virtual clock when one with
@@ -355,6 +343,11 @@ class Engine:
 
     # -- jitted steps ------------------------------------------------------
     def _make_step_fns(self):
+        """The three step programs. Prefill fills a dense mini-cache and
+        scatters it into the pools through per-row page maps
+        (``write_pages``); chunk and decode route every cache access
+        through a block table (in-tile paged flash / fused or page-gathered
+        decode read), at each layer's index of the stacked pools."""
         model, cfg = self.model, self.cfg
         if getattr(model, "use_fused_decode", None) != cfg.use_fused_decode:
             # the flag lives on the model dataclass (it's baked into the
@@ -364,71 +357,6 @@ class Engine:
                 model, use_fused_decode=cfg.use_fused_decode)
         mcfg = model.cfg
         mini_dtype = jnp.float32 if cfg.kv_quantized else cfg.kv_dtype
-        if self._paged:
-            return self._make_paged_step_fns(mini_dtype, model)
-
-        def prefill_fn(params, kv, tokens, lengths, slots, temps, topks,
-                       seeds):
-            b, w = tokens.shape
-            with scope("kv_splice"):
-                zeros = jnp.zeros((mcfg.num_layers, b, w, mcfg.num_kv_heads,
-                                   mcfg.resolved_head_dim), mini_dtype)
-                mini = {"k": zeros, "v": zeros,
-                        "pos": jnp.zeros((), jnp.int32)}
-            logits, mini = model.prefill_at(params, {"tokens": tokens},
-                                            mini, lengths=lengths)
-            with scope("sample"):
-                toks = sample_tokens(logits[:, 0, :], temps, topks, seeds,
-                                     jnp.zeros((b,), jnp.uint32),
-                                     max_top_k=cfg.max_top_k)
-            with scope("kv_splice"):
-                kv = write_slot(kv, slots, mini["k"], mini["v"])
-            return toks, kv
-
-        def chunk_fn(params, kv, tokens, start, length, slot, temp, topk,
-                     seed):
-            with scope("kv_splice"):
-                row = {"k": slot_rows(kv["k"], slot),
-                       "v": slot_rows(kv["v"], slot), "pos": start}
-            logits, row = model.prefill_chunk(params, {"tokens": tokens},
-                                              row, lengths=length[None])
-            with scope("sample"):
-                tok = sample_tokens(logits[:, 0, :], temp[None], topk[None],
-                                    seed[None], jnp.zeros((1,), jnp.uint32),
-                                    max_top_k=cfg.max_top_k)
-            with scope("kv_splice"):
-                kv = {"k": set_slot_rows(kv["k"], slot, row["k"]),
-                      "v": set_slot_rows(kv["v"], slot, row["v"])}
-            return tok[0], kv
-
-        def decode_fn(params, kv, pos, tokens, temps, topks, seeds, steps):
-            cache = {"k": kv["k"], "v": kv["v"], "pos": pos}
-            logits, cache = model.decode_step(params, tokens, cache)
-            with scope("sample"):
-                tok = sample_tokens(logits[:, 0, :], temps, topks, seeds,
-                                    steps, max_top_k=cfg.max_top_k)
-                # finite-logit flag rides along in the same int32 transfer:
-                # a slot whose logits went non-finite fails ALONE on the host
-                ok = jnp.all(jnp.isfinite(logits[:, 0, :]), axis=-1)
-                out = jnp.stack([tok.astype(jnp.int32),
-                                 ok.astype(jnp.int32)], axis=-1)
-            return out, {"k": cache["k"], "v": cache["v"]}
-
-        return (step_jit(prefill_fn, donate_argnums=1),
-                step_jit(chunk_fn, donate_argnums=1),
-                step_jit(decode_fn, donate_argnums=1))
-
-    def _make_paged_step_fns(self, mini_dtype, model):
-        """Paged mirrors of the three step programs. Prefill keeps the
-        slot path's math exactly (same dense mini-cache, same per-row
-        logit gather) and only the final splice differs — write_pages
-        scatters through per-row page maps instead of slot indices — so
-        paged greedy output matches the slot engine token for token.
-        Chunk and decode route every cache access through a block table
-        (in-tile paged flash / fused or page-gathered decode read).
-        ``model`` is the caller's fused-decode rebind."""
-        cfg = self.cfg
-        mcfg = model.cfg
         pg = cfg.page_size
 
         def prefill_fn(params, kv, tokens, lengths, page_maps, temps, topks,
@@ -469,6 +397,8 @@ class Engine:
             with scope("sample"):
                 tok = sample_tokens(logits[:, 0, :], temps, topks, seeds,
                                     steps, max_top_k=cfg.max_top_k)
+                # finite-logit flag rides along in the same int32 transfer:
+                # a slot whose logits went non-finite fails ALONE on the host
                 ok = jnp.all(jnp.isfinite(logits[:, 0, :]), axis=-1)
                 out = jnp.stack([tok.astype(jnp.int32),
                                  ok.astype(jnp.int32)], axis=-1)
@@ -490,13 +420,11 @@ class Engine:
         if req.rid in self._results:
             raise DuplicateRequestError(
                 f"request rid={req.rid} is already in flight")
-        if self._paged:
-            need = -(-req.prompt_len // self.cfg.page_size)
-            if need > self.alloc.num_pages:
-                raise InvalidRequestError(
-                    f"request rid={req.rid}: prompt needs {need} pages but "
-                    f"the pool has {self.alloc.num_pages} — can never be "
-                    f"admitted")
+        need = -(-req.prompt_len // self.cfg.page_size)
+        if need > self.alloc.num_pages:
+            raise InvalidRequestError(
+                f"request rid={req.rid}: prompt needs {need} pages but the "
+                f"pool has {self.alloc.num_pages} — can never be admitted")
         if (self.cfg.max_queue > 0 and req.rid >= 0
                 and len(self.scheduler.queue) >= self.cfg.max_queue):
             # negative rids are warmup clones — internal, never shed
@@ -585,10 +513,11 @@ class Engine:
 
         - for each distinct prompt bucket in ``reqs``, the full
           (bucket × batch-bucket) prefill grid, traced with all-padding
-          dummy batches (slot index == num_slots, so every cache write is
+          dummy batches (all-sentinel page maps, so every cache write is
           dropped);
         - the chunked-prefill program (one dummy chunk) if any request's
-          prompt exceeds the largest bucket;
+          prompt exceeds the largest bucket, or a prefix hit could stream
+          a suffix through it;
         - the decode program, via one short clone per distinct bucket
           (prompt clipped to max_len - 1 so the clone's >= 1-token budget
           always fits) plus a minimal 2-token fallback request if none of
@@ -618,29 +547,22 @@ class Engine:
                 seen.setdefault(self.scheduler.bucket_for(r.prompt_len), r)
 
         # (bucket × batch-bucket) prefill grid: all-padding dummy batches
-        # (slot path: OOB slot index; paged path: all-sentinel page maps —
-        # either way every cache write is dropped)
-        drop = self.cfg.num_slots
+        # whose all-sentinel page maps drop every cache write
         for w in sorted(seen):
             for bb in self.batch_buckets:
-                if self._paged:
-                    route = jnp.full((bb, -(-w // self.cfg.page_size)),
-                                     self.alloc.num_pages, jnp.int32)
-                else:
-                    route = jnp.full((bb,), drop, jnp.int32)
                 tok_dev, self.kv = self._prefill(
                     self.params, self.kv,
                     jnp.zeros((bb, w), jnp.int32),
                     jnp.ones((bb,), jnp.int32),
-                    route,
+                    jnp.full((bb, -(-w // self.cfg.page_size)),
+                             self.alloc.num_pages, jnp.int32),
                     jnp.zeros((bb,), jnp.float32),
                     jnp.zeros((bb,), jnp.int32),
                     jnp.zeros((bb,), jnp.uint32))
-        # a paged engine with prefix caching uses the chunk program for
-        # every prefix hit, not just beyond-largest-bucket prompts — warm
-        # it whenever a trace could hit it
-        if chunked or (self._paged and self.prefix is not None
-                       and (seen or chunked)):
+        # with prefix caching the chunk program serves every prefix hit,
+        # not just beyond-largest-bucket prompts — warm it whenever a trace
+        # could hit it
+        if chunked or (self.prefix is not None and seen):
             # one dummy chunk compiles the (single) chunk program
             tok_dev, self.kv = self._chunk(*self._dummy_chunk_args())
 
@@ -665,31 +587,27 @@ class Engine:
             # drop them so runtime hit/miss stats start clean and the
             # first real admissions aren't served warmup pages
             self.prefix.clear()
-        if self.alloc is not None:
-            assert self.alloc.pages_in_use == 0, \
-                f"warmup leaked {self.alloc.pages_in_use} pages"
+        assert self.alloc.pages_in_use == 0, \
+            f"warmup leaked {self.alloc.pages_in_use} pages"
         self._reset_counters()
         self.set_faults(plan)
         return self.compile_counts()
 
     def _dummy_chunk_args(self) -> tuple:
-        """Chunk-program arguments that write nothing live: on the slot
-        path the garbage lands in slot 0 beyond every causal mask until the
-        slot's next prefill overwrites it (the engine is idle); on the
-        paged path an all-sentinel table row drops the writes outright."""
-        route = (jnp.full((1, self.pages_per_slot), self.alloc.num_pages,
-                          jnp.int32) if self._paged else np.int32(0))
+        """Chunk-program arguments that write nothing live: an
+        all-sentinel table row drops the writes outright."""
         return (self.params, self.kv,
                 jnp.zeros((1, self.scheduler.buckets[-1]), jnp.int32),
-                np.int32(0), np.int32(1), route, np.float32(0.0),
-                np.int32(0), np.uint32(0))
+                np.int32(0), np.int32(1),
+                jnp.full((1, self.pages_per_slot), self.alloc.num_pages,
+                         jnp.int32),
+                np.float32(0.0), np.int32(0), np.uint32(0))
 
     def _decode_args(self) -> tuple:
-        args = (self.params, self.kv, jnp.asarray(self._pos),
+        return (self.params, self.kv, jnp.asarray(self._pos),
                 jnp.asarray(self._tok[:, None]), jnp.asarray(self._temps),
                 jnp.asarray(self._topks), jnp.asarray(self._seeds),
-                jnp.asarray(self._steps))
-        return args + ((jnp.asarray(self._table),) if self._paged else ())
+                jnp.asarray(self._steps), jnp.asarray(self._table))
 
     def step(self) -> None:
         """Admit every admissible request (one batched prefill dispatch per
@@ -718,29 +636,14 @@ class Engine:
             # queue_stats()["dropped"]
             self._g_queue.set(len(sched.queue))
         with span("engine.admit"):
-            if self._paged:
-                self._admit_paged()
-            else:
-                while (batch := sched.admit_batch(
-                        mixed=self.cfg.mixed_admission)) is not None:
-                    try:
-                        if batch.chunked:
-                            self._run_chunked(*batch.items[0])
-                        else:
-                            with span("engine.prefill",
-                                      lambda: self._prefill_span_args(
-                                          batch.items, batch.bucket)):
-                                self._run_prefill_batch(batch)
-                    except Exception as e:  # noqa: BLE001 — fault isolation
-                        self._abort_admission(batch.items, e)
+            self._admit()
 
         if sched.num_active == 0:
             return
-        if self._paged:
-            with span("engine.extend"):
-                self._extend_for_decode()
-            if sched.num_active == 0:      # extension self-preempted all
-                return
+        with span("engine.extend"):
+            self._extend_for_decode()
+        if sched.num_active == 0:          # extension self-preempted all
+            return
         with span("engine.decode", self._decode_span_args):
             out_dev, self.kv = self._decode(*self._decode_args())
         with span("engine.decode.wait"):
@@ -800,63 +703,7 @@ class Engine:
                 self._fail_slot(slot, RequestStatus.ERROR.value,
                                 f"prefill failed: {exc}")
 
-    def _run_prefill_batch(self, batch: AdmittedBatch) -> None:
-        """One device dispatch for a whole same-bucket admission batch."""
-        t_admit = self._now()
-        b, w = len(batch.items), batch.bucket
-        bb = next(x for x in self.batch_buckets if b <= x)
-        tokens = np.zeros((bb, w), np.int32)
-        lengths = np.ones((bb,), np.int32)
-        slots = np.full((bb,), self.cfg.num_slots, np.int32)  # pad: dropped
-        temps = np.zeros((bb,), np.float32)
-        topks = np.zeros((bb,), np.int32)
-        seeds = np.zeros((bb,), np.uint32)
-        for i, (slot, req) in enumerate(batch.items):
-            tokens[i, :req.prompt_len] = req.prompt
-            lengths[i] = req.prompt_len
-            slots[i] = slot
-            sp = req.sampling
-            temps[i], topks[i] = sp.temperature, sp.top_k
-            seeds[i] = np.uint32(sp.seed)
-        tok_dev, self.kv = self._prefill(
-            self.params, self.kv, jnp.asarray(tokens), jnp.asarray(lengths),
-            jnp.asarray(slots), jnp.asarray(temps), jnp.asarray(topks),
-            jnp.asarray(seeds))
-        toks = np.asarray(tok_dev)  # lint: allow[host-sync] B first tokens, one transfer per batched prefill
-        self._c["prefill_dispatches"].inc()
-        self._c["prefill_admitted"].inc(b)
-        self._c["prefill_tokens"].inc(int(lengths[:b].sum()))
-        now = self._now()
-        for i, (slot, req) in enumerate(batch.items):
-            self._record_first_token(slot, req, int(toks[i]), now, t_admit)
-
-    def _run_chunked(self, slot: int, req: GenerationRequest) -> None:
-        """Stream a beyond-largest-bucket prompt through the bucket-width
-        chunk program against the slot's own cache rows. Only the final
-        chunk's sample is real; intermediate device results are never
-        synced."""
-        t_admit = self._now()
-        w = self.scheduler.buckets[-1]
-        p, sp = req.prompt_len, req.sampling
-        tok_dev = None
-        for start in range(0, p, w):
-            clen = min(w, p - start)
-            chunk = np.zeros((1, w), np.int32)
-            chunk[0, :clen] = req.prompt[start:start + clen]
-            with span("engine.chunk", lambda: {"tokens": clen}):
-                tok_dev, self.kv = self._chunk(
-                    self.params, self.kv, jnp.asarray(chunk),
-                    np.int32(start), np.int32(clen), np.int32(slot),
-                    np.float32(sp.temperature), np.int32(sp.top_k),
-                    np.uint32(sp.seed))
-            self._c["chunk_dispatches"].inc()
-            self._c["prefill_tokens"].inc(clen)
-        self._c["chunked_admitted"].inc()
-        # lint: allow[host-sync] one scalar per chunked prefill, by design
-        self._record_first_token(slot, req, int(tok_dev), self._now(),
-                                 t_admit)
-
-    # -- paged admission ---------------------------------------------------
+    # -- admission ---------------------------------------------------------
     def _set_table_row(self, slot: int, pages: List[int]) -> None:
         row = self._table[slot]
         row[:] = self.alloc.num_pages                # sentinel tail
@@ -1009,8 +856,8 @@ class Engine:
             have.extend(pages)
             self._set_table_row(slot, have)
 
-    def _admit_paged(self) -> None:
-        """Paged admission, strictly FIFO. Plain bucket-size requests
+    def _admit(self) -> None:
+        """Admission, strictly FIFO. Plain bucket-size requests
         accumulate into one right-padded prefill dispatch (``pending``);
         prefix hits and beyond-largest-bucket prompts stream their
         unmatched suffix through the chunk program; resume tickets restore
@@ -1075,8 +922,7 @@ class Engine:
 
     def _flush_pending(self, pending: List[tuple]) -> None:
         """One right-padded prefill dispatch for the accumulated admission
-        run (the paged mirror of :meth:`_run_prefill_batch`; padding rows
-        carry all-sentinel page maps)."""
+        run (padding rows carry all-sentinel page maps)."""
         if not pending:
             return
         try:
@@ -1112,7 +958,7 @@ class Engine:
             self.params, self.kv, jnp.asarray(tokens), jnp.asarray(lengths),
             jnp.asarray(maps), jnp.asarray(temps), jnp.asarray(topks),
             jnp.asarray(seeds))
-        toks = np.asarray(tok_dev)  # lint: allow[host-sync] one transfer per paged prefill dispatch
+        toks = np.asarray(tok_dev)  # lint: allow[host-sync] one transfer per prefill dispatch
         self._c["prefill_dispatches"].inc()
         self._c["prefill_admitted"].inc(b)
         self._c["prefill_tokens"].inc(int(lengths[:b].sum()))
@@ -1122,8 +968,8 @@ class Engine:
 
     def _admit_stream(self, slot: int, req: GenerationRequest,
                       start_tok: int) -> None:
-        """Stream a prompt's unmatched suffix through the paged chunk
-        program, starting at the prefix-matched offset (0 for a plain
+        """Stream a prompt's unmatched suffix through the chunk program,
+        starting at the prefix-matched offset (0 for a plain
         beyond-largest-bucket prompt). Only the final chunk's sample is
         real; intermediate device results are never synced."""
         t_admit = self._now()
@@ -1199,12 +1045,11 @@ class Engine:
         self._release_slot(slot)
 
     def _release_slot(self, slot: int) -> None:
-        if self._paged:
-            # release the request's page references; prefix-cached pages
-            # keep their cache reference and survive for future matches
-            self.alloc.decref(self._slot_pages[slot])
-            self._slot_pages[slot] = []
-            self._set_table_row(slot, [])
+        # release the request's page references; prefix-cached pages keep
+        # their cache reference and survive for future matches
+        self.alloc.decref(self._slot_pages[slot])
+        self._slot_pages[slot] = []
+        self._set_table_row(slot, [])
         self._park(slot)
 
     def _finish_queued(self, item, status: str, msg: str = "") -> None:
@@ -1245,9 +1090,8 @@ class Engine:
         self._h_latency.observe(res.latency)
 
     def _park(self, slot: int) -> None:
-        # park the freed slot: greedy token 0 at position 0, overwritten by
-        # the next admission's prefill before it is ever attended (paged:
-        # the slot's all-sentinel table row drops its parked decode writes)
+        # park the freed slot: greedy token 0 at position 0; its
+        # all-sentinel table row drops its parked decode writes
         self._pos[slot] = 0
         self._tok[slot] = 0
         self._temps[slot] = 0.0
@@ -1301,8 +1145,8 @@ class Engine:
     def check_invariants(self) -> bool:
         """Reconcile every piece of host bookkeeping against every other:
         scheduler slot partition, result-table coverage, block tables vs.
-        per-slot page lists, and (paged) the allocator's refcounts/free
-        list against the union of live block tables and prefix-cache
+        per-slot page lists, and the allocator's refcounts/free list
+        against the union of live block tables and prefix-cache
         references. Raises :class:`EngineInvariantError` naming the first
         mismatch; returns True when consistent. Pure host arithmetic — no
         device sync — so chaos tests call it after EVERY step."""
@@ -1323,8 +1167,6 @@ class Engine:
             if req.rid not in self._results:
                 raise EngineInvariantError(
                     f"queued rid={req.rid} has no result entry")
-        if not self._paged:
-            return True
         pg, sentinel = self.cfg.page_size, self.alloc.num_pages
         want: Dict[int, int] = {}
         for slot in range(n):
@@ -1373,10 +1215,10 @@ class Engine:
     def compile_counts(self) -> Dict[str, int]:
         """Compiled-program counts (prefill: one per (prompt bucket, batch
         bucket) pair seen; chunk: 1 when the trace has beyond-largest-bucket
-        prompts; decode: 1). Flat across a post-warmup trace ⇔ no
-        recompilation. The paged spill/restore gathers compile lazily at
-        the first preemption (O(log max_pages) programs, bounded by the
-        pow2 padding) and are not tracked here."""
+        prompts or prefix hits; decode: 1). Flat across a post-warmup trace
+        ⇔ no recompilation. The spill/restore gathers compile lazily at the
+        first preemption (O(log max_pages) programs, bounded by the pow2
+        padding) and are not tracked here."""
         return {"prefill": self._prefill._cache_size(),
                 "chunk": self._chunk._cache_size(),
                 "decode": self._decode._cache_size()}
@@ -1394,11 +1236,8 @@ class Engine:
         return cache_bytes(self.kv)
 
     def page_stats(self) -> Dict[str, int]:
-        """Page-pool / prefix-reuse / preemption observability (paged
-        layout only; empty dict on the slot layout). Counters reset with
-        :meth:`warmup`."""
-        if not self._paged:
-            return {}
+        """Page-pool / prefix-reuse / preemption observability. Counters
+        reset with :meth:`warmup`."""
         return {
             "num_pages": self.alloc.num_pages,
             "page_size": self.cfg.page_size,
@@ -1448,10 +1287,9 @@ class Engine:
         ``page_stats``/``queue_stats``/the legacy counter attributes show
         is derivable from it."""
         self._g_slots.set_value(self.scheduler.num_active)
-        if self._paged:
-            self._g_pages.set_value(self.alloc.pages_in_use)
-            if self.prefix is not None:
-                self._g_prefix_pages.set_value(self.prefix.cached_pages)
+        self._g_pages.set_value(self.alloc.pages_in_use)
+        if self.prefix is not None:
+            self._g_prefix_pages.set_value(self.prefix.cached_pages)
         return self.metrics.snapshot()
 
 

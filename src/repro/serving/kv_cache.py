@@ -1,32 +1,32 @@
-"""Slot-indexed, optionally quantized KV cache for the serving engine.
+"""Paged, optionally quantized KV cache for the serving engine.
 
 The cache is a plain pytree (it flows through ``jax.jit`` / ``lax.scan`` /
 donation like the rest of the model state):
 
-    {"k": <storage>, "v": <storage>, "pos": (num_slots,) int32}
+    {"k": <storage>, "v": <storage>}
 
-where ``<storage>`` is either a dense ``(L, S, T, Hk, D)`` array (``L``
-layers, ``S`` slots, ``T`` max_len) or a :class:`QuantizedKV` — INT8 codes
-plus a per-(token, head, group) float32 scale and uint8 zero-point, groups
-tiling the head_dim axis ("per-head-group"). INT8 storage costs
-``1 + 5/group`` bytes per element vs 2 for bf16, i.e. ~½ the resident bytes
-at ``group ≥ 32``. The planes are float32/uint8 because the TPU kernels
-cannot load float16.
+where ``<storage>`` is a page pool of every layer: either a dense
+``(L, P, page, Hk, D)`` array (``L`` layers, ``P`` pages of ``page``
+tokens) or a :class:`QuantizedKV` — INT8 codes plus a per-(token, head,
+group) float32 scale and uint8 zero-point, groups tiling the head_dim axis
+("per-head-group"). INT8 storage costs ``1 + 5/group`` bytes per element
+vs 2 for bf16, i.e. ~½ the resident bytes at ``group ≥ 32``. The planes
+are float32/uint8 because the TPU kernels cannot load float16.
+``serving/paging.py`` owns the allocator and refcounts; this module owns
+the device arrays and the scatters.
 
-Reads dequantize at the attention boundary (``models/layers.attn_apply``):
-the reference path is pure jnp; on TPU the Pallas ``kv_dequant`` kernel
-(``repro.kernels.ops``) does the expansion in VMEM. Dispatch follows the
-same ``repro.quant.matmul_impl`` switch as the weight kernels.
-
-Writes quantize the incoming k/v: per-slot decode writes scatter one token
-at each slot's own position (``pos`` is a vector — the engine convention),
-prefill writes splice a whole batch of slot rows in one dispatch
-(:func:`write_slot`), and the chunked prefill works on one slot's rows via
-:func:`slot_rows` / :func:`set_slot_rows`.
+Each request's positions route to physical pages through a block table
+row. Writes quantize the incoming k/v: prefill scatters a whole batch of
+dense mini-caches through per-row page maps in one dispatch
+(:func:`write_pages`), decode and the chunked prefill write at each
+layer's index of the stacked pools (``models/layers.paged_write``). Reads
+dequantize at the attention boundary (``models/layers.attn_apply``): the
+fused decode kernel in-tile (:func:`fused_decode_attn`), the reference
+paths through :func:`kv_dequantize`, which follows the same
+``repro.quant.matmul_impl`` switch as the weight kernels.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import NamedTuple
 
 import jax
@@ -115,9 +115,8 @@ def kv_dequantize(q: QuantizedKV, dtype=jnp.float32) -> jax.Array:
     return flat.reshape(*lead, hk, d).astype(dtype)
 
 
-def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, *,
-                      table=None, layer=None,
-                      block_t: int = 256) -> jax.Array:
+def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, table,
+                      layer) -> jax.Array:
     """Fused flash-decode read of the cache: one query token per row
     attends its history in a single Pallas program — INT8 codes dequantize
     IN-TILE, no materialized dense K/V, per-row lengths bound the K loop.
@@ -125,12 +124,11 @@ def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, *,
     q: (B, 1, H, D) (RoPE applied); positions: (B, 1) absolute decode
     positions (row b's cache holds lengths[b] = positions[b] + 1 live
     tokens — the current token's K/V must already be written).
-    ``k_entry``/``v_entry`` are the per-layer storage: (B, T, Hk, D) slot
-    rows, or — with ``table`` (B, n_pages) — the stacked page pools
-    (L, P, page, Hk, D) of every layer, read at ``layer`` in place (dense
-    or :class:`QuantizedKV` either way). Returns (B, 1, H, D) in
-    q's dtype. The escape hatch is the caller's: ``use_fused_decode=False``
-    keeps the dequant-then-attend reference path.
+    ``k_entry``/``v_entry`` are the stacked page pools (L, P, page, Hk, D)
+    of every layer (dense or :class:`QuantizedKV`), read at ``layer`` in
+    place through ``table`` (B, n_pages). Returns (B, 1, H, D) in q's
+    dtype. The escape hatch is the caller's: ``use_fused_decode=False``
+    keeps the gather-then-attend reference path.
     """
     from repro.kernels import ops     # local: kernels are TPU-optional
     lengths = positions[:, 0].astype(jnp.int32) + 1
@@ -142,141 +140,13 @@ def fused_decode_attn(q: jax.Array, k_entry, v_entry, positions, *,
                       v_scale=v_entry.scale, v_zero=v_entry.zero,
                       group_size=k_entry.group_size)
         k_entry, v_entry = k_entry.codes, v_entry.codes
-    if table is not None:
-        out = ops.decode_attn_paged(q2, k_entry, v_entry, table, lengths,
-                                    layer=layer, **kwargs)
-    else:
-        out = ops.decode_attn(q2, k_entry, v_entry, lengths,
-                              block_t=block_t, **kwargs)
+    out = ops.decode_attn_paged(q2, k_entry, v_entry, table, lengths,
+                                layer=layer, **kwargs)
     return out[:, None].astype(q.dtype)
 
 
-def kv_update(q: QuantizedKV, x: jax.Array, pos) -> QuantizedKV:
-    """Write new tokens x (B, s, Hk, D) into the (B, T, Hk, D) storage.
-
-    ``pos`` scalar → splice s tokens at a uniform position (the static
-    serving path and the chunked prefill); ``pos`` vector (B,) → scatter
-    one token per row at that row's own position (the engine decode path,
-    s == 1). Scalar splices scatter per token column with drop semantics:
-    columns past the cache edge (a final prefill chunk's padded tail) are
-    dropped instead of shifting the write like ``dynamic_update_slice``
-    would."""
-    new = kv_quantize(x, q.group_size)
-    if getattr(pos, "ndim", 0) == 1:
-        assert x.shape[1] == 1, "per-slot writes are one token per step"
-        b = x.shape[0]
-        idx = jnp.arange(b)
-        return QuantizedKV(
-            q.codes.at[idx, pos].set(new.codes[:, 0]),
-            q.scale.at[idx, pos].set(new.scale[:, 0]),
-            q.zero.at[idx, pos].set(new.zero[:, 0]),
-            q.group_size)
-    cols = pos + jnp.arange(x.shape[1])
-    return QuantizedKV(
-        q.codes.at[:, cols].set(new.codes),
-        q.scale.at[:, cols].set(new.scale),
-        q.zero.at[:, cols].set(new.zero),
-        q.group_size)
-
-
 # ---------------------------------------------------------------------------
-# slot-cache construction / bookkeeping
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class KVCacheConfig:
-    """Shape/storage policy for the engine's slot cache."""
-    num_slots: int
-    max_len: int
-    dtype: object = jnp.float32      # dense storage dtype
-    quantized: bool = False          # INT8 per-head-group storage
-    group_size: int = 0              # 0 → head_dim (one group per head)
-
-
-def init_slot_cache(model_cfg, cfg: KVCacheConfig) -> dict:
-    """Fresh {"k", "v", "pos"} cache: (L, S, T, Hk, D) storage, per-slot
-    positions. The layer axis leads so ``lax.scan`` over blocks slices one
-    layer's (S, T, Hk, D) cache per step — identical to the static path."""
-    shape = (model_cfg.num_layers, cfg.num_slots, cfg.max_len,
-             model_cfg.num_kv_heads, model_cfg.resolved_head_dim)
-    if cfg.quantized:
-        g = cfg.group_size or model_cfg.resolved_head_dim
-        assert model_cfg.resolved_head_dim % g == 0, (shape, g)
-        store = QuantizedKV(
-            codes=jnp.zeros(shape, jnp.uint8),
-            scale=jnp.full(shape[:-1] + (shape[-1] // g,), 1e-4, jnp.float32),
-            zero=jnp.zeros(shape[:-1] + (shape[-1] // g,), jnp.uint8),
-            group_size=g)
-        k = store
-        v = QuantizedKV(jnp.zeros_like(store.codes),
-                        jnp.full_like(store.scale, 1e-4),
-                        jnp.zeros_like(store.zero), g)
-    else:
-        k = jnp.zeros(shape, cfg.dtype)
-        v = jnp.zeros(shape, cfg.dtype)
-    return {"k": k, "v": v, "pos": jnp.zeros((cfg.num_slots,), jnp.int32)}
-
-
-def write_slot(cache: dict, slots, k_new: jax.Array, v_new: jax.Array) -> dict:
-    """Splice B freshly prefilled slot rows into the big cache in one
-    dispatch.
-
-    ``slots``: (B,) int32 slot indices (a scalar is treated as B == 1);
-    ``k_new``/``v_new``: (L, B, W, Hk, D) dense floats (the batched prefill
-    mini-caches), written at [:, slots[b], :W]. The scatter drops rows
-    whose slot index is out of range — the batch-bucket padding convention
-    (padding rows carry slot == num_slots) — and any token column past the
-    cache edge, so padded bucket tails never hold live tokens."""
-    out = dict(cache)
-    slots = jnp.atleast_1d(jnp.asarray(slots, jnp.int32))
-    for name, new in (("k", k_new), ("v", v_new)):
-        entry = cache[name]
-        idx_s = slots[:, None]                      # (B, 1)
-        idx_t = jnp.arange(new.shape[2])[None, :]   # (1, W)
-        if isinstance(entry, QuantizedKV):
-            q = kv_quantize(new, entry.group_size)
-            entry = QuantizedKV(
-                entry.codes.at[:, idx_s, idx_t].set(q.codes),
-                entry.scale.at[:, idx_s, idx_t].set(q.scale),
-                entry.zero.at[:, idx_s, idx_t].set(q.zero),
-                entry.group_size)
-        else:
-            entry = entry.at[:, idx_s, idx_t].set(new.astype(entry.dtype))
-        out[name] = entry
-    return out
-
-
-def slot_rows(entry, slot):
-    """One slot's (L, 1, T, Hk, D) rows of the (L, S, T, Hk, D) storage
-    (dense or :class:`QuantizedKV`) — the chunked prefill's working view."""
-    if isinstance(entry, QuantizedKV):
-        return QuantizedKV(
-            jax.lax.dynamic_slice_in_dim(entry.codes, slot, 1, axis=1),
-            jax.lax.dynamic_slice_in_dim(entry.scale, slot, 1, axis=1),
-            jax.lax.dynamic_slice_in_dim(entry.zero, slot, 1, axis=1),
-            entry.group_size)
-    return jax.lax.dynamic_slice_in_dim(entry, slot, 1, axis=1)
-
-
-def set_slot_rows(entry, slot, rows):
-    """Write a (L, 1, T, Hk, D) slot row (from :func:`slot_rows`) back into
-    the (L, S, T, Hk, D) storage."""
-    if isinstance(entry, QuantizedKV):
-        return QuantizedKV(
-            jax.lax.dynamic_update_slice_in_dim(
-                entry.codes, rows.codes, slot, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(
-                entry.scale, rows.scale, slot, axis=1),
-            jax.lax.dynamic_update_slice_in_dim(
-                entry.zero, rows.zero, slot, axis=1),
-            entry.group_size)
-    return jax.lax.dynamic_update_slice_in_dim(
-        entry, rows.astype(entry.dtype), slot, axis=1)
-
-
-# ---------------------------------------------------------------------------
-# paged storage (the block-table layout — serving/paging.py owns the
-# allocator/refcounts; this layer owns the device arrays and the scatters)
+# page pools and their scatters
 # ---------------------------------------------------------------------------
 
 def init_paged_storage(model_cfg, num_pages: int, page_size: int,
@@ -311,7 +181,7 @@ def init_paged_storage(model_cfg, num_pages: int, page_size: int,
 def write_pages(kv: dict, page_map, k_new: jax.Array, v_new: jax.Array,
                 page_size: int) -> dict:
     """Splice B freshly prefilled (L, B, W, Hk, D) mini-caches into the page
-    pools through per-row page maps — the paged mirror of :func:`write_slot`.
+    pools through per-row page maps, in one dispatch.
 
     ``page_map``: (B, ceil(W/page_size)) int32 physical page per page-column
     of each row. Entries equal to the sentinel (== num_pages) drop their
@@ -319,7 +189,7 @@ def write_pages(kv: dict, page_map, k_new: jax.Array, v_new: jax.Array,
     and a row's map holds exactly ceil(prompt_len/page_size) live pages, so
     the bucket-padded tail past the last allocated page is dropped (the
     garbage inside the last live page is overwritten by decode writes in
-    position order before it is ever attended, as on the slot path)."""
+    position order before it is ever attended)."""
     out = dict(kv)
     w = k_new.shape[2]
     pidx = jnp.arange(w) // page_size                   # (W,) static
@@ -348,7 +218,7 @@ def paged_view(entry, table, layer):
     from the stack (no per-layer pool is sliced out). Sentinel table
     entries clip to the last physical page — those positions are strictly
     beyond every live query's causal mask, so the view attends identically
-    to a slot-cache row."""
+    to a contiguous cache holding the same tokens."""
     def gather(pool):
         b, npg = table.shape
         g = pool[layer, table]                          # (B, npg, page, ...)
@@ -374,7 +244,7 @@ def put_pages(entry, pages, rows):
 
 
 def cache_bytes(cache: dict) -> int:
-    """Resident bytes of the K/V storage (excludes the tiny pos vector)."""
+    """Resident bytes of the K/V storage."""
     total = 0
     for name in ("k", "v"):
         entry = cache[name]
@@ -395,7 +265,7 @@ def cache_is_finite(cache: dict) -> bool:
     per plane — a diagnostic, not a per-step check."""
     for name in ("k", "v"):
         entry = cache[name]
-        leaves = ([entry.codes, entry.scales]
+        leaves = ([entry.codes, entry.scale]
                   if isinstance(entry, QuantizedKV) else [entry])
         for leaf in leaves:
             if jnp.issubdtype(leaf.dtype, jnp.floating) and not bool(
@@ -404,8 +274,6 @@ def cache_is_finite(cache: dict) -> bool:
     return True
 
 
-__all__ = ["QuantizedKV", "KVCacheConfig", "init_slot_cache", "write_slot",
-           "slot_rows", "set_slot_rows", "cache_bytes", "cache_is_finite",
-           "kv_quantize", "kv_dequantize", "kv_update", "fused_decode_attn",
-           "init_paged_storage", "write_pages", "paged_view", "take_pages",
-           "put_pages"]
+__all__ = ["QuantizedKV", "cache_bytes", "cache_is_finite", "kv_quantize",
+           "kv_dequantize", "fused_decode_attn", "init_paged_storage",
+           "write_pages", "paged_view", "take_pages", "put_pages"]

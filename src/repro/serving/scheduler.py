@@ -7,15 +7,14 @@ compiled shapes goes through :func:`Scheduler.bucket_for` (prompt-length
 bucketing), so the step functions compile once per bucket and never again.
 
 Invariants (tested in tests/test_engine.py and tests/test_paging.py):
-- admission is FIFO: requests start in submit order (``admit_batch`` pops
-  the FIFO head-run — by default the longest run sharing one prompt
-  bucket; ``mixed=True`` crosses buckets and right-pads the run to its
-  largest member's bucket — it never skips over a queued request);
+- admission is FIFO: requests start in submit order (the engine pops the
+  queue head with ``admit_head`` and never skips over a queued request);
 - a slot is EXCLUSIVE: never two live requests on one slot;
 - retire frees the slot for reuse within the same run;
 - a request is admitted only if prompt_len + max_new_tokens fits max_len
   and it decodes at least one token (max_new_tokens >= 1);
-- a prompt longer than the largest bucket admits alone (chunked prefill);
+- a prompt longer than the largest bucket streams through the chunked
+  prefill at the largest bucket's width;
 - priority is submission order (``seq``): preemption (the paged engine)
   always victimizes the YOUNGEST live request, and a preempted request's
   :class:`ResumeTicket` re-enters the queue ordered by seq — ahead of
@@ -181,19 +180,6 @@ class ResumeTicket:
         return self.request.seq
 
 
-@dataclasses.dataclass
-class AdmittedBatch:
-    """One admission group. ``chunked=False``: the FIFO head-run sharing
-    one prompt ``bucket``, admitted together — one batched prefill dispatch
-    covers every ``(slot, request)`` in ``items``. ``chunked=True``: a
-    single request whose prompt exceeds the largest bucket; it streams
-    through the bucket-width chunked-prefill program (``bucket`` is the
-    chunk width, i.e. the largest bucket)."""
-    bucket: int
-    items: List[tuple]                 # [(slot, request), ...]
-    chunked: bool = False
-
-
 def default_buckets(max_len: int) -> tuple:
     """Power-of-two prompt buckets 8, 16, … covering max_len."""
     out, b = [], 8
@@ -220,7 +206,7 @@ class Scheduler:
             raise ValueError(
                 f"largest prompt bucket {self.buckets[-1]} exceeds max_len "
                 f"{max_len}: the bucket-padded prefill would write past the "
-                f"slot cache edge")
+                f"cache edge")
         self.queue: Deque = deque()        # GenerationRequest | ResumeTicket
         self.free: Deque[int] = deque(range(num_slots))
         self.slots: List[Optional[SlotState]] = [None] * num_slots
@@ -238,8 +224,8 @@ class Scheduler:
                 f"max_new {req.max_new_tokens} exceeds max_len {self.max_len}")
         if req.prompt_len < 1:
             raise InvalidRequestError(f"request {req.rid}: empty prompt")
-        # prompts beyond the largest bucket are fine: they admit alone and
-        # stream through the chunked prefill (see admit_batch)
+        # prompts beyond the largest bucket are fine: they stream through
+        # the chunked prefill
         req.seq = self._seq
         self._seq += 1
         self.queue.append(req)
@@ -254,19 +240,6 @@ class Scheduler:
                 del self.queue[i]
                 return item
         return None
-
-    def admit(self) -> Optional[tuple]:
-        """Pop the FIFO head onto a free slot → (slot, request), or None."""
-        if not self.queue or not self.free:
-            return None
-        assert not isinstance(self.queue[0], ResumeTicket), \
-            "resume tickets re-admit through admit_head (engine restores " \
-            "spilled pages); admit() only handles fresh requests"
-        slot = self.free.popleft()
-        req = self.queue.popleft()
-        assert self.slots[slot] is None, f"slot {slot} double-booked"
-        self.slots[slot] = SlotState(request=req)
-        return slot, req
 
     def peek(self):
         """The queue head (GenerationRequest or ResumeTicket), or None."""
@@ -312,48 +285,6 @@ class Scheduler:
         self.free.append(slot)
         self.requeue(ticket)
         return state
-
-    def admit_batch(self, mixed: bool = False) -> Optional[AdmittedBatch]:
-        """Pop the longest FIFO head-run sharing one prompt bucket onto
-        free slots — one batched prefill dispatch admits the whole run.
-
-        A prompt beyond the largest bucket admits alone (``chunked=True``):
-        it streams through the bucket-width program chunk by chunk. FIFO
-        order is preserved strictly — the run stops at the first queued
-        request whose bucket differs (never skips over it) or when the
-        free-list empties. With ``mixed=True`` the run crosses buckets:
-        it pops the head-run of every in-bucket request and dispatches one
-        prefill right-padded to the LARGEST member's bucket (causal masking
-        plus per-row lengths make the padding inert), collapsing a
-        short/long interleave into one dispatch instead of one per bucket
-        flip. Returns None when nothing is admissible.
-
-        Resume tickets are never popped here — the caller drains them via
-        :meth:`admit_head` (they need page restoration, not prefill)."""
-        if not self.queue or not self.free:
-            return None
-        if isinstance(self.queue[0], ResumeTicket):
-            return None
-        wmax = self.buckets[-1]
-        if self.queue[0].prompt_len > wmax:
-            return AdmittedBatch(bucket=wmax, items=[self.admit()],
-                                 chunked=True)
-        items = []
-        if mixed:
-            bucket = 0
-            while (self.queue and self.free
-                   and not isinstance(self.queue[0], ResumeTicket)
-                   and self.queue[0].prompt_len <= wmax):
-                bucket = max(bucket, self.bucket_for(self.queue[0].prompt_len))
-                items.append(self.admit())
-            return AdmittedBatch(bucket=bucket, items=items)
-        bucket = self.bucket_for(self.queue[0].prompt_len)
-        while (self.queue and self.free
-               and not isinstance(self.queue[0], ResumeTicket)
-               and self.queue[0].prompt_len <= wmax
-               and self.bucket_for(self.queue[0].prompt_len) == bucket):
-            items.append(self.admit())
-        return AdmittedBatch(bucket=bucket, items=items)
 
     def retire(self, slot: int) -> GenerationRequest:
         state = self.slots[slot]
@@ -402,7 +333,7 @@ class Scheduler:
         return out
 
 
-__all__ = ["AdmittedBatch", "DuplicateRequestError", "EngineError",
+__all__ = ["DuplicateRequestError", "EngineError",
            "EngineInvariantError", "EngineStalledError", "GenerationRequest",
            "GenerationResult", "InvalidRequestError", "QueueFullError",
            "RequestStatus", "ResumeTicket", "SlotState", "Scheduler",

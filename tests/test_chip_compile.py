@@ -74,28 +74,26 @@ def _dequant(m: int, n: int, k: int):
         ((n, k // GROUP),)]
 
 
-def _flash(paged: bool, int8: bool, stacked: bool = False, *,
-           slots=SLOTS, t=T, pages=PAGES, layers=CFG.num_layers, heads=H,
-           kv_heads=HK, head_dim=HD):
-    """flash_decode on a slot cache, one layer's page pool, or (stacked)
-    the pools of every layer read at a traced layer index."""
-    lead = (pages, PAGE) if paged else (slots, t)
+def _flash(int8: bool, stacked: bool = False, *, slots=SLOTS, t=T,
+           pages=PAGES, layers=CFG.num_layers, heads=H, kv_heads=HK,
+           head_dim=HD, dtype=jnp.float32):
+    """flash_decode on one layer's page pool, or (stacked) the pools of
+    every layer read at a traced layer index."""
+    lead = (pages, PAGE)
     if stacked:
         lead = (layers,) + lead
-    kv = [(lead + (kv_heads, head_dim), jnp.uint8 if int8 else jnp.float32)]
+    kv = [(lead + (kv_heads, head_dim), jnp.uint8 if int8 else dtype)]
     if int8:          # one group per head: f32 scale, uint8 zero-point
         kv += [(lead + (kv_heads, 1),), (lead + (kv_heads, 1), jnp.uint8)]
     shapes = [((slots, heads, head_dim),)] + kv + kv + [((slots,), jnp.int32)]
-    if paged:
-        shapes.append(((slots, t // PAGE), jnp.int32))
+    shapes.append(((slots, t // PAGE), jnp.int32))
     if stacked:
         shapes.append(((), jnp.int32))
 
     def fn(q, *rest):
         layer = rest[-1] if stacked else None
         rest = rest[:-1] if stacked else rest
-        table = rest[-1] if paged else None
-        lengths = rest[-2] if paged else rest[-1]
+        table, lengths = rest[-1], rest[-2]
         planes = rest[:len(kv) * 2]
         if int8:
             kc, ks, kz, vc, vs, vz = planes
@@ -119,22 +117,23 @@ CASES = {
     **{f"dequant_matmul_m{m}_{n}x{k}": (lambda m=m, n=n, k=k:
                                          _dequant(m, n, k))
        for m in (8, 512) for n, k in ((D, D), (F, D), (D, F))},
-    "flash_decode_slot_dense": lambda: _flash(False, False),
-    "flash_decode_slot_int8": lambda: _flash(False, True),
-    "flash_decode_paged_dense": lambda: _flash(True, False),
-    "flash_decode_paged_int8": lambda: _flash(True, True),
-    "flash_decode_paged_stacked_dense": lambda: _flash(True, False, True),
-    "flash_decode_paged_stacked_int8": lambda: _flash(True, True, True),
+    "flash_decode_paged_dense": lambda: _flash(False),
+    "flash_decode_paged_int8": lambda: _flash(True),
+    "flash_decode_paged_stacked_dense": lambda: _flash(False, True),
+    "flash_decode_paged_stacked_int8": lambda: _flash(True, True),
+    # bf16 pools keep the tiled grid, one page a grid step
+    "flash_decode_paged_bf16": lambda: _flash(False, dtype=jnp.bfloat16),
+    "flash_decode_paged_stacked_bf16": lambda: _flash(
+        False, True, dtype=jnp.bfloat16),
     # head_dim 128: the paged loop over live pages
-    "flash_decode_paged_loop_dense": lambda: _flash(True, False,
-                                                    head_dim=128),
+    "flash_decode_paged_loop_dense": lambda: _flash(False, head_dim=128),
     "flash_decode_paged_loop_stacked_dense": lambda: _flash(
-        True, False, True, head_dim=128),
+        False, True, head_dim=128),
     # granite-8b.serve.assist: 12 rows, a 256-page table, the f32 pools of
     # 36 layers (a flat (36·1024, 16, 8, 128) pool in the kernel)
     "flash_decode_paged_loop_granite_cell": lambda: _flash(
-        True, False, True, slots=12, t=4096, pages=1024, layers=36,
-        heads=32, kv_heads=8, head_dim=128),
+        False, True, slots=12, t=4096, pages=1024, layers=36, heads=32,
+        kv_heads=8, head_dim=128),
     "kv_dequant": _kv_dequant,
     "topk_mask": lambda: ((lambda z: topk_mask.topk_row(z, F // 2)),
                           [((D, F),)]),
@@ -179,8 +178,8 @@ def test_paged_step_programs_keep_pools_in_place_on_v5e(
         name = name[:-len("_hd128")]
     model = build_model(cfg, remat=False)
     eng = Engine(model, model.init(jax.random.PRNGKey(0)), EngineConfig(
-        num_slots=4, max_len=64, prompt_buckets=(16, 32), kv_layout="paged",
-        page_size=16, num_pages=19))
+        num_slots=4, max_len=64, prompt_buckets=(16, 32), page_size=16,
+        num_pages=19))
     fn, args = {"decode": (eng._decode, eng._decode_args),
                 "chunk": (eng._chunk, eng._dummy_chunk_args)}[name]
     args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(

@@ -1,6 +1,6 @@
 """Fused flash-decode attention kernel: parity matrix vs the reference
-dequant-then-attend path over slot/paged layouts, dense/INT8 storage,
-head-group sizes, and ragged per-slot lengths (length-0 and full-cache
+gather-then-attend path over paged pools, dense/INT8 storage, head-group
+sizes, page sizes and ragged per-slot lengths (length-0 and full-cache
 slots included) — plus the engine-level greedy bit-parity contract for the
 ``use_fused_decode`` escape hatch."""
 import dataclasses
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_tiny_config
-from repro.kernels import decode_attn, ops
+from repro.kernels import decode_attn, ops, ref as kref
 from repro.models import build_model
 from repro.models.layers import decode_attention
 from repro.serving import Engine, EngineConfig, GenerationRequest, \
@@ -56,21 +56,29 @@ def _paged_pool(rng, k, v, lens, page):
 
 
 # ---------------------------------------------------------------------------
-# slot layout
+# paged pools: ragged lengths, head groups, page sizes, INT8 groups
 # ---------------------------------------------------------------------------
 
+def _paged_case(rng, hk, g, page, dtype=jnp.float32):
+    """q, the contiguous rows, and their shuffled page pool at LENS."""
+    q, k, v = _qkv(rng, len(LENS), T, hk, g, dtype)
+    pool_k, pool_v, table, _ = _paged_pool(rng, k, v, LENS, page)
+    return q, k, v, pool_k.astype(dtype), pool_v.astype(dtype), table
+
+
 @pytest.mark.parametrize("hk,g", [(1, 1), (2, 1), (2, 4)])
-def test_slot_dense_parity_ragged_lengths(hk, g):
+def test_paged_dense_parity_ragged_lengths(hk, g):
     rng = np.random.default_rng(0)
-    q, k, v = _qkv(rng, len(LENS), T, hk, g)
+    q, k, v, pool_k, pool_v, table = _paged_case(rng, hk, g, 8)
     lens = jnp.asarray(LENS, jnp.int32)
-    ref = ops.decode_attn(q, k, v, lens, use_pallas=False)
-    fused = ops.decode_attn(q, k, v, lens, block_t=16)
+    ref = ops.decode_attn_paged(q, pool_k, pool_v, table, lens,
+                                use_pallas=False)
+    fused = ops.decode_attn_paged(q, pool_k, pool_v, table, lens)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
     assert np.all(np.asarray(fused[0]) == 0.0)       # length-0 → exact zeros
     # the reference path itself must agree with decode_attention (the
-    # pre-fusion masked-softmax read) on live rows
+    # model's masked-softmax read) over the contiguous rows on live rows
     live = [i for i, l in enumerate(LENS) if l > 0]
     pos = (lens - 1)[jnp.asarray(live)][:, None]
     da = decode_attention(q[jnp.asarray(live)][:, None], k[jnp.asarray(live)],
@@ -79,47 +87,48 @@ def test_slot_dense_parity_ragged_lengths(hk, g):
                                atol=1e-5, rtol=1e-5)
 
 
-def test_slot_single_tile_is_bit_identical():
-    """With one K tile covering the whole cache the online softmax visits
+def test_paged_single_page_is_bit_identical():
+    """With one page covering the whole cache the online softmax visits
     every key in one pass — fused output is bit-identical to the oracle."""
     rng = np.random.default_rng(1)
-    q, k, v = _qkv(rng, len(LENS), T, 2, 2)
+    q, _, _, pool_k, pool_v, table = _paged_case(rng, 2, 2, T)
     lens = jnp.asarray(LENS, jnp.int32)
-    ref = ops.decode_attn(q, k, v, lens, use_pallas=False)
-    fused = ops.decode_attn(q, k, v, lens, block_t=T)
+    ref = ops.decode_attn_paged(q, pool_k, pool_v, table, lens,
+                                use_pallas=False)
+    fused = ops.decode_attn_paged(q, pool_k, pool_v, table, lens)
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(ref))
 
 
-def test_slot_tile_size_invariance():
-    rng = np.random.default_rng(2)
-    q, k, v = _qkv(rng, len(LENS), T, 2, 2)
+def test_paged_page_size_invariance():
+    """The same rows in pools of 7-, 16-, 40- and 64-token pages (a
+    partial last page, several pages, one page, a page wider than the
+    cache) attend to the same output."""
     lens = jnp.asarray(LENS, jnp.int32)
-    outs = [ops.decode_attn(q, k, v, lens, block_t=bt)
-            for bt in (7, 16, 40, 512)]
+    outs = []
+    for page in (7, 16, 40, 64):
+        rng = np.random.default_rng(2)
+        q, _, _, pool_k, pool_v, table = _paged_case(rng, 2, 2, page)
+        outs.append(ops.decode_attn_paged(q, pool_k, pool_v, table, lens))
     for o in outs[1:]:
         np.testing.assert_allclose(np.asarray(o), np.asarray(outs[0]),
                                    atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("group", [D, D // 2])
-def test_slot_int8_parity(group):
+def test_paged_int8_parity_per_group(group):
     rng = np.random.default_rng(3)
-    q, k, v = _qkv(rng, len(LENS), T, 2, 3)
-    qk, qv = kv_quantize(k, group), kv_quantize(v, group)
+    q, _, _, pool_k, pool_v, table = _paged_case(rng, 2, 3, 8)
+    qk, qv = kv_quantize(pool_k, group), kv_quantize(pool_v, group)
     lens = jnp.asarray(LENS, jnp.int32)
-    args = (q, qk.codes, qv.codes, lens, qk.scale, qk.zero, qv.scale,
+    args = (q, qk.codes, qv.codes, table, lens, qk.scale, qk.zero, qv.scale,
             qv.zero)
-    ref = ops.decode_attn(*args, group_size=group, use_pallas=False)
-    fused = ops.decode_attn(*args, group_size=group, block_t=16)
+    ref = ops.decode_attn_paged(*args, group_size=group, use_pallas=False)
+    fused = ops.decode_attn_paged(*args, group_size=group)
     # same in-tile dequant numerics as the reference expansion → float-tight
     np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
     assert np.all(np.asarray(fused[0]) == 0.0)
 
-
-# ---------------------------------------------------------------------------
-# paged layout
-# ---------------------------------------------------------------------------
 
 def test_paged_dense_parity_and_slot_equivalence():
     rng = np.random.default_rng(4)
@@ -131,9 +140,9 @@ def test_paged_dense_parity_and_slot_equivalence():
     fused = ops.decode_attn_paged(q, pool_k, pool_v, table, lens)
     np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
-    # the paged read attends the same written tokens as the slot read
-    slot = ops.decode_attn(q, k, v, lens, use_pallas=False)
-    np.testing.assert_allclose(np.asarray(ref), np.asarray(slot),
+    # the paged read attends the same written tokens as a contiguous read
+    rows = kref.decode_attn(q, k, v, lens)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(rows),
                                atol=1e-5, rtol=1e-5)
     assert np.all(np.asarray(fused[0]) == 0.0)       # all-sentinel row
 
@@ -335,12 +344,14 @@ def test_paged_loop_table_shorter_than_a_block():
 
 def test_fused_decode_attn_wrapper_shapes_and_dtype():
     rng = np.random.default_rng(6)
-    q, k, v = _qkv(rng, len(LENS), T, 2, 2, dtype=jnp.bfloat16)
+    q, _, _, pool_k, pool_v, table = _paged_case(rng, 2, 2, 8, jnp.bfloat16)
+    k, v = pool_k[None], pool_v[None]                # a stack of one layer
     positions = (jnp.asarray(LENS, jnp.int32) - 1)[:, None]
-    out = fused_decode_attn(q[:, None], k, v, positions)
+    layer = jnp.int32(0)
+    out = fused_decode_attn(q[:, None], k, v, positions, table, layer)
     assert out.shape == (len(LENS), 1, 4, D) and out.dtype == jnp.bfloat16
     qk, qv = kv_quantize(k, D), kv_quantize(v, D)
-    out_q = fused_decode_attn(q[:, None], qk, qv, positions)
+    out_q = fused_decode_attn(q[:, None], qk, qv, positions, table, layer)
     assert out_q.shape == out.shape and out_q.dtype == jnp.bfloat16
     assert isinstance(qk, QuantizedKV)
 
@@ -351,8 +362,10 @@ def test_nan_rows_propagate_to_output():
     rng = np.random.default_rng(7)
     q, k, v = _qkv(rng, 3, T, 2, 2)
     k = k.at[1].set(jnp.nan)
-    lens = jnp.asarray([T, T, 0], jnp.int32)
-    fused = ops.decode_attn(q, k, v, lens, block_t=16)
+    lens = [T, T, 0]
+    pool_k, pool_v, table, _ = _paged_pool(rng, k, v, lens, page=8)
+    fused = ops.decode_attn_paged(q, pool_k, pool_v, table,
+                                  jnp.asarray(lens, jnp.int32))
     assert bool(jnp.all(jnp.isfinite(fused[0])))
     assert not bool(jnp.all(jnp.isfinite(fused[1])))
     assert np.all(np.asarray(fused[2]) == 0.0)       # parked row unaffected
@@ -386,14 +399,14 @@ def _run(model, params, cfg, **ecfg_kw):
 
 
 @pytest.mark.parametrize("storage", [
-    dict(kv_dtype=jnp.float32),
-    dict(kv_dtype=jnp.bfloat16, kv_quantized=True),
-    dict(kv_dtype=jnp.float32, kv_layout="paged", page_size=8),
-    dict(kv_dtype=jnp.bfloat16, kv_quantized=True, kv_layout="paged",
-         page_size=8),
+    dict(kv_dtype=jnp.float32, page_size=8),
+    dict(kv_dtype=jnp.bfloat16, kv_quantized=True, page_size=8),
     # head_dim 128: the paged loop over live pages
-    dict(kv_dtype=jnp.float32, kv_layout="paged", page_size=4,
-         head_dim=128),
+    dict(kv_dtype=jnp.float32, page_size=4, head_dim=128),
+    # bf16 pools and INT8 groups narrower than a head: the tiled grid
+    dict(kv_dtype=jnp.bfloat16, page_size=8),
+    dict(kv_dtype=jnp.bfloat16, kv_quantized=True, kv_group_size=8,
+         page_size=8),
 ])
 def test_engine_greedy_bit_parity_fused_vs_reference(tiny_lm, storage):
     cfg, model, params = tiny_lm

@@ -1,6 +1,6 @@
-"""Continuous-batching engine: scheduler invariants, slot-cache
-quantization, engine-vs-static greedy parity, per-slot sampling
-determinism, and the no-recompilation-after-warmup contract."""
+"""Continuous-batching engine: scheduler invariants, admission runs, page
+pool writes and quantization, engine-vs-static greedy parity, per-slot
+sampling determinism, and the no-recompilation-after-warmup contract."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,11 +9,13 @@ import pytest
 from repro.configs import get_tiny_config
 from repro.models import build_model
 from repro.quant import matmul_impl
+from repro.models.layers import paged_write
 from repro.serving import (Engine, EngineConfig, GenerationRequest,
-                           KVCacheConfig, SamplingParams, Scheduler,
-                           cache_bytes, init_slot_cache, kv_dequantize,
-                           kv_quantize, kv_update, sample_tokens)
-from repro.serving.kv_cache import _reference_dequant
+                           SamplingParams, Scheduler, cache_bytes,
+                           init_paged_storage, kv_dequantize, kv_quantize,
+                           sample_tokens, write_pages)
+from repro.serving.kv_cache import (QuantizedKV, _reference_dequant,
+                                    put_pages, take_pages)
 from repro.serving.scheduler import default_buckets
 
 
@@ -53,21 +55,22 @@ def test_scheduler_fifo_admission_and_slot_reuse():
     for i in range(5):
         s.submit(GenerationRequest(rid=i, prompt=np.arange(4, dtype=np.int32),
                                    max_new_tokens=4))
-    a0 = s.admit()
-    a1 = s.admit()
+    a0 = s.admit_head()
+    a1 = s.admit_head()
     assert a0[1].rid == 0 and a1[1].rid == 1          # FIFO order
     assert {a0[0], a1[0]} == {0, 1}                   # distinct slots
-    assert s.admit() is None                          # full: no admission
+    assert s.admit_head() is None                     # full: no admission
+    assert s.peek().rid == 2                          # head waits in place
     assert s.num_active == 2 and not s.idle
 
     freed = a0[0]
     assert s.retire(freed).rid == 0                   # eviction frees slot
-    a2 = s.admit()
+    a2 = s.admit_head()
     assert a2[0] == freed and a2[1].rid == 2          # slot reused, FIFO kept
     for slot in list(s.active_slots()):
         s.retire(slot)
-    assert s.admit()[1].rid == 3 and s.admit()[1].rid == 4
-    assert s.admit() is None and len(s.queue) == 0
+    assert s.admit_head()[1].rid == 3 and s.admit_head()[1].rid == 4
+    assert s.admit_head() is None and s.peek() is None
 
 
 def test_scheduler_rejects_oversized_empty_and_zero_budget_requests():
@@ -98,37 +101,48 @@ def test_prompt_bucketing():
     s2.submit(GenerationRequest(rid=9, prompt=np.zeros(30, np.int32),
                                 max_new_tokens=4))
     assert s2.bucket_for(30) == 24
-    batch = s2.admit_batch()
-    assert batch.chunked and batch.bucket == 24
-    assert [r.rid for _, r in batch.items] == [9]
+    assert s2.admit_head()[1].rid == 9
     # a bucket wider than max_len would silently clip live prompt tokens at
     # the cache edge — rejected at construction instead
     with pytest.raises(ValueError):
         Scheduler(num_slots=1, max_len=16, prompt_buckets=(8, 32))
 
 
-def test_scheduler_admit_batch_groups_fifo_head_run():
-    s = Scheduler(num_slots=4, max_len=64)          # buckets 8/16/32/64
-    lens = [5, 8, 13, 7, 6]                          # buckets 8,8,16,8,8
-    for i, l in enumerate(lens):
-        s.submit(GenerationRequest(rid=i, prompt=np.ones(l, np.int32),
-                                   max_new_tokens=2))
-    b0 = s.admit_batch()                             # head-run: rids 0,1
-    assert not b0.chunked and b0.bucket == 8
-    assert [r.rid for _, r in b0.items] == [0, 1]    # stops at rid 2 (b16)
-    b1 = s.admit_batch()
-    assert b1.bucket == 16 and [r.rid for _, r in b1.items] == [2]
-    b2 = s.admit_batch()                             # free-list caps the run
-    assert b2.bucket == 8 and [r.rid for _, r in b2.items] == [3]
-    assert s.admit_batch() is None and len(s.queue) == 1
-    for slot, _ in b0.items:
-        s.retire(slot)
-    b3 = s.admit_batch()
-    assert [r.rid for _, r in b3.items] == [4]
+# ---------------------------------------------------------------------------
+# admission runs
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["bucketed", "mixed"])
+def test_admission_flushes_its_run_at_a_bucket_change(tiny_lm, mixed):
+    """Admission pops the FIFO head onto free slots and gathers same-bucket
+    heads into one prefill dispatch: a bucket change flushes the run
+    (``mixed_admission`` keeps it going, right-padded to its largest
+    bucket), and a full slot list stops it without skipping the head."""
+    cfg, model, params = tiny_lm
+    engine = Engine(model, params, EngineConfig(
+        num_slots=4, max_len=64, mixed_admission=mixed,
+        prefix_caching=False))                        # buckets 8/16/32/64
+    runs = []
+    dispatch = engine._dispatch_pending
+
+    def recording(pending):
+        runs.append([r.rid for _, r in pending])
+        dispatch(pending)
+
+    engine._dispatch_pending = recording
+    for r in _requests(cfg, lens=[5, 8, 13, 7, 6], gens=[2] * 5):
+        engine.submit(r)                              # buckets 8,8,16,8,8
+    engine.step()
+    assert runs == ([[0, 1, 2, 3]] if mixed else [[0, 1], [2], [3]])
+    assert engine.scheduler.peek().rid == 4           # no free slot: waits
+    results = engine.run()
+    assert runs[-1] == [4]
+    assert sorted(r.rid for r in results) == [0, 1, 2, 3, 4]
+    assert engine.prefill_dispatches == len(runs)
 
 
 # ---------------------------------------------------------------------------
-# KV cache quantization
+# KV page pool: writes and quantization
 # ---------------------------------------------------------------------------
 
 def test_kv_quantize_roundtrip_and_kernel_parity(rng):
@@ -150,100 +164,138 @@ def test_kv_quantize_roundtrip_and_kernel_parity(rng):
     assert float(jnp.abs(_reference_dequant(z, jnp.float32)).max()) == 0.0
 
 
-def test_kv_update_scalar_and_vector_writes(rng):
-    x = jnp.asarray(rng.normal(size=(2, 3, 2, 16)), jnp.float32)
-    base = kv_quantize(jnp.zeros((2, 8, 2, 16)), 16)
-    splice = kv_update(base, x, jnp.int32(2))         # scalar: rows 2..4
-    got = _reference_dequant(splice, jnp.float32)[:, 2:5]
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(_reference_dequant(
-                                   kv_quantize(x, 16), jnp.float32)))
-    tok = x[:, :1]
-    scatter = kv_update(base, tok, jnp.asarray([1, 6]))  # per-slot positions
-    deq = _reference_dequant(scatter, jnp.float32)
-    ref = _reference_dequant(kv_quantize(tok, 16), jnp.float32)
-    np.testing.assert_allclose(np.asarray(deq[0, 1]), np.asarray(ref[0, 0]))
-    np.testing.assert_allclose(np.asarray(deq[1, 6]), np.asarray(ref[1, 0]))
-    assert float(jnp.abs(deq[0, 2:]).max()) == 0.0    # rest untouched
+def _planes(entry):
+    return [np.asarray(x) for x in jax.tree.leaves(entry)]
 
 
-def test_write_slot_batched_matches_sequential(rng, tiny_lm):
-    """One batched write_slot dispatch (B rows) is bit-identical to B
-    sequential single-slot splices, for dense AND INT8 QuantizedKV storage;
-    padding rows (slot == num_slots) are dropped."""
+def _read(entry, dtype=jnp.float32):
+    """Dense values of a pool plane, INT8 storage dequantized."""
+    if isinstance(entry, QuantizedKV):
+        entry = _reference_dequant(entry, dtype)
+    return np.array(entry)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+def test_paged_write_scalar_and_vector_positions(rng, tiny_lm, quantized):
+    """``paged_write`` lands each token at [layer, page, offset] through the
+    block table: a scalar position writes s consecutive tokens (the chunk),
+    a per-row vector one token per row at its own position (decode). A
+    position on a sentinel entry is dropped, and no other layer or page
+    changes."""
     cfg, _, _ = tiny_lm
-    from repro.serving import write_slot
-    for quantized in (False, True):
-        cache = init_slot_cache(cfg, KVCacheConfig(num_slots=4, max_len=32,
-                                                   quantized=quantized))
-        kv = {"k": cache["k"], "v": cache["v"]}
-        shape = (cfg.num_layers, 3, 8, cfg.num_kv_heads,
-                 cfg.resolved_head_dim)
-        k_new = jnp.asarray(rng.normal(size=shape), jnp.float32)
-        v_new = jnp.asarray(rng.normal(size=shape), jnp.float32)
-        slots = jnp.asarray([2, 0, 4])               # 4 == num_slots: pad row
-        batched = write_slot(kv, slots, k_new, v_new)
-        seq = kv
-        for i in (0, 1):                              # pad row never written
-            seq = write_slot(seq, jnp.int32(int(slots[i])),
-                             k_new[:, i:i + 1], v_new[:, i:i + 1])
-        for name in ("k", "v"):
-            for a, b in zip(jax.tree.leaves(batched[name]),
-                            jax.tree.leaves(seq[name])):
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    hk, d = cfg.num_kv_heads, cfg.resolved_head_dim
+    pool = init_paged_storage(cfg, 6, 4, quantized=quantized)["k"]
+    table = jnp.asarray([[3, 1, 6], [0, 5, 2]], jnp.int32)   # 6: sentinel
+    x = jnp.asarray(rng.normal(size=(2, 3, hk, d)), jnp.float32)
+    want = (_reference_dequant(kv_quantize(x, pool.group_size), jnp.float32)
+            if quantized else x)
+    want = np.asarray(want)
+    base = _read(pool)
+
+    chunk = _read(paged_write(pool, 1, table, jnp.int32(2), x))
+    for row, spots in ((0, [(3, 2), (3, 3), (1, 0)]),
+                       (1, [(0, 2), (0, 3), (5, 0)])):
+        for i, (page, off) in enumerate(spots):
+            np.testing.assert_array_equal(chunk[1, page, off], want[row, i])
+    touched = np.zeros(chunk.shape[:3], bool)
+    touched[1, [3, 3, 1, 0, 0, 5], [2, 3, 0, 2, 3, 0]] = True
+    np.testing.assert_array_equal(chunk[~touched], base[~touched])
+
+    step = _read(paged_write(pool, 1, table, jnp.asarray([1, 6]), x[:, :1]))
+    np.testing.assert_array_equal(step[1, 3, 1], want[0, 0])
+    np.testing.assert_array_equal(step[1, 5, 2], want[1, 0])
+    touched = np.zeros(step.shape[:3], bool)
+    touched[1, [3, 5], [1, 2]] = True
+    np.testing.assert_array_equal(step[~touched], base[~touched])
+
+    # row 0's position 9 maps to its sentinel entry: nothing is written
+    dropped = paged_write(pool, 1, table, jnp.asarray([9, 0]), x[:, :1])
+    got = _read(dropped)
+    np.testing.assert_array_equal(got[1, 0, 0], want[1, 0])
+    got[1, 0, 0] = base[1, 0, 0]
+    np.testing.assert_array_equal(got, base)
 
 
-def test_slot_rows_roundtrip(rng, tiny_lm):
-    """slot_rows/set_slot_rows (the chunked prefill's working view) slice
-    and splice one slot's rows exactly, dense and quantized."""
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+def test_write_pages_batched_matches_sequential(rng, tiny_lm, quantized):
+    """One batched write_pages dispatch (B rows) is bit-identical to B
+    sequential single-row splices, for dense AND INT8 QuantizedKV pools;
+    a padding row (all-sentinel page map) and the page columns past a
+    row's map are dropped."""
     cfg, _, _ = tiny_lm
-    from repro.serving import set_slot_rows, slot_rows
-    for quantized in (False, True):
-        cache = init_slot_cache(cfg, KVCacheConfig(num_slots=3, max_len=16,
-                                                   quantized=quantized))
-        entry = cache["k"]
-        row = slot_rows(entry, jnp.int32(1))
-        leaves = jax.tree.leaves(row)
-        assert all(l.shape[1] == 1 for l in leaves)
-        bumped = jax.tree.map(lambda x: x + 1, row)
-        back = set_slot_rows(entry, jnp.int32(1), bumped)
-        for a, b in zip(jax.tree.leaves(slot_rows(back, jnp.int32(1))),
-                        jax.tree.leaves(bumped)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        for a, b in zip(jax.tree.leaves(slot_rows(back, jnp.int32(0))),
-                        jax.tree.leaves(slot_rows(entry, jnp.int32(0)))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    kv = init_paged_storage(cfg, 8, 4, quantized=quantized)
+    shape = (cfg.num_layers, 3, 8, cfg.num_kv_heads, cfg.resolved_head_dim)
+    k_new = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v_new = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    maps = jnp.asarray([[5, 1], [0, 8], [8, 8]], jnp.int32)  # 8: sentinel
+    batched = write_pages(kv, maps, k_new, v_new, 4)
+    seq = kv
+    for i in (0, 1):                                  # pad row never written
+        seq = write_pages(seq, maps[i:i + 1], k_new[:, i:i + 1],
+                          v_new[:, i:i + 1], 4)
+    for name in ("k", "v"):
+        for a, b in zip(_planes(batched[name]), _planes(seq[name])):
+            np.testing.assert_array_equal(a, b)
+        # pages no live map entry names keep their initial bytes
+        for a, b in zip(_planes(batched[name]), _planes(kv[name])):
+            np.testing.assert_array_equal(a[:, [2, 3, 4, 6, 7]],
+                                          b[:, [2, 3, 4, 6, 7]])
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+def test_take_put_pages_roundtrip(tiny_lm, quantized):
+    """take_pages/put_pages (the spill and restore of preemption) move
+    whole pages across all layers exactly, dense and quantized; a sentinel
+    index in a restore drops its payload."""
+    cfg, _, _ = tiny_lm
+    entry = init_paged_storage(cfg, 5, 4, quantized=quantized)["k"]
+    pages = jnp.asarray([2, 0], jnp.int32)
+    taken = take_pages(entry, pages)
+    assert all(x.shape[:2] == (cfg.num_layers, 2)
+               for x in jax.tree.leaves(taken))
+    bumped = jax.tree.map(lambda x: x + 1, taken)
+    back = put_pages(entry, pages, bumped)
+    for a, b in zip(_planes(take_pages(back, pages)), _planes(bumped)):
+        np.testing.assert_array_equal(a, b)
+    others = jnp.asarray([1, 3, 4], jnp.int32)
+    for a, b in zip(_planes(take_pages(back, others)),
+                    _planes(take_pages(entry, others))):
+        np.testing.assert_array_equal(a, b)
+    dropped = put_pages(entry, jnp.asarray([5, 5], jnp.int32), bumped)
+    for a, b in zip(_planes(dropped), _planes(entry)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_int8_cache_bytes_about_half_of_dense(tiny_lm):
     cfg, model, params = tiny_lm
-    dense = init_slot_cache(cfg, KVCacheConfig(num_slots=4, max_len=32,
-                                               dtype=jnp.bfloat16))
-    int8 = init_slot_cache(cfg, KVCacheConfig(num_slots=4, max_len=32,
-                                              quantized=True))
+    dense = init_paged_storage(cfg, 8, 16, dtype=jnp.bfloat16)
+    int8 = init_paged_storage(cfg, 8, 16, quantized=True)
     ratio = cache_bytes(dense) / cache_bytes(int8)
     assert 1.5 <= ratio <= 2.0                        # ≈ half the bytes
 
 
 def test_quantized_cache_logit_tolerance(tiny_lm):
-    """INT8 KV cache vs dense through prefill + decode_step: prefill logits
-    are exact (attention reads the fresh dense K/V), decode logits are
-    within int8 tolerance."""
+    """INT8 page pool vs dense through the engine's prefill splice and a
+    decode step: the same prefilled K/V written into both pools
+    (write_pages), then decode logits through each pool are within int8
+    tolerance."""
     cfg, model, params = tiny_lm
     toks = jax.random.randint(jax.random.PRNGKey(2), (2, 12), 0,
                               cfg.vocab_size)
-    dense = model.init_cache(2, 16, jnp.float32)
-    quant = init_slot_cache(cfg, KVCacheConfig(num_slots=2, max_len=16,
-                                               quantized=True))
-    quant["pos"] = jnp.zeros((), jnp.int32)           # static-style scalar pos
-    ld, cd = jax.jit(model.prefill)(params, {"tokens": toks}, dense)
-    lq, cq = jax.jit(model.prefill)(params, {"tokens": toks}, quant)
-    np.testing.assert_array_equal(np.asarray(ld), np.asarray(lq))
-    tok = jnp.argmax(ld[:, -1], -1)[:, None]
-    dd, _ = jax.jit(model.decode_step)(params, tok, cd)
-    dq, _ = jax.jit(model.decode_step)(params, tok, cq)
+    mini = model.init_cache(2, 12, jnp.float32)
+    logits, mini = jax.jit(model.prefill)(params, {"tokens": toks}, mini)
+    table = jnp.asarray([[3, 0], [1, 4]], jnp.int32)
+    tok = jnp.argmax(logits[:, -1], -1)[:, None]
+    decode = jax.jit(model.decode_step)
+    out = []
+    for quantized in (False, True):
+        kv = write_pages(init_paged_storage(cfg, 6, 8, quantized=quantized),
+                         table, mini["k"], mini["v"], 8)
+        cache = {**kv, "pos": jnp.full((2,), 12, jnp.int32), "table": table}
+        out.append(decode(params, tok, cache)[0])
+    dd, dq = out
     scale = float(jnp.abs(dd).max())
-    assert float(jnp.abs(dd - dq).max()) < 0.05 * scale
+    assert 0.0 < float(jnp.abs(dd - dq).max()) < 0.05 * scale
 
 
 # ---------------------------------------------------------------------------
@@ -372,8 +424,8 @@ def test_engine_chunked_long_prompt_matches_static_path(
 
 
 def test_engine_chunked_int8_cache_completes(tiny_lm):
-    """Long prompts through the INT8 QuantizedKV slot cache: the chunk
-    program quantizes on write and attends the dequantized rows — the
+    """Long prompts through the INT8 QuantizedKV page pool: the chunk
+    program quantizes on write and attends the dequantized pages — the
     trace completes with the right budgets and no post-warmup compiles."""
     cfg, model, params = tiny_lm
     reqs = _requests(cfg, lens=[20, 40, 9, 33], gens=[4, 3, 5, 2])
@@ -389,8 +441,8 @@ def test_engine_chunked_int8_cache_completes(tiny_lm):
         r.max_new_tokens for r in reqs)
 
 
-@pytest.mark.parametrize("layout", ["slots", "paged"])
-def test_engine_lowered_programs_run_dots_at_f32(tiny_lm, layout):
+@pytest.mark.parametrize("kv", ["paged", "int8"])     # dense or INT8 pools
+def test_engine_lowered_programs_run_dots_at_f32(tiny_lm, kv):
     """``lowered_text`` lowers the decode and chunk programs at the engine's
     own shapes without running or recompiling them, and every dot in them
     is traced at float32 precision (``step_jit``), so no row's logits
@@ -399,8 +451,8 @@ def test_engine_lowered_programs_run_dots_at_f32(tiny_lm, layout):
     reqs = _requests(cfg, lens=[20, 5], gens=[3, 2])
     engine = Engine(model, params,
                     EngineConfig(num_slots=2, max_len=32,
-                                 prompt_buckets=(8, 16), kv_layout=layout,
-                                 page_size=8))
+                                 prompt_buckets=(8, 16), page_size=8,
+                                 kv_quantized=kv == "int8"))
     compiled = engine.warmup(reqs)
     for program in ("decode", "chunk"):
         dots = [line for line in engine.lowered_text(program).splitlines()

@@ -40,8 +40,8 @@ def _requests(cfg, lens, gens, base=0, seed=0, **kw):
             for i, (l, g) in enumerate(zip(lens, gens))]
 
 
-PAGED = dict(num_slots=3, max_len=48, kv_layout="paged", page_size=8,
-             num_pages=9, prefix_caching=False)
+PAGED = dict(num_slots=3, max_len=48, page_size=8, num_pages=9,
+             prefix_caching=False)
 TRACE = ([7, 9, 11, 7, 9, 13, 7, 9], [10, 12, 9, 11, 8, 10, 12, 9])
 
 
@@ -246,9 +246,11 @@ def test_deadlock_detected_early_when_pool_never_allocates(tiny_lm):
 # decode guard: non-finite logits fail the slot, not the batch
 # ---------------------------------------------------------------------------
 
-def test_real_nan_in_cache_fails_only_the_poisoned_slot(tiny_lm):
+@pytest.mark.parametrize("quantized", [False, True], ids=["dense", "int8"])
+def test_real_nan_in_cache_fails_only_the_poisoned_slot(tiny_lm, quantized):
     cfg, model, params = tiny_lm
-    eng = Engine(model, params, EngineConfig(num_slots=2, max_len=32))
+    eng = Engine(model, params, EngineConfig(num_slots=2, max_len=32,
+                                             kv_quantized=quantized))
     eng.warmup(_requests(cfg, [6], [4]))
     a, b = _requests(cfg, [6, 6], [8, 8])
     eng.submit(a)
@@ -257,9 +259,13 @@ def test_real_nan_in_cache_fails_only_the_poisoned_slot(tiny_lm):
     clean = {r.rid: list(r.tokens) for r in eng._results.values()}
     slot = next(s for s in eng.scheduler.active_slots()
                 if eng.scheduler.slots[s].request.rid == 0)
-    # genuine corruption, not a flag: NaN K rows make slot 0's attention
-    # (and therefore its logits) non-finite on the next decode step
-    eng.kv["k"] = eng.kv["k"].at[:, slot].set(jnp.nan)
+    # genuine corruption, not a flag: NaN K entries in the victim's pages
+    # (an INT8 pool's scale plane) make its attention, and therefore its
+    # logits, non-finite on the next decode step
+    pages = jnp.asarray(eng._slot_pages[slot])
+    k = eng.kv["k"]
+    eng.kv["k"] = (k._replace(scale=k.scale.at[:, pages].set(jnp.nan))
+                   if quantized else k.at[:, pages].set(jnp.nan))
     assert not cache_is_finite(eng.kv)  # the diagnostic localizes it
     out = {r.rid: r for r in eng.run()}
     assert out[0].status == "error" and "non-finite" in out[0].error

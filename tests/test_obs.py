@@ -319,7 +319,7 @@ def test_engine_trace_deadline_and_error(tiny_lm):
 def test_engine_trace_preempt_resume_spans(tiny_lm):
     cfg, model, params = tiny_lm
     eng = Engine(model, params, EngineConfig(
-        num_slots=3, max_len=48, kv_layout="paged", page_size=8,
+        num_slots=3, max_len=48, page_size=8,
         num_pages=9, prefix_caching=False))
     # overload-shaped trace: 3 slots x 5 pages each vs a 9-page pool, so
     # decode extension must preempt (mirrors the bench overload scenario)

@@ -21,7 +21,7 @@ BLOCK_SCOPES = {"attn_in", "kv_write", "attn", "attn_out", "mlp"}
 # a pool too small for all three requests at full length: decode extension
 # preempts the youngest, which later resumes
 PAGED = dict(num_slots=3, max_len=32, prompt_buckets=(8, 16), page_size=4,
-             kv_layout="paged", num_pages=12, prefix_caching=False)
+             num_pages=12, prefix_caching=False)
 
 
 @pytest.fixture(scope="module")
@@ -192,9 +192,8 @@ def _program(eng, name):
     if name == "chunk":
         return eng._chunk, eng._dummy_chunk_args()
     b, w = 2, eng.scheduler.buckets[0]
-    route = (jnp.full((b, -(-w // eng.cfg.page_size)), eng.alloc.num_pages,
-                      jnp.int32) if eng._paged
-             else jnp.full((b,), eng.cfg.num_slots, jnp.int32))
+    route = jnp.full((b, -(-w // eng.cfg.page_size)), eng.alloc.num_pages,
+                     jnp.int32)
     return eng._prefill, (eng.params, eng.kv, jnp.zeros((b, w), jnp.int32),
                           jnp.ones((b,), jnp.int32), route,
                           jnp.zeros((b,), jnp.float32),
@@ -202,22 +201,22 @@ def _program(eng, name):
                           jnp.zeros((b,), jnp.uint32))
 
 
-def _optimized(model, params, layout, name):
+def _optimized(model, params, kv, name):
     eng = Engine(model, params, EngineConfig(
         num_slots=2, max_len=32, prompt_buckets=(8, 16), page_size=8,
-        kv_layout=layout))
+        kv_quantized=kv == "int8"))
     fn, args = _program(eng, name)
     return fn.lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("layout", ["paged", "slots"])
+@pytest.mark.parametrize("kv", ["paged", "int8"])     # dense or INT8 pools
 @pytest.mark.parametrize("name", ["decode", "chunk", "prefill"])
-def test_scopes_change_metadata_only(tiny_lm, monkeypatch, layout, name):
+def test_scopes_change_metadata_only(tiny_lm, monkeypatch, kv, name):
     _, model, params = tiny_lm
-    scoped = _optimized(model, params, layout, name)
+    scoped = _optimized(model, params, kv, name)
     monkeypatch.setattr(jax, "named_scope",
                         lambda _: contextlib.nullcontext())
-    plain = _optimized(model, params, layout, name)
+    plain = _optimized(model, params, kv, name)
     assert canonical_hlo(scoped) == canonical_hlo(plain)
     # every op of the program lies under a scope of the vocabulary, those
     # of the layer loop's body under a block's scope

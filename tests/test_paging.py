@@ -1,13 +1,15 @@
 """Paged KV-cache subsystem: allocator/refcount invariants, prefix-cache
-match/insert/evict semantics, resume-ticket ordering, paged-vs-slot greedy
-bit-parity (dense + INT8), copy-on-write stability of shared-prefix pages,
-and preempt-then-resume parity under page-pool pressure."""
+match/insert/evict semantics, resume-ticket ordering, greedy bit-parity
+with the static path (dense) and the unfused engine (INT8), copy-on-write
+stability of shared-prefix pages, and preempt-then-resume parity under
+page-pool pressure."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_tiny_config
+from repro.launch.serve import make_step_fns, static_greedy_reference
 from repro.models import build_model
 from repro.serving import (Engine, EngineConfig, GenerationRequest,
                            PageAllocator, PrefixCache, SamplingParams,
@@ -45,6 +47,13 @@ def _run(engine, reqs):
         engine.submit(r)
     results = engine.run()
     return {r.rid: r.tokens for r in results}
+
+
+def _static(model, params, reqs, max_len):
+    """Each request's greedy tokens alone on the static path."""
+    step_fns = make_step_fns(model)
+    return {r.rid: static_greedy_reference(model, params, r, max_len,
+                                           step_fns) for r in reqs}
 
 
 # ---------------------------------------------------------------------------
@@ -150,14 +159,12 @@ def test_resume_ticket_ordering_and_admission():
                               max_new_tokens=4) for i in range(3)]
     for r in reqs:
         s.submit(r)
-    slot, r0 = s.admit()
+    slot, r0 = s.admit_head()
     s.slots[slot].generated = 2
     t0 = ResumeTicket(request=r0, generated=2, last_token=7, pos=5, n_pages=1)
     s.preempt(slot, t0)
     # the ticket outranks every never-admitted request (r0.seq is oldest)
     assert s.peek() is t0
-    # batched admission never pops a ticket — the engine must restore pages
-    assert s.admit_batch() is None
     slot, head = s.admit_head()
     assert head is t0
     assert s.slots[slot].generated == 2        # decode progress survives
@@ -172,19 +179,18 @@ def test_resume_ticket_ordering_and_admission():
 
 
 # ---------------------------------------------------------------------------
-# engine parity: paged vs slot
+# engine parity: static path, unfused engine
 # ---------------------------------------------------------------------------
 
 def test_paged_matches_slot_greedy_dense(tiny_lm, assert_flat_compiles):
     """Acceptance: mixed-length greedy trace through the paged engine is
-    bit-identical to the slot engine (page_size divides max_len)."""
+    bit-identical to the static path (page_size divides max_len)."""
     cfg, model, params = tiny_lm
     max_len = 64
     reqs = _requests(cfg, lens=[5, 13, 8, 21, 3, 16], gens=[6, 3, 9, 4, 8, 5])
-    slot = Engine(model, params, EngineConfig(num_slots=4, max_len=max_len))
-    want = _run(slot, reqs)
+    want = _static(model, params, reqs, max_len)
     paged = Engine(model, params, EngineConfig(
-        num_slots=4, max_len=max_len, kv_layout="paged", page_size=8))
+        num_slots=4, max_len=max_len, page_size=8))
     compiled = paged.warmup(reqs)
     with assert_flat_compiles(paged, compiled):  # no recompilation after warmup
         for r in reqs:
@@ -197,17 +203,18 @@ def test_paged_matches_slot_greedy_dense(tiny_lm, assert_flat_compiles):
 
 
 def test_paged_int8_matches_slot_int8(tiny_lm):
-    """The paged pool with per-page INT8 scales reproduces the slot
-    engine's INT8 outputs exactly — same quantizer, same group shapes."""
+    """The fused read of the INT8 pool (per-page scales dequantized
+    in-tile) reproduces the unfused engine's INT8 outputs exactly — same
+    quantizer, same group shapes, gather-then-attend reference read."""
     cfg, model, params = tiny_lm
     max_len = 32
     reqs = _requests(cfg, lens=[6, 11, 9], gens=[5, 4, 6])
-    slot = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=max_len, kv_quantized=True))
-    want = _run(slot, reqs)
+    ref = Engine(model, params, EngineConfig(
+        num_slots=2, max_len=max_len, kv_quantized=True, page_size=8,
+        use_fused_decode=False))
+    want = _run(ref, reqs)
     paged = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=max_len, kv_quantized=True,
-        kv_layout="paged", page_size=8))
+        num_slots=2, max_len=max_len, kv_quantized=True, page_size=8))
     got = _run(paged, reqs)
     for req in reqs:
         assert got[req.rid] == want[req.rid], req.rid
@@ -217,7 +224,7 @@ def test_paged_prefix_hit_reuses_pages_copy_free(tiny_lm):
     """Requests sharing a prompt prefix reuse the cached pages (no copy):
     hits are counted, reused tokens skip prefill, the shared pages' bytes
     are untouched by the diverging request (CoW by construction), and
-    outputs stay bit-identical to the slot engine."""
+    outputs stay bit-identical to the static path."""
     cfg, model, params = tiny_lm
     max_len, pg = 64, 8
     rng = np.random.default_rng(3)
@@ -229,11 +236,10 @@ def test_paged_prefix_hit_reuses_pages_copy_free(tiny_lm):
                                       prompt=np.concatenate([prefix, tail]),
                                       max_new_tokens=5,
                                       sampling=SamplingParams(seed=100 + i)))
-    slot = Engine(model, params, EngineConfig(num_slots=2, max_len=max_len))
-    want = _run(slot, reqs)
+    want = _static(model, params, reqs, max_len)
 
     paged = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=max_len, kv_layout="paged", page_size=pg))
+        num_slots=2, max_len=max_len, page_size=pg))
     paged.warmup(reqs)
     paged.submit(reqs[0])
     first = {r.rid: r.tokens for r in paged.run()}
@@ -263,10 +269,9 @@ def test_paged_preempt_then_resume_matches_slot(tiny_lm):
     cfg, model, params = tiny_lm
     max_len = 48                               # 6 pages/slot at page_size 8
     reqs = _requests(cfg, lens=[30, 29, 31, 28], gens=[12, 12, 12, 12])
-    slot = Engine(model, params, EngineConfig(num_slots=3, max_len=max_len))
-    want = _run(slot, reqs)
+    want = _static(model, params, reqs, max_len)
     paged = Engine(model, params, EngineConfig(
-        num_slots=3, max_len=max_len, kv_layout="paged", page_size=8,
+        num_slots=3, max_len=max_len, page_size=8,
         num_pages=9, prefix_caching=False))    # 9 < 3*6: decode must evict
     got = _run(paged, reqs)
     stats = paged.page_stats()
@@ -289,8 +294,8 @@ def test_paged_programs_keep_pools_in_place(tiny_lm, name, quant):
     program is checked in ``test_chip_compile``."""
     cfg, model, params = tiny_lm
     eng = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=32, prompt_buckets=(8, 16), kv_layout="paged",
-        page_size=8, num_pages=11, use_fused_decode=False,
+        num_slots=2, max_len=32, prompt_buckets=(8, 16), page_size=8,
+        num_pages=11, use_fused_decode=False,
         **(dict(kv_quantized=True, kv_dtype=jnp.bfloat16) if quant else {})))
     fn, args = {"decode": (eng._decode, eng._decode_args),
                 "chunk": (eng._chunk, eng._dummy_chunk_args)}[name]
@@ -298,11 +303,20 @@ def test_paged_programs_keep_pools_in_place(tiny_lm, name, quant):
     assert pool_carry_faults(eng, hlo) == []
 
 
+def test_paged_pool_is_the_only_kv_layout():
+    """The engine serves from the paged pool only; the configuration field
+    stays for files that name it and refuses any other layout."""
+    assert EngineConfig().kv_layout == "paged"
+    assert EngineConfig(kv_layout="paged") == EngineConfig()
+    with pytest.raises(ValueError, match="slot layout was removed"):
+        EngineConfig(kv_layout="slots")
+
+
 def test_paged_pool_must_fit_one_request(tiny_lm):
     cfg, model, params = tiny_lm
     with pytest.raises(ValueError):
         Engine(model, params, EngineConfig(
-            num_slots=2, max_len=64, kv_layout="paged", page_size=8,
+            num_slots=2, max_len=64, page_size=8,
             num_pages=7))                      # < pages_per_slot (8)
 
 
@@ -339,12 +353,10 @@ def test_preempt_sole_active_request_resumes_bit_identically(tiny_lm):
     fresh slot with bit-identical continuation."""
     cfg, model, params = tiny_lm
     req = _requests(cfg, lens=[13], gens=[10])[0]
-    slot = Engine(model, params, EngineConfig(num_slots=2, max_len=32))
-    want = _run(slot, req and [req])[0]
+    want = _static(model, params, [req], 32)[0]
 
     paged = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=32, kv_layout="paged", page_size=8,
-        prefix_caching=False))
+        num_slots=2, max_len=32, page_size=8, prefix_caching=False))
     paged.warmup([req])
     paged.submit(req)
     paged.step()
@@ -370,11 +382,10 @@ def test_resume_waits_until_pool_frees_pages(tiny_lm):
     # requests grow from 2 to 4 pages, so A's extension at pos 12 must
     # preempt B, and B's 3-page ticket can't fit until A retires.
     reqs = _requests(cfg, lens=[7, 7], gens=[9, 9])
-    slot = Engine(model, params, EngineConfig(num_slots=2, max_len=16))
-    want = _run(slot, reqs)
+    want = _static(model, params, reqs, 16)
 
     paged = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=16, kv_layout="paged", page_size=4,
+        num_slots=2, max_len=16, page_size=4,
         num_pages=6, prefix_caching=False))
     paged.warmup(reqs)
     for r in reqs:
@@ -405,8 +416,7 @@ def test_cancel_while_spilled_frees_ticket_and_payload(tiny_lm):
     cfg, model, params = tiny_lm
     reqs = _requests(cfg, lens=[13, 13], gens=[8, 8])
     paged = Engine(model, params, EngineConfig(
-        num_slots=2, max_len=32, kv_layout="paged", page_size=8,
-        prefix_caching=False))
+        num_slots=2, max_len=32, page_size=8, prefix_caching=False))
     paged.warmup(reqs)
     for r in reqs:
         paged.submit(r)
